@@ -58,12 +58,25 @@ class SanaBackend:
 
     # -- setup ---------------------------------------------------------------
     def setup(self) -> None:
-        key = jax.random.PRNGKey(self.cfg.seed_params)
-        kt, kv = jax.random.split(key)
-        if self.params is None:
-            self.params = sana.init_sana(kt, self.cfg.model)
-        if self.vae_params is None and self.cfg.decode_images:
-            self.vae_params = dcae.init_decoder(kv, self.cfg.vae)
+        need_vae = self.vae_params is None and self.cfg.decode_images
+        if self.params is None or need_vae:
+            # random weights come from ONE compiled program: at the 1.6B
+            # geometry an op-by-op eager init is ~40 separately compiled
+            # programs, and this one lands in the persistent compile cache
+            need_params = self.params is None
+
+            def init(key):
+                kt, kv = jax.random.split(key)
+                out = {}
+                if need_params:
+                    out["params"] = sana.init_sana(kt, self.cfg.model)
+                if need_vae:
+                    out["vae"] = dcae.init_decoder(kv, self.cfg.vae)
+                return out
+
+            out = jax.jit(init)(jax.random.PRNGKey(self.cfg.seed_params))
+            self.params = out.get("params", self.params)
+            self.vae_params = out.get("vae", self.vae_params)
         if self.prompt_embeds is None:
             self._load_prompts()
 

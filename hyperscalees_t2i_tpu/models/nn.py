@@ -71,8 +71,8 @@ def dense(p: Params, x: jax.Array, lora: Optional[Params] = None, lora_scale: fl
     base and factored perturbations are present, the whole expression
     resolves through ``ops/fused_qlora.fused_qlora_dense`` — ONE kernel
     dequantizes the s8 base tile in VMEM and applies the member's LoRA chain
-    against it (the unified hot path; its XLA fallback is the byte-identical
-    pre-round-15 composition). Attention's QKV/out projections (sana.py
+    against it (the unified hot path; off the TPU it lowers the byte-identical
+    pre-round-15 XLA composition). Attention's QKV/out projections (sana.py
     attn1/attn2, clip.py q/k/v/out) are ordinary dense sites and get the
     same treatment through here.
     """
@@ -85,7 +85,7 @@ def dense(p: Params, x: jax.Array, lora: Optional[Params] = None, lora_scale: fl
         qk = p["kernel_q8"]
         if lora is not None and fused_qlora_applies(lora):
             # unified int8-dequant + member-LoRA resolution (one kernel on
-            # TPU; the round-14 composition as its XLA fallback) — the LoRA
+            # TPU; the round-14 XLA composition elsewhere) — the LoRA
             # delta is consumed here, not re-applied below
             y = fused_qlora_dense(x, qk, lora, lora_scale)
             lora = None

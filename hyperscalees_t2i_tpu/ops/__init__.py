@@ -1,6 +1,6 @@
 """TPU ops: sampling primitives, Pallas kernels, distributed attention.
 
-Re-exports are LAZY (PEP 562): ``ops.pallas_probe`` is stdlib-only at
+Re-exports are LAZY (PEP 562): ``ops.pallas_gate`` is stdlib-only at
 import and is consumed by jax-free processes (the bench ladder parent,
 tools/bench_report.py) — an eager ``from .ring_attention import ...`` here
 would drag jax into them through the package init.

@@ -150,6 +150,20 @@ def maybe_quantize_tree(
     return quantize_tree(tree, min_size=resolve_base_quant_min_size(min_size))
 
 
+def quantize_frozen(tree: Params, base_quant: str) -> Params:
+    """:func:`maybe_quantize_tree` over device-resident frozen trees (one
+    tree, or a dict of them) as ONE compiled pass with the float input
+    donated — every leaf the pass leaves untouched keeps its buffer, and
+    the float kernels are released as soon as the call returns instead of
+    living on behind a second reference. What bench.build and train.cli run
+    at the multi-GB geometries."""
+    if base_quant in (None, "", "off", False):
+        return tree
+    return jax.jit(
+        lambda t: maybe_quantize_tree(t, base_quant), donate_argnums=(0,)
+    )(tree)
+
+
 def tree_int8_bytes(tree: Any) -> int:
     """Total bytes of int8 leaves in a tree — a diagnostic for sizing a
     quantized base (tests/tools; the preflight's chip-true accounting

@@ -10,52 +10,48 @@ XLA versions where the fusion heuristic materializes the dequantized copy
 instead (the failure mode the preflight's ``int8_dequant_copy_bytes``
 instrument measures on CPU).
 
-Ships **behind a flag** with a clean XLA fallback, mirroring
-``ops/fused_lora.py``:
+Ships **behind a flag**, mirroring ``ops/fused_lora.py``:
 
-- ``HSES_BASE_QUANT_PALLAS=1`` + a TPU backend + a successful one-time
-  probe compile → the Pallas kernel;
-- anything else (CPU tests, non-TPU platforms, any trace error) →
-  :func:`xla_int8_matmul`, the same math in plain jnp.
+- ``HSES_BASE_QUANT_PALLAS=1`` + a TPU backend → the Pallas kernel (a
+  Mosaic refusal raises at the enclosing compile);
+- anything else → :func:`xla_int8_matmul`, the same math in plain jnp.
 
 CPU correctness is proven in interpret mode (tests/test_quant.py) — the
 ops/attention.py / ops/fused_lora.py contract: the CPU tier can lower and
-*interpret* the kernel; only real TPU executes it.
+*interpret* the kernel; only a TPU executes it (``tools/kernel_check.py``
+is that run).
 """
 
 from __future__ import annotations
 
-import functools
-import sys
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from .pallas_probe import backend_is_tpu, env_requested, probe
-
-
-def _probe_thunk():
-    """Tiny-operand kernel execution for the shared one-time probe
-    (ops/pallas_probe.py) — a Mosaic rejection must surface here as the
-    documented fallback, not inside the enclosing ES-step compile."""
-    return _pallas_int8_matmul(
-        jnp.ones((8, 16), jnp.float32),
-        jnp.ones((16, 8), jnp.int8),
-        jnp.ones((1, 8), jnp.float32),
-        block_t=8, interpret=False,
-    )
+from .pallas_gate import backend_is_tpu, env_requested
 
 
 def use_base_quant_pallas() -> bool:
-    """Opt-in gate (the XLA dequant fusion is the proven default): env flag
-    + a TPU backend + the probe compile (the shared ``ops/pallas_probe``
-    machine). The flag is a request, not a demand — anywhere the kernel
-    can't run falls back with one stderr line."""
+    """Opt-in gate (the XLA dequant fusion is the default): the env flag
+    AND a TPU backend (ops/pallas_gate.py)."""
+    return env_requested("HSES_BASE_QUANT_PALLAS") is True and backend_is_tpu()
+
+
+# The kernel keeps a node's whole [din, dout] s8 matrix resident in VMEM (it
+# tiles tokens only). Mosaic's default scoped limit on v5e is 16 MiB: the
+# 4.8 MiB [2240, 2240] Sana attention node compiles, the 23.9 MiB
+# [2240, 11200] FFN node is refused at 24.11 MiB (v5e compile, PR 21), so
+# only nodes up to half the limit are the kernel's.
+MAX_RESIDENT_BYTES = 8 * 2**20
+
+
+def _kernel_handles(q8: jax.Array, scale: jax.Array) -> bool:
+    """2D per-output-channel nodes small enough to sit in VMEM whole. GGUF
+    block-scale nodes (``scale.shape[-2] > 1``) and wide nodes are XLA's."""
     return (
-        env_requested("HSES_BASE_QUANT_PALLAS") is True
-        and backend_is_tpu()
-        and probe("quant_mm", _probe_thunk, "the XLA dequant fusion")
+        q8.ndim == 2 and scale.ndim == 2 and scale.shape[0] == 1
+        and q8.size <= MAX_RESIDENT_BYTES
     )
 
 
@@ -63,20 +59,20 @@ def dequant_matmul(x: jax.Array, qk: dict) -> jax.Array:
     """``x @ dequant(qk)`` — THE dequant-matmul contract every 2D
     ``kernel_q8`` consumer resolves through: ``nn.dense`` (float path aside),
     the matmul-equivalent conv/patch-embed sites (ops/fused_qlora.py), and
-    the unified kernel's base-term fallback. One definition, so "consumes an
+    the unified kernel's XLA composition. One definition, so "consumes an
     int8 base" means the same lowering everywhere: the explicit in-VMEM
     Pallas dequant kernel when :func:`use_base_quant_pallas` gates it on
-    (2D per-output-channel nodes only), the XLA operand-fused dequant
-    otherwise (incl. GGUF block-scale nodes, which the kernel declines)."""
-    if qk["q8"].ndim == 2 and use_base_quant_pallas():
-        return int8_matmul(x, qk["q8"], qk["scale"])
+    and the node is one :func:`_kernel_handles`, the XLA operand-fused
+    dequant otherwise."""
+    if use_base_quant_pallas() and _kernel_handles(qk["q8"], qk["scale"]):
+        return int8_matmul(x, qk["q8"], qk["scale"], use_pallas=True)
     from .quant import dequantize_kernel
 
     return x @ dequantize_kernel(qk, x.dtype)
 
 
 def xla_int8_matmul(x: jax.Array, q8: jax.Array, scale: jax.Array) -> jax.Array:
-    """The fallback: ``x @ (q8·scale)`` with the dequant left to XLA operand
+    """The XLA form: ``x @ (q8·scale)`` with the dequant left to XLA operand
     fusion — exactly what ``nn.dense`` lowers via ``dequantize_kernel``."""
     from .quant import dequantize_kernel
 
@@ -106,7 +102,7 @@ def _pallas_int8_matmul(x2, q8, scale, block_t: int, interpret: bool):
     if T_pad != T:
         x2 = jnp.pad(x2, ((0, T_pad - T), (0, 0)))
     out = pl.pallas_call(
-        functools.partial(_int8_mm_kernel),
+        _int8_mm_kernel,
         out_shape=jax.ShapeDtypeStruct((T_pad, dout), x2.dtype),
         grid=(n_blk,),
         in_specs=[
@@ -116,6 +112,7 @@ def _pallas_int8_matmul(x2, q8, scale, block_t: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((block_t, dout), lambda t: (t, 0)),
         interpret=interpret,
+        name="int8_matmul",
     )(x2, q8, scale)
     return out[:T]
 
@@ -131,26 +128,19 @@ def int8_matmul(
 ) -> jax.Array:
     """``x @ (q8·scale)`` for one 2D per-output-channel int8 kernel node.
 
-    ``x`` may have any leading shape (``[..., din]``). GGUF block-scale
-    nodes (``scale.shape[-2] > 1``) take the XLA path — the kernel handles
-    the per-channel layout only. ``use_pallas=None`` auto-selects via
-    :func:`use_base_quant_pallas`; a trace failure falls back to the XLA
-    fusion with one stderr line."""
+    ``x`` may have any leading shape (``[..., din]``). Nodes the kernel
+    does not handle (:func:`_kernel_handles`) take the XLA path whatever
+    ``use_pallas`` says. ``use_pallas=None`` auto-selects via
+    :func:`use_base_quant_pallas`; a selected kernel that fails to trace or
+    compile raises. ``interpret`` is for tests only."""
     if use_pallas is None:
         use_pallas = use_base_quant_pallas()
-    if scale.ndim != 2 or scale.shape[0] != 1 or q8.ndim != 2:
+    if not _kernel_handles(q8, scale):
         use_pallas = False
     if not (use_pallas or interpret):
         return xla_int8_matmul(x, q8, scale)
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    try:
-        out = _pallas_int8_matmul(x2, q8, scale, block_t, interpret)
-    except Exception as e:  # pragma: no cover - platform dependent
-        print(
-            f"[quant_mm] Pallas int8 kernel unavailable ({type(e).__name__}: "
-            f"{e}); falling back to the XLA dequant fusion",
-            file=sys.stderr, flush=True,
-        )
-        return xla_int8_matmul(x, q8, scale)
+    out = _pallas_int8_matmul(
+        x.reshape(-1, x.shape[-1]), q8, scale, block_t, interpret
+    )
     return out.reshape(*lead, out.shape[-1])

@@ -4,7 +4,7 @@ TPU-native replacement for the reference's NCCL shim
 (``/root/reference/VAR_models/dist.py`` — SURVEY.md §5.8) plus the
 population/data/tensor parallelism the reference lacks (SURVEY.md §2.2).
 
-Axis taxonomy (and deliberate omissions):
+Axis conventions (and deliberate omissions):
 
 - ``pop`` — ES population members; this is the framework's data
   parallelism (each device evaluates whole models, only [pop, B] score

@@ -66,19 +66,10 @@ def all_gather_ragged(
     return data, lens
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a named axis. ``jax.lax.axis_size`` only exists in
-    newer jax; on 0.4.x ``psum(1, axis)`` constant-folds to a Python int
-    inside shard_map, which is exactly what perm construction needs."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ppermute_ring(x: jax.Array, axis_name: str, *, shift: int = 1) -> jax.Array:
     """Ring shift along a named axis — the building block for ring attention
     and other neighbor-exchange schedules (used by ops/ring_attention)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -117,7 +108,7 @@ def master_only(fn: Callable[..., T]) -> Callable[..., Optional[T]]:
 #
 # multihost_utils' gathers/barriers run a *compiled* cross-process program,
 # and XLA's CPU backend cannot build one ("Multiprocess computations aren't
-# implemented on the CPU backend" on jax 0.4.x) — which would leave every
+# implemented on the CPU backend") — which would leave every
 # host-level agreement path (metric means, the coordinated-commit vote, the
 # desync fingerprint, preemption broadcast) untestable on the 2-proc CPU rig
 # the chaos tests and CI run on. The jax.distributed coordination service's

@@ -26,6 +26,7 @@ DCN across slices in multi-host deployments).
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, Optional, Sequence
 
@@ -71,22 +72,9 @@ def initialize_multihost() -> bool:
     return True
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-compat ``shard_map``: newer jax exposes ``jax.shard_map``
-    (``check_vma`` kwarg); 0.4.x only has ``jax.experimental.shard_map``
-    (``check_rep`` kwarg, same meaning). One wrapper so every call site in
-    the framework is version-agnostic — ``jax.shard_map`` raising
-    AttributeError on this container silently killed every sharded path
-    (pop_eval, ring attention) at seed."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
+# the framework's bodies return all_gather'ed values under replicated
+# out_specs, which the varying-manual-axes check cannot infer
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def make_mesh(
@@ -224,11 +212,11 @@ def pop_slice_plan(mesh: Mesh, pop_size: int) -> Dict[str, object]:
 def replicate_to_mesh(tree, mesh: Mesh):
     """Stage a host-local pytree fully replicated over ``mesh``, including
     meshes that span processes (multi-controller pods): plain
-    ``jax.device_put`` handles single-process meshes; cross-process meshes go
-    through ``multihost_utils.host_local_array_to_global_array`` — the
-    blessed path on jax 0.4.x, where ``device_put`` onto non-addressable
-    devices is not supported. Every process must pass the same values (they
-    do: θ init and checkpoint restores are seed/file-deterministic)."""
+    ``jax.device_put`` handles single-process meshes (arrays already on one
+    of the mesh's devices keep that buffer as their shard there); cross-process
+    meshes go through ``multihost_utils.host_local_array_to_global_array``.
+    Every process must pass the same values (they do: θ init and checkpoint
+    restores are seed/file-deterministic)."""
     if jax.process_count() <= 1 or all(
         d.process_index == jax.process_index() for d in mesh.devices.ravel()
     ):

@@ -186,13 +186,14 @@ def make_fleet_evaluator(
     contiguous lane spans, so ``mesh.host_slices(W*pop, W)`` is exactly the
     job→lane packing map (tested cover identity, tests/test_fleet.py).
 
-    Bitwise contract: each job's lane runs the *same ops in the same
+    Parity contract: each job's lane runs the *same ops in the same
     association* as the solo ``make_population_evaluator`` member lane —
     ``lane_slice`` is the very gather the serve twin uses, and the σ scalars
     are host-precomputed f32 (one rounding, like the solo program's baked
-    constants) — so per-job reward rows are bitwise-identical to W solo runs
-    on the same backend (asserted by bench --fleet / CI fleet_smoke).
-    Fitness shaping stays OUT of this program; the trainer standardizes
+    constants) — so per-job reward rows agree with W solo runs to rounding:
+    two XLA programs, a written ulp bound, not a hash
+    (``train/fleet.reward_rows_close``; bench --fleet / CI fleet_smoke hold
+    it). Fitness shaping stays OUT of this program; the trainer standardizes
     per job (``es.jobwise_prompt_normalized_scores``), never across jobs.
 
     All jobs in one step share compile-relevant geometry (pop_size, rank,

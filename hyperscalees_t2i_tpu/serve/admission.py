@@ -65,20 +65,18 @@ def resolve_hbm_budget(
     override_bytes: Optional[float] = None,
 ) -> Tuple[Optional[float], str]:
     """(budget bytes or None, source string). Override wins; else the running
-    device's capacity by kind; None when neither is known (gate unarmed)."""
+    device's capacity by kind. Off the TPU there is no capacity to protect
+    (None: gate unarmed); a TPU kind the capacity table lacks raises
+    (utils/mfu.device_chip)."""
     if override_bytes is not None:
         return float(override_bytes), "configured hbm_budget_bytes"
-    try:
-        import jax
+    import jax
 
-        from ..utils.mfu import hbm_bytes_for_kind
+    from ..utils.mfu import device_hbm_bytes
 
-        kind = getattr(jax.devices()[0], "device_kind", "")
-        cap = hbm_bytes_for_kind(kind)
-        if cap is not None:
-            return float(cap), f"device capacity ({kind})"
-    except Exception:
-        pass
+    cap = device_hbm_bytes()
+    if cap is not None:
+        return float(cap), f"device capacity ({jax.devices()[0].device_kind})"
     return None, "unknown (gate unarmed)"
 
 

@@ -7,10 +7,10 @@ path already proved:
   × images-per-request × static generation config), compiled once via
   ``jit(...).lower(...).compile()`` and reused for every batch — the same
   AOT discipline as the trainer/bench compile sites, with one ledger record
-  (``site="serve"``) per program. Under a pinned persistent compile cache
-  (``ServeConfig.compile_cache_dir`` / ``JAX_COMPILATION_CACHE_DIR``, the
-  PR 11 machinery) a restarted engine deserializes its warm pool instead of
-  recompiling.
+  (``site="serve"``) per program. The persistent compile cache sits where
+  ``JAX_COMPILATION_CACHE_DIR`` says, else ``<checkout>/.jax_cache``
+  (``utils/compile_cache``), so a restarted engine deserializes its warm
+  pool instead of recompiling.
 - **Adapters enter as program *arguments***: a batch axis of LoRA trees
   (``lora.stack_adapters`` → ``es.stacked_adapter_theta`` inside the
   ``lax.map`` lane — the member-axis contract of the training hot path,
@@ -87,7 +87,6 @@ class ServeConfig:
     max_queue: int = 1024
     adapter_budget_bytes: int = 0
     hbm_budget_bytes: Optional[int] = None
-    compile_cache_dir: Optional[str] = None
     # live telemetry (obs/exporter.py): serve /metrics + /healthz on this
     # port (0 = off). Multi-process serving fleets follow the trainer's
     # per-process offset discipline (obs/multihost.exporter_port).
@@ -133,20 +132,9 @@ class ServeEngine:
         self.cfg = cfg or ServeConfig()
         if self.cfg.adapter_batch < 1:
             raise ValueError(f"adapter_batch must be >= 1, got {self.cfg.adapter_batch}")
-        if self.cfg.compile_cache_dir:
-            # persistent compile cache (PR 11): pin it BEFORE the first serve
-            # compile so a restarted engine deserializes its warm pool. An
-            # operator-set JAX_COMPILATION_CACHE_DIR WINS — the cache config
-            # is process-global, and silently retargeting it here would move
-            # every other compile site's warm pool too.
-            import os
+        from ..utils.compile_cache import place_compile_cache
 
-            if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-                os.makedirs(self.cfg.compile_cache_dir, exist_ok=True)
-                os.environ["JAX_COMPILATION_CACHE_DIR"] = self.cfg.compile_cache_dir
-                jax.config.update(
-                    "jax_compilation_cache_dir", str(self.cfg.compile_cache_dir)
-                )
+        place_compile_cache()
         if theta_template is None:
             theta_template = backend.init_theta(jax.random.PRNGKey(0))
         self.template = theta_template

@@ -2,9 +2,9 @@
 
 Usage::
 
-    python -m hyperscalees_t2i_tpu.tools.bench_report BENCH_r05.json [...]
-    python -m hyperscalees_t2i_tpu.tools.bench_report --log .round5/rungs.log
-    python -m hyperscalees_t2i_tpu.tools.bench_report --trend BENCH_r0*.json
+    python -m hyperscalees_t2i_tpu.tools.bench_report BENCH.json [...]
+    python -m hyperscalees_t2i_tpu.tools.bench_report --log bench_runs/rungs.log
+    python -m hyperscalees_t2i_tpu.tools.bench_report --trend bench_runs/BENCH_*.json
 
 Reads driver bench artifacts (the one-line JSON with a ``rungs`` map) and/or
 raw serve-mode logs (one JSON object per line, heartbeats ignored) and prints

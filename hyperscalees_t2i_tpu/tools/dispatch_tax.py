@@ -298,10 +298,10 @@ def run(rung: str, steps: int, chain: int) -> dict:
     # kernel provenance: which Pallas env flags were set when this row was
     # measured, and whether the unified routing shaped the qlora program
     from ..ops.fused_qlora import unified_routing_enabled
-    from ..ops.pallas_probe import active_pallas_flags, probe_results
+    from ..ops.pallas_gate import active_pallas_flags, selected_kernels
 
     rec["pallas_env"] = active_pallas_flags()
-    rec["pallas_probes"] = probe_results()
+    rec["pallas_selected"] = selected_kernels()
     rec["fused_qlora"] = unified_routing_enabled()
     return rec
 

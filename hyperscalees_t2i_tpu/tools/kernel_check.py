@@ -1,0 +1,296 @@
+"""Compile, run and compare each Pallas kernel with its XLA composition, at
+the shapes its real caller passes it.
+
+    python -m hyperscalees_t2i_tpu.tools.kernel_check [--kernels a,b]
+        [--compile_only] [--out FILE]
+
+The CPU tier can only *interpret* the four kernels in ``ops/``; whether
+Mosaic accepts them, and whether what it builds agrees with the XLA path, is
+a fact about a chip. This is the one place that establishes it: every case
+below is a call a model really makes (Sana-Sprint 1.6B dense sites under
+``--base_quant int8 --pop_fuse true``, the VAR ten-scale KV cache, Infinity's
+masked cross-attention), run with ``interpret=False`` and compared with the
+XLA form the gate would otherwise choose. ``chip_smoke.py`` runs the first
+case of every kernel the default TPU gates select and fails on a
+disagreement; the opt-in kernels are checked only by this tool.
+
+``--compile_only`` lowers and compiles each case for a TPU v5e *without a
+chip* (libtpu's compile-only topology, ``jax.experimental.topologies``):
+Mosaic's verdict, no numbers. It is how a kernel change is rehearsed in a
+sandbox that has no accelerator.
+
+Exit code 1 when any case failed to compile or missed its tolerance; one
+JSON object per case on stdout (and in ``--out``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import sys
+import traceback
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# bf16 keeps 8 significand bits: a rounding moves a value by at most 2^-8
+# relatively, and two results that round to different neighbours sit one
+# spacing — up to 2^-7 of the largest magnitude — apart. Every case compares
+# bf16 results, relative to the reference's largest magnitude.
+_BF16_EPS = 2.0 ** -8
+
+
+@dataclasses.dataclass(frozen=True)
+class Case:
+    kernel: str  # the pallas_call name (ops/pallas_gate.selected_kernels key)
+    label: str
+    make: Callable[[jax.Array], Tuple[Any, ...]]  # key -> args
+    kernel_fn: Callable[..., jax.Array]
+    xla_fn: Callable[..., jax.Array]
+    tol: float  # bound on max|kernel − xla| / max|xla|
+    tol_reason: str
+
+
+def _factored(key, m: int, n: int, r_e: int, noise_dtype):
+    from ..lora import FactoredDelta
+
+    kw, ku, kv = jax.random.split(key, 3)
+    return FactoredDelta(
+        jax.random.normal(kw, (m, n), jnp.float32) / jnp.sqrt(m),
+        jax.random.normal(ku, (m, r_e), jnp.float32).astype(noise_dtype),
+        jax.random.normal(kv, (n, r_e), jnp.float32).astype(noise_dtype),
+        jnp.float32(0.01 / 2.0),  # σ/√r_e at the trainer's σ=0.01, rank 4
+    )
+
+
+def _qlora_case(label: str, T: int, din: int, dout: int, members: int = 0) -> Case:
+    """One Sana dense site under int8 base + fused factored members: bf16
+    activations, s8 base, LoRA rank 8, ES rank 4, bf16 noise store.
+    ``members`` > 0 adds the member axis ``lax.map(batch_size=member_batch)``
+    vmaps over (activations and the noise slices are per member; the base
+    and the unperturbed factors are shared)."""
+    from ..lora import FactoredDelta
+    from ..ops.fused_qlora import fused_qlora_dense, xla_fused_qlora
+    from ..ops.quant import quantize_kernel
+
+    def make(key):
+        kx, kq, ka, kb = jax.random.split(key, 4)
+        lead = (members,) if members else ()
+        x = jax.random.normal(kx, (*lead, T, din), jnp.float32).astype(jnp.bfloat16)
+        qk = quantize_kernel(jax.random.normal(kq, (din, dout), jnp.float32) / jnp.sqrt(din))
+        a = _factored(ka, din, 8, 4, jnp.bfloat16)
+        b = _factored(kb, 8, dout, 4, jnp.bfloat16)
+        if members:
+            per_member = lambda f, k: FactoredDelta(
+                f.w,
+                jax.random.normal(k, (members, *f.u.shape), jnp.float32).astype(f.u.dtype),
+                jnp.broadcast_to(f.v, (members, *f.v.shape)),
+                jnp.full((members,), f.c),
+            )
+            a, b = per_member(a, ka), per_member(b, kb)
+        return x, qk, a, b
+
+    def run(fn):
+        def one(x, qk, a, b):
+            return fn(x, qk, {"a": a, "b": b}, 2.0)
+
+        if not members:
+            return one
+        axes = FactoredDelta(None, 0, 0, 0)
+        return jax.vmap(one, in_axes=(0, None, axes, axes))
+
+    return Case(
+        "fused_qlora", label, make,
+        run(lambda x, qk, leaf, s: fused_qlora_dense(x, qk, leaf, s, use_pallas=True)),
+        run(xla_fused_qlora),
+        tol=4 * _BF16_EPS,
+        tol_reason="the XLA form rounds a_k, b_k and both partial products to "
+                   "bf16 before the sum; the kernel keeps f32 until one final "
+                   "rounding: four bf16 roundings apart",
+    )
+
+
+def _attention_case(label: str, B: int, nq: int, L: int, kv_len: Optional[int],
+                    H: int = 16, dh: int = 64, masked: bool = False) -> Case:
+    from ..ops.attention import decode_attention
+
+    def make(key):
+        kq, kk, kv, km = jax.random.split(key, 4)
+        bf = lambda k, s: jax.random.normal(k, s, jnp.float32).astype(jnp.bfloat16)
+        args = (bf(kq, (B, nq, H, dh)), bf(kk, (B, L, H, dh)), bf(kv, (B, L, H, dh)))
+        if masked:
+            # padded text: a valid prefix per row, never empty
+            n_valid = jax.random.randint(km, (B, 1), 1, L + 1)
+            args += (jnp.arange(L)[None, :] < n_valid,)
+        return args
+
+    def run(use_pallas):
+        def fn(q, k, v, mask=None):
+            return decode_attention(q, k, v, kv_len=kv_len, kv_mask=mask,
+                                    use_pallas=use_pallas)
+
+        return fn
+
+    return Case(
+        "decode_attention", label, make, run(True), run(False),
+        tol=2 * _BF16_EPS,
+        tol_reason="both sides hold the softmax in f32 and round the output "
+                   "to bf16 once; the online-softmax rescaling reorders the "
+                   "f32 sums, so results may round to neighbouring bf16 "
+                   "values: one spacing, 2^-7 of the largest magnitude",
+    )
+
+
+def _lora_case(label: str, T: int, din: int, dout: int) -> Case:
+    from ..ops.fused_lora import member_lora_delta, xla_member_lora_delta
+
+    def make(key):
+        kx, ka, kb = jax.random.split(key, 3)
+        x = jax.random.normal(kx, (T, din), jnp.float32).astype(jnp.bfloat16)
+        return x, _factored(ka, din, 8, 4, jnp.bfloat16), _factored(kb, 8, dout, 4, jnp.bfloat16)
+
+    return Case(
+        "member_lora_delta", label, make,
+        lambda x, a, b: member_lora_delta(x, a, b, 2.0, use_pallas=True),
+        lambda x, a, b: xla_member_lora_delta(x, a, b, 2.0),
+        tol=4 * _BF16_EPS,
+        tol_reason="the XLA form rounds a_k, b_k and x@a_k to bf16; the kernel "
+                   "keeps the chain in f32: up to four bf16 roundings apart",
+    )
+
+
+def _int8mm_case(label: str, T: int, din: int, dout: int) -> Case:
+    from ..ops.quant import quantize_kernel
+    from ..ops.quant_mm import int8_matmul, xla_int8_matmul
+
+    def make(key):
+        kx, kq = jax.random.split(key)
+        x = jax.random.normal(kx, (T, din), jnp.float32).astype(jnp.bfloat16)
+        qk = quantize_kernel(jax.random.normal(kq, (din, dout), jnp.float32) / jnp.sqrt(din))
+        return x, qk["q8"], qk["scale"]
+
+    return Case(
+        "int8_matmul", label, make,
+        lambda x, q8, s: int8_matmul(x, q8, s, use_pallas=True),
+        xla_int8_matmul,
+        tol=2 * _BF16_EPS,
+        tol_reason="the XLA form rounds the dequantized weights to bf16 "
+                   "before the dot, the kernel multiplies in f32: one operand "
+                   "rounding plus the output rounding",
+    )
+
+
+# VAR default geometry (models/var.VARConfig): 16 heads × 64, ten scales
+# 1,2,3,4,5,6,8,10,13,16 → queries pn² against the cache prefix written so
+# far, batch = 2 × prompts (CFG) — 4 prompts here, as the `ar` rung has.
+_VAR_SCALES = ((1, 1), (4, 5), (9, 14), (16, 30), (25, 55), (36, 91),
+               (64, 155), (100, 255), (169, 424), (256, 680))
+
+
+def cases() -> List[Case]:
+    out = [
+        # Sana-Sprint 1.6B (models/sana.SanaConfig): 32×32 latent tokens per
+        # image tile (reward_tile 1), d_model 2240, caption dim 2304 × 32
+        # synthesized tokens, AdaLN 6·d. The member-axis case is first: it is
+        # what pop_eval's lax.map(batch_size=1) lowers, the flagship call.
+        _qlora_case("sana attn to_q/k/v/out, member axis 1: x[1,1024,2240] @ s8[2240,2240]",
+                    1024, 2240, 2240, members=1),
+        _qlora_case("sana attn to_q/k/v/out: x[1024,2240] @ s8[2240,2240]", 1024, 2240, 2240),
+        _qlora_case("sana attn2 to_k/v on caption: x[32,2240] @ s8[2240,2240]", 32, 2240, 2240),
+        _qlora_case("sana caption_proj/linear_1: x[32,2304] @ s8[2304,2240]", 32, 2304, 2240),
+        _qlora_case("sana time_embed/linear: x[1,2240] @ s8[2240,13440]", 1, 2240, 13440),
+        _qlora_case("sana proj_out: x[1024,2240] @ s8[2240,32]", 1024, 2240, 32),
+    ]
+    out += [
+        _attention_case(f"var scale {i}: q[8,{nq},16,64] vs cache[8,680,16,64] kv_len {kv}",
+                        8, nq, 680, kv)
+        for i, (nq, kv) in reversed(list(enumerate(_VAR_SCALES)))
+    ]
+    out += [
+        # Infinity cross-attention (models/infinity.py): bool text mask; 16
+        # synthesized tokens (backends/infinity_backend) and T5's 512
+        _attention_case("infinity cross-attn: q[8,256,16,64] vs text[8,16,16,64] + mask",
+                        8, 256, 16, None, masked=True),
+        _attention_case("infinity cross-attn: q[8,256,16,64] vs text[8,512,16,64] + mask",
+                        8, 256, 512, None, masked=True),
+        _lora_case("sana attn site, float base: x[1024,2240], a[2240,8], b[8,2240]",
+                   1024, 2240, 2240),
+        # (the wider FFN 1×1 convs are not this kernel's: quant_mm._kernel_handles)
+        _int8mm_case("sana attn: x[1024,2240] @ s8[2240,2240]", 1024, 2240, 2240),
+    ]
+    return out
+
+
+def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str, Any]:
+    """Compile (and, with a chip, run and compare) one case. Raises whatever
+    tracing or Mosaic raises; a missed tolerance is reported in the record
+    (``ok`` false), not raised."""
+    rec: Dict[str, Any] = {"kernel": case.kernel, "case": case.label}
+    key = jax.random.PRNGKey(0)
+    if compile_only_device is not None:
+        from jax.sharding import SingleDeviceSharding
+
+        s = SingleDeviceSharding(compile_only_device)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            jax.eval_shape(case.make, key),
+        )
+        jax.jit(case.kernel_fn).lower(*args).compile()
+        return {**rec, "ok": True, "compiled_for": compile_only_device.device_kind}
+    args = jax.jit(case.make)(key)
+    got = jax.jit(case.kernel_fn)(*args).astype(jnp.float32)
+    ref = jax.jit(case.xla_fn)(*args).astype(jnp.float32)
+    diff = float(jnp.max(jnp.abs(got - ref)))
+    scale = float(jnp.max(jnp.abs(ref)))
+    rel = diff / max(scale, 1e-30)
+    return {
+        **rec, "max_abs_diff": diff, "max_abs_ref": scale, "rel": rel,
+        "tol": case.tol, "tol_reason": case.tol_reason,
+        "ok": bool(rel <= case.tol and jnp.all(jnp.isfinite(got))),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--kernels", default="",
+                    help="comma list of pallas_call names (default: all four)")
+    ap.add_argument("--compile_only", action="store_true",
+                    help="compile for a TPU v5e topology without a chip")
+    ap.add_argument("--out", default=None, help="also write the records here (JSONL)")
+    args = ap.parse_args(argv)
+    want = {k for k in args.kernels.split(",") if k}
+    device = None
+    if args.compile_only:
+        from jax.experimental import topologies
+
+        device = topologies.get_topology_desc(
+            topology_name="v5e:2x2", platform="tpu"
+        ).devices[0]
+    elif jax.default_backend() != "tpu":
+        print("kernel_check: no TPU backend (pass --compile_only for Mosaic's "
+              "verdict without a chip)", file=sys.stderr)
+        return 2
+    records = []
+    for case in cases():
+        if want and case.kernel not in want:
+            continue
+        try:
+            rec = run_case(case, device)
+        except Exception as e:  # the report is the point: record, go on
+            rec = {"kernel": case.kernel, "case": case.label, "ok": False,
+                   "error": f"{type(e).__name__}: {e}"[:2000],
+                   "traceback_tail": traceback.format_exc()[-1500:]}
+        records.append(rec)
+        print(json.dumps(rec), flush=True)
+    if args.out:
+        with open(args.out, "w") as f:
+            f.writelines(json.dumps(r) + "\n" for r in records)
+    return 0 if records and all(r["ok"] for r in records) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -12,7 +12,7 @@ Usage::
 
     # refresh the committed manifest from known-good runs
     python -m hyperscalees_t2i_tpu.tools.sentry baseline \\
-        --out SENTRY_BASELINE.json runs/good1 runs/good2 BENCH_r05.json
+        --out SENTRY_BASELINE.json runs/good1 runs/good2 bench_runs/BENCH.json
 
 Sources are run dirs (metrics.jsonl + programs.jsonl + CAPACITY*.json +
 CALIB*.json + QUALITY*.json), ``*.jsonl`` ledgers (committed

@@ -25,14 +25,19 @@ owns everything around that program:
   membership change lands at the next tick boundary, riding the same
   due-boundary discipline as the trainer's checkpoint/rollback machinery.
 
-Parity contract (what is and isn't bit-identical — README runbook):
-per-job REWARD ROWS are bitwise-identical to the job's solo run (all their
-reductions live inside the shared member-lane ``lax.map`` body; σ enters as
-a one-rounding f32 argument — ``trainer.fleet_scalar_args``). The θ-update
-outputs are rounding-tight, NOT bitwise: the tiny promptnorm/standardization
-reductions sit in a different XLA fusion context than the solo program's and
-XLA does not pin reduction association across programs — the same documented
-boundary as ``reward_tile`` and the pod eval split.
+Parity contract (README runbook): per-job REWARD ROWS agree with the job's
+solo run within :data:`ROWS_TOL_ULPS` float32 ulps of the largest row value
+(:func:`reward_rows_close`). Every per-job input is exactly the solo one —
+the key split, the noise draw (counter-based, no cross-job reduction), σ as
+a one-rounding f32 argument (``trainer.fleet_scalar_args``) — but the fused
+step and the solo step are different XLA programs, and XLA pins neither
+fusion nor reduction association across programs: the rows differ by
+rounding (measured 1–2 ulps on XLA:CPU, jax 0.9.0, PR 21; an earlier jax
+happened to compile the lane body identically in both and they hashed
+equal). A real divergence — another job's key, another member's noise —
+moves a row by ~1e-2, five orders above the bound. The θ-update outputs are
+rounding-tight for the same reason — the documented boundary of
+``reward_tile`` and the pod eval split.
 """
 
 from __future__ import annotations
@@ -98,10 +103,31 @@ def job_lane_spans(width: int, pop_size: int) -> List[Tuple[int, int]]:
 
 def reward_rows_digest(rows) -> str:
     """Canonical content digest of one job's ``[pop, B]`` combined reward
-    rows — the bitwise-parity surface bench --fleet / CI compare between
-    fused and solo runs. f32 little-endian bytes in C order, sha256."""
+    rows — an identifier for logs and artifacts (same program, same rows →
+    same digest). NOT the fleet-vs-solo parity test: that is
+    :func:`reward_rows_close`. f32 little-endian bytes in C order, sha256."""
     a = np.ascontiguousarray(np.asarray(rows, np.float32))
     return hashlib.sha256(a.astype("<f4", copy=False).tobytes()).hexdigest()
+
+
+# Fleet-vs-solo reward rows: the bound, in float32 ulps (2^-24 relative) of
+# the largest solo row value. Two XLA programs computing the same rows may
+# associate and fuse differently, each choice moving a result by an ulp or
+# two; 16 leaves room over the 1–2 measured, and is ~10^5 below what any
+# mix-up of keys, noise slabs or σ produces (module docstring).
+ROWS_TOL_ULPS = 16
+
+
+def reward_rows_close(rows, solo_rows) -> Tuple[bool, float]:
+    """(within the contract?, max |rows − solo_rows|) for one job's
+    ``[pop, B]`` combined reward rows against its solo run's."""
+    a = np.asarray(rows, np.float32)
+    b = np.asarray(solo_rows, np.float32)
+    if a.shape != b.shape:
+        return False, float("inf")
+    diff = float(np.max(np.abs(a - b))) if a.size else 0.0
+    atol = ROWS_TOL_ULPS * 2.0 ** -24 * float(np.max(np.abs(b))) if b.size else 0.0
+    return bool(np.all(np.isfinite(a)) and diff <= atol), diff
 
 
 def make_solo_reward_rows(backend, reward_fn, tc) -> Callable:
@@ -111,10 +137,9 @@ def make_solo_reward_rows(backend, reward_fn, tc) -> Callable:
     same population evaluator) and returns the raw combined reward rows.
 
     The full solo step never exposes its rows (its outputs are the update
-    products), so parity checks run THIS program for the solo side. Its
-    rows match the fused fleet step's ``fleet_reward_rows`` bitwise because
-    every reward-row reduction lives inside the member-lane ``lax.map``
-    body, whose compiled association is the same in both programs.
+    products), so parity checks run THIS program for the solo side and
+    hold the fused fleet step's ``fleet_reward_rows`` to it with
+    :func:`reward_rows_close`.
     """
     import jax
 
@@ -256,7 +281,7 @@ class FleetJobSpec:
 class _Job:
     __slots__ = ("spec", "index", "theta", "prev_delta", "epoch", "end_epoch",
                  "store", "done", "leave_requested", "last_scalars",
-                 "rows_digest", "rows_digests", "admission")
+                 "rows_digest", "rows_digests", "first_rows", "admission")
 
     def __init__(self, spec: FleetJobSpec, index: int, theta, store, epoch: int,
                  prev_delta, admission: Dict[str, Any]):
@@ -273,11 +298,13 @@ class _Job:
         self.last_scalars: Dict[str, Any] = {}
         self.rows_digest: Optional[str] = None
         # digest per ADVANCED epoch (index e = the rows that produced the
-        # e→e+1 update). Index 0 is the bitwise fleet-vs-solo parity surface:
-        # init θ is identical, so row parity is exact; later epochs run from
-        # rounding-tight (not bitwise) θ, so their rows drift in the last ulp
-        # — the documented per-step contract (module docstring).
+        # e→e+1 update) — content identifiers for the logs
         self.rows_digests: List[str] = []
+        # the rows of the first epoch this process advanced: from a fresh
+        # start the fleet-vs-solo parity surface (init θ is identical, so
+        # the rows must be reward_rows_close to the solo program's; later
+        # epochs run from rounding-tight θ and drift with it)
+        self.first_rows: Optional[np.ndarray] = None
         self.admission = admission
 
 
@@ -566,6 +593,8 @@ class FleetScheduler:
             job.epoch += 1
             job.rows_digest = reward_rows_digest(rows[j])
             job.rows_digests.append(job.rows_digest)
+            if job.first_rows is None:
+                job.first_rows = np.array(rows[j], np.float32)
             prefix = f"job{job.index}"
             scalars: Dict[str, Any] = {}
             for k, v in metrics.items():
@@ -607,6 +636,7 @@ class FleetScheduler:
         return {"job_id": job_id, "index": j.index, "epoch": j.epoch,
                 "end_epoch": j.end_epoch, "done": j.done,
                 "rows_digest": j.rows_digest, "rows_digests": list(j.rows_digests),
+                "first_rows": j.first_rows,
                 "admission": j.admission,
                 "scalars": dict(j.last_scalars)}
 

@@ -1,4 +1,4 @@
-"""GGUF single-file ingestion (VERDICT.md missing #4): the container the
+"""GGUF single-file ingestion: the container the
 reference actually loads for its quantized Z-Image transformer
 (``/root/reference/models/zImageTurbo.py:140-197`` via diffusers'
 ``GGUFQuantizationConfig``).

@@ -8,13 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    # a real submodule on every supported jax, but NOT re-exported as a lazy
-    # attribute on 0.4.x — plain `jax.export` raises AttributeError there
-    # (the pre-PR2 failure mode of the lowering test below)
-    import jax.export as jax_export
-except ImportError:  # pragma: no cover - much older jax only
-    jax_export = None
+from jax import export as jax_export
 
 from hyperscalees_t2i_tpu.ops.attention import (
     _naive_masked_attention,
@@ -104,8 +98,6 @@ def test_online_softmax_multi_kv_block(block_kv):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.skipif(jax_export is None,
-                    reason="jax.export module unavailable on this jax build")
 def test_flash_kernel_lowers_for_tpu_at_infinity_1m_geometry():
     """The kernel must pass Mosaic TPU lowering at the Infinity "1M" preset's
     final-scale geometry (64²=4096 queries, ~10k-position KV cache, dh=128 —
@@ -117,6 +109,21 @@ def test_flash_kernel_lowers_for_tpu_at_infinity_1m_geometry():
     v = jax.ShapeDtypeStruct((B, L, H, dh), jnp.bfloat16)
     f = jax.jit(lambda q, k, v: decode_attention(q, k, v, kv_len=9936, use_pallas=True))
     exp = jax_export.export(f, platforms=["tpu"])(q, k, v)
+    assert len(exp.mlir_module_serialized) > 0
+
+
+def test_masked_kernel_lowers_for_tpu():
+    """The boolean text mask of Infinity's cross-attention must pass the TPU
+    lowering: as a (1, block_kv) block out of a [B, L] bool array it did not
+    (a block's second-to-last dim must be a multiple of 8 or the whole
+    axis) — found by the first v5e compile of the kernel, PR 21."""
+    B, nq, L, H, dh = 8, 256, 16, 16, 64
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    f = jax.jit(lambda q, k, v, m: decode_attention(q, k, v, kv_mask=m, use_pallas=True))
+    exp = jax_export.export(f, platforms=["tpu"])(
+        sds(B, nq, H, dh), sds(B, L, H, dh), sds(B, L, H, dh),
+        jax.ShapeDtypeStruct((B, L), jnp.bool_),
+    )
     assert len(exp.mlir_module_serialized) > 0
 
 

@@ -1,73 +1,40 @@
-"""bench.py --compile_cache (round 15): the window-to-number path.
+"""The persistent compile cache is placed from outside, by one rule
+(utils/compile_cache.py): ``JAX_COMPILATION_CACHE_DIR`` set → the cache is
+there and nothing points it elsewhere; unset → ``<checkout>/.jax_cache``,
+derived from the file's own location. A chip call starts on a fresh machine:
+unless the directory can be named by the runner and is the same on every
+run, every call recompiles the flagship step.
 
-A rare TPU window must spend its minutes on measured steps, not recompiles
-— ``--compile_cache DIR`` pins the persistent jax compilation cache at DIR
-via the environment (the only channel that reaches a child BEFORE its jax
-import, the --scaling XLA_FLAGS discipline). Under test on CPU:
+Under test on CPU, each case in a fresh process (the cache directory is read
+once per process):
 
-- the argv/env mechanics (``bench.apply_compile_cache_argv``), and
-- the cache-hit contract end to end: two fresh processes compiling the
-  same program against one cache dir — the second run's backend-compile
-  span must collapse to ~0 (deserialization), proven here with the same
-  AOT ``lower()``/``compile()`` split ``bench.run_rung`` times. The CI
-  ``compile_cache_smoke`` job asserts the same collapse on two full tiny
-  bench runs.
+- both branches of the helper, with environment and ``jax.config`` agreeing
+  afterwards (so ``obs.metrics.compile_cache_entries`` counts the directory
+  in use);
+- the hit end to end: two processes compiling the same program against one
+  directory named only through the environment variable — only that
+  directory gains entries and the second process's backend-compile span
+  collapses (deserialization), with the same ``lower()``/``compile()`` split
+  the trainer and bench time.
 """
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
 REPO = Path(__file__).resolve().parent.parent
 
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench", REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_apply_compile_cache_argv(tmp_path):
-    bench = _load_bench()
-    env = {}
-    cache = tmp_path / "cc"
-    argv = bench.apply_compile_cache_argv(
-        ["--rung", "tiny", "--compile_cache", str(cache)], environ=env
-    )
-    assert argv == ["--rung", "tiny"]  # flag stripped wherever it appears
-    assert env["JAX_COMPILATION_CACHE_DIR"] == str(cache)
-    assert env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
-    assert cache.is_dir()  # created up front so the first child can write
-    # flag-free argv passes through untouched, env untouched
-    env2 = {}
-    assert bench.apply_compile_cache_argv(["--scaling"], environ=env2) == ["--scaling"]
-    assert env2 == {}
-    with pytest.raises(SystemExit, match="directory"):
-        bench.apply_compile_cache_argv(["--compile_cache"], environ={})
-
-
-# the child pays one jax import + one small-program compile; both runs use
-# bench's own env mechanism so the test proves the --compile_cache channel,
-# not just jax's cache
 _CHILD = r"""
-import json, sys, time
+import json, os, sys, time
 sys.path.insert(0, {repo!r})
-import importlib.util
-spec = importlib.util.spec_from_file_location("bench", {repo!r} + "/bench.py")
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-bench.apply_compile_cache_argv(["--compile_cache", {cache!r}])
-import os
+from hyperscalees_t2i_tpu.utils.compile_cache import place_compile_cache
+placed = place_compile_cache()
 import jax
 import jax.numpy as jnp
-jax.config.update("jax_platforms", "cpu")
+from hyperscalees_t2i_tpu.obs.metrics import compile_cache_entries
 
 def prog(x):
     y = x
@@ -79,31 +46,79 @@ x = jnp.ones((256, 256))
 t0 = time.perf_counter()
 lowered = jax.jit(prog).lower(x)
 t1 = time.perf_counter()
-compiled = lowered.compile()
+lowered.compile()
 t2 = time.perf_counter()
 print(json.dumps({{
-    "lowering_s": t1 - t0, "compile_span_s": t2 - t1,
-    "entries": len(os.listdir({cache!r})),
+    "placed": placed,
+    "env": os.environ["JAX_COMPILATION_CACHE_DIR"],
+    "config": jax.config.jax_compilation_cache_dir,
+    "compile_span_s": t2 - t1,
+    "entries": compile_cache_entries(),
 }}))
 """
 
 
-def test_cache_hit_collapses_second_compile_span(tmp_path):
-    cache = str(tmp_path / "cc")
-    runs = []
-    for i in range(2):
-        proc = subprocess.run(
-            [sys.executable, "-c", _CHILD.format(repo=str(REPO), cache=cache)],
-            capture_output=True, text=True, timeout=300,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
-    first, second = runs
+def _run(repo: Path, cache_env) -> dict:
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    if cache_env is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_env)
+    proc = subprocess.run(
+        [sys.executable, "-c", _CHILD.format(repo=str(repo))],
+        capture_output=True, text=True, timeout=300, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_env_set_only_that_directory_gains_entries_and_second_run_hits(tmp_path):
+    cache = tmp_path / "named" / "cc"  # created by the helper
+    default = REPO / ".jax_cache"
+    count = lambda: len(os.listdir(default)) if default.exists() else 0
+    before = count()
+    first, second = _run(REPO, cache), _run(REPO, cache)
+    assert count() == before  # no code pointed the cache anywhere else
+    for r in (first, second):
+        assert r["placed"] == r["env"] == r["config"] == str(cache)
     assert first["entries"] > 0, "first run never populated the cache"
     assert second["entries"] >= first["entries"]
     # the contract: the second run DESERIALIZES instead of compiling. The
     # miss side of this program measures ~1s+ on CPU; a hit is ~ms. The
     # bound is generous for shared-runner jitter while still far below any
     # real compile.
-    assert second["compile_span_s"] < max(0.25, 0.3 * first["compile_span_s"]), runs
+    assert second["compile_span_s"] < max(0.25, 0.3 * first["compile_span_s"]), (
+        first, second)
+
+
+def test_env_unset_cache_is_checkout_relative_wherever_the_checkout_lives(tmp_path):
+    """Unset → ``<checkout>/.jax_cache`` from the helper file's own location:
+    a copy of the package somewhere else caches under THAT copy, never under
+    a hard-coded path, a temp name, a pid or a time."""
+    elsewhere = tmp_path / "copy"
+    shutil.copytree(
+        REPO / "hyperscalees_t2i_tpu", elsewhere / "hyperscalees_t2i_tpu",
+        ignore=shutil.ignore_patterns("__pycache__"),
+    )
+    r = _run(elsewhere, None)
+    want = str(elsewhere / ".jax_cache")
+    assert r["placed"] == r["env"] == r["config"] == want
+    assert r["entries"] > 0 and os.listdir(want)
+    assert _run(elsewhere, None)["placed"] == want  # the same path every run
+
+
+def test_no_entry_point_hardcodes_a_cache_path():
+    """The variable is assigned in exactly one place, and no absolute
+    checkout path survives anywhere in the tree."""
+    assigning, absolute = [], []
+    for path in [REPO / "bench.py", REPO / "chip_smoke.py", REPO / "__graft_entry__.py",
+                 *(REPO / "hyperscalees_t2i_tpu").rglob("*.py")]:
+        text = path.read_text()
+        if '"/root' + "/repo" in text:  # (split: this file must not match itself)
+            absolute.append(path.name)
+        if ('environ["JAX_COMPILATION_CACHE_DIR"] =' in text
+                or 'environ[ENV] =' in text
+                or '"jax_compilation_cache_dir"' in text):
+            assigning.append(path.name)
+    assert absolute == []
+    assert assigning == ["compile_cache.py"], assigning

@@ -8,9 +8,10 @@ The tentpole contract under test, at toy geometry:
 - ``jobwise_prompt_normalized_scores`` standardizes each job against its
   OWN statistics (never pooled across jobs);
 - ONE ``make_fleet_step`` execution reproduces each job's solo reward rows
-  BITWISE (per-step, given identical θ) while the update outputs match the
-  solo step to rounding (XLA does not pin reduction association across
-  programs — the documented boundary);
+  within the written tolerance (``fleet.ROWS_TOL_ULPS``, per step, given
+  identical θ) and the update outputs to rounding — XLA pins neither fusion
+  nor reduction association across programs, so a cross-program hash is not
+  a contract it can keep (it held under one jax and broke under the next);
 - the ``FleetScheduler`` enforces cohort admission, interleaves fair-share
   ticks, fans per-job telemetry into ``job<j>/…`` streams, and keeps
   per-job checkpoint slots independently restorable;
@@ -40,7 +41,7 @@ from hyperscalees_t2i_tpu.train.fleet import (
     cohort_mismatches,
     job_lane_spans,
     make_solo_reward_rows,
-    reward_rows_digest,
+    reward_rows_close,
 )
 from hyperscalees_t2i_tpu.train.trainer import (
     fleet_scalar_args,
@@ -121,7 +122,7 @@ def test_jobwise_promptnorm_refuses_wrong_rank():
 
 
 # ---------------------------------------------------------------------------
-# the fused step vs solo: bitwise rows, rounding-tight update
+# the fused step vs solo: rows within tolerance, rounding-tight update
 # ---------------------------------------------------------------------------
 
 def _fleet_tc(sigma, lr_scale, seed, run_dir):
@@ -148,7 +149,7 @@ def test_fleet_step_matches_solo_rows_bitwise_update_close(tmp_path):
     ]
     keys = [epoch_key(t.seed, 0) for t in tcs]
 
-    # solo references: reward rows (the bitwise surface) + stateful update
+    # solo references: reward rows (the parity surface) + stateful update
     solo_rows, solo_thetas = [], []
     for t, th, k in zip(tcs, thetas, keys):
         rows_fn = make_solo_reward_rows(backend, brightness_reward, t)
@@ -180,10 +181,13 @@ def test_fleet_step_matches_solo_rows_bitwise_update_close(tmp_path):
     assert opt_scores.shape[0] == 2
 
     for j in range(2):
-        # reward rows: BITWISE — all row reductions run inside the lane body
-        assert reward_rows_digest(rows[j]) == reward_rows_digest(solo_rows[j]), (
-            f"job {j} reward rows diverged from solo"
-        )
+        # reward rows: within the written tolerance of the solo program's
+        # (train/fleet.ROWS_TOL_ULPS — two XLA programs, rounding apart)
+        ok, diff = reward_rows_close(rows[j], solo_rows[j])
+        assert ok, f"job {j} reward rows diverged from solo: max|diff|={diff}"
+        # ... and the bound still tells jobs apart: the OTHER job's rows
+        # (its key, its noise, its σ) are far outside it
+        assert not reward_rows_close(rows[j], solo_rows[1 - j])[0]
         # updated θ: rounding-tight, not bitwise (cross-program reduction
         # association is XLA's to choose — the documented boundary)
         got = jax.device_get(lane_slice(theta_new, j))
@@ -268,8 +272,8 @@ def test_fleet_scheduler_end_to_end(tmp_path):
     assert sa["done"] and sb["done"]
     assert sa["epoch"] == 2 and sb["epoch"] == 2
 
-    # epoch-0 reward rows: BITWISE equal to each job's solo rows (identical
-    # init θ — later epochs drift in the last ulp because θ drifted)
+    # epoch-0 reward rows: within tolerance of each job's solo rows
+    # (identical init θ — later epochs drift because θ drifted)
     frozen = make_frozen(backend, brightness_reward)
     info0 = backend.step_info(0, 2, 1)
     ids0 = jnp.asarray(np.asarray(info0.flat_ids, np.int32))
@@ -279,8 +283,10 @@ def test_fleet_scheduler_end_to_end(tmp_path):
             jax.random.fold_in(jax.random.PRNGKey(tc.seed), 17)
         )
         rows = rows_fn(frozen, theta0, ids0, epoch_key(tc.seed, 0))
-        dig = reward_rows_digest(np.asarray(jax.device_get(rows)))
-        assert sched.job_state(jid)["rows_digests"][0] == dig, jid
+        ok, diff = reward_rows_close(
+            sched.job_state(jid)["first_rows"], np.asarray(jax.device_get(rows))
+        )
+        assert ok, (jid, diff)
 
     # per-job slots restore independently, no fleet state needed
     template = backend.init_theta(jax.random.PRNGKey(0))

@@ -11,35 +11,49 @@ import jax.numpy as jnp
 # -- mfu -------------------------------------------------------------------
 
 
-def test_device_peak_flops_matches_on_kind():
+def test_device_peak_flops_matches_on_exact_kind():
     from hyperscalees_t2i_tpu.utils import mfu
 
     class FakeDev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
     assert mfu.device_peak_flops(FakeDev("TPU v5 lite")) == 197e12
-    assert mfu.device_peak_flops(FakeDev("TPU v5p chip")) == 459e12
+    assert mfu.device_peak_flops(FakeDev("TPU v5p")) == 459e12
     assert mfu.device_peak_flops(FakeDev("TPU v6e")) == 918e12
-    assert mfu.device_peak_flops(FakeDev("NVIDIA H100")) is None  # unknown → None
+    # off the TPU nothing is measured: None, the gates stay unarmed
+    assert mfu.device_peak_flops(FakeDev("cpu", "cpu")) is None
+    assert mfu.device_peak_flops(FakeDev("NVIDIA H100", "gpu")) is None
+    # a TPU the table does not name exactly is an error, never a v5p default
+    # (the old substring table read any unknown "v5…" kind as 459 TFLOP/s)
+    for kind in ("TPU v5 lite pod", "TPU v5x", "TPU7x", ""):
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            mfu.device_peak_flops(FakeDev(kind))
 
 
 def test_hbm_tables_match_on_kind():
     """The roofline's second and third axes (utils/mfu): HBM bandwidth and
-    capacity resolve by device_kind substring, same gate as the FLOPs table."""
+    capacity resolve by exact device_kind string or chip name, one table."""
     from hyperscalees_t2i_tpu.utils import mfu
 
     assert mfu.hbm_bw_for_kind("TPU v5 lite") == 819e9
-    assert mfu.hbm_bw_for_kind("TPU v5p chip") == 2765e9
+    assert mfu.hbm_bw_for_kind("TPU v5p") == 2765e9
     assert mfu.hbm_bytes_for_kind("TPU v5e") == 16e9
+    assert mfu.hbm_bytes_for_kind("v5e") == 16e9  # chip name (preflight --chip)
     assert mfu.hbm_bytes_for_kind("TPU v4") == 32e9
     assert mfu.hbm_bw_for_kind("NVIDIA H100") is None
+    assert mfu.hbm_bw_for_kind("TPU v5p chip") is None  # no substring matching
     assert mfu.hbm_bytes_for_kind("") is None
+    # every device_kind string resolves to a chip in the table
+    assert set(mfu.DEVICE_KINDS.values()) <= set(mfu.CHIPS)
 
     class FakeDev:
         device_kind = "TPU v6e"
+        platform = "tpu"
 
     assert mfu.device_hbm_bandwidth(FakeDev()) == 1640e9
+    assert mfu.device_hbm_bytes(FakeDev()) == 32e9
 
 
 def test_executable_flops_and_formula():

@@ -434,25 +434,40 @@ def test_breaker_quarantines_store_io_faults_then_recovers(backend, template):
     assert snap["obs/serve_request_errors"] == 3  # 2 faults + 1 shed
 
 
-def test_slow_dispatch_fault_inflates_ewma(backend, template):
+def test_slow_dispatch_fault_inflates_ewma(backend, template, monkeypatch):
     from hyperscalees_t2i_tpu.resilience.faultinject import (
-        FaultPlan, set_fault_plan,
+        SLOW_FAULT_ENV, FaultPlan, set_fault_plan,
+    )
+    from hyperscalees_t2i_tpu.resilience.telemetry import (
+        get_resilience_registry, set_resilience_registry,
     )
 
     set_registry(MetricsRegistry())
+    set_resilience_registry(None)
     eng = _engine(backend, template, overload=OverloadConfig())
     eng.submit("a", [0], seed=1)
     eng.flush()
     baseline = eng._governor.ewma.get((1, None))
     assert baseline is not None
-    set_fault_plan(FaultPlan.parse("slow_dispatch*1"))
+    # No wall-clock margin: the straggle is sized from the baseline itself.
+    # time.sleep never returns early, so the faulted dispatch lasts at least
+    # the straggle, which exceeds the baseline — the EWMA (a convex mix of
+    # the two) must rise, however slow or fast this machine is.
+    monkeypatch.setenv(SLOW_FAULT_ENV, repr(2.0 * baseline + 0.01))
+    plan = FaultPlan.parse("slow_dispatch*1")
+    set_fault_plan(plan)
     try:
         eng.submit("a", [0], seed=2)
         eng.flush()
+        inflated = eng._governor.ewma.get((1, None))
+        eng.submit("a", [0], seed=3)  # the plan is spent: no second straggle
+        eng.flush()
     finally:
         set_fault_plan(None)
-    # the injected 0.25 s straggle dominates a tiny-rung dispatch
-    assert eng._governor.ewma.get((1, None)) > baseline + 0.05
+    assert inflated > baseline
+    # exactly one injected fault was observed over the two dispatches
+    assert plan.serve_faults["slow_dispatch"] == 0
+    assert get_resilience_registry().snapshot()["resilience/faults_injected"] == 1
 
 
 # ---------------------------------------------------------------------------

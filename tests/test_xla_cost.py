@@ -352,7 +352,7 @@ def test_ici_bandwidth_table():
     from hyperscalees_t2i_tpu.utils.mfu import ici_bw_for_kind
 
     assert ici_bw_for_kind("TPU v5 lite") == 200e9
-    assert ici_bw_for_kind("TPU v5p chip") == 600e9
+    assert ici_bw_for_kind("TPU v5p") == 600e9
     assert ici_bw_for_kind("cpu") is None
     assert ici_bw_for_kind("") is None
 
