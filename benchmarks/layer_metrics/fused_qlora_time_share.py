@@ -1,0 +1,8 @@
+"""Device time of events named ``fused_qlora`` over the device's busy time."""
+from ._shared import kernel_time_share
+
+LAYER, UNIT, SOURCE, MOVES = "kernels", "%", "device_trace", "images_per_s_per_chip"
+
+
+def read(rec):
+    return kernel_time_share(rec, "fused_qlora")
