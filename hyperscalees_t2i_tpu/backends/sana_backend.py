@@ -19,6 +19,7 @@ import numpy as np
 
 from ..lora import LoRASpec, init_lora
 from ..models import dcae, sana
+from ..obs import block_if_tracing, span as obs_span
 from .base import StepInfo, default_step_info
 
 Pytree = Any
@@ -74,11 +75,15 @@ class SanaBackend:
                     out["vae"] = dcae.init_decoder(kv, self.cfg.vae)
                 return out
 
-            out = jax.jit(init)(jax.random.PRNGKey(self.cfg.seed_params))
+            # one program, so one span: the DiT's and the decoder's weights
+            with obs_span("init_params"):
+                out = block_if_tracing(jax.jit(init)(jax.random.PRNGKey(self.cfg.seed_params)))
             self.params = out.get("params", self.params)
             self.vae_params = out.get("vae", self.vae_params)
         if self.prompt_embeds is None:
-            self._load_prompts()
+            with obs_span("load_prompts"):
+                self._load_prompts()
+                block_if_tracing((self.prompt_embeds, self.prompt_mask))
 
     def _load_prompts(self) -> None:
         """Load an encoded-prompt cache (reference ``_load_or_encode_prompts``,
@@ -163,23 +168,25 @@ class SanaBackend:
         embeds = frozen["prompt_embeds"][flat_ids]
         mask = frozen["prompt_mask"][flat_ids]
         hw = (cfg.height_latent, cfg.width_latent)
-        if cfg.backend_mode == "pipeline":
-            latents = sana.multistep_generate(
-                frozen["params"], cfg.model, embeds, mask, key,
-                guidance_scale=cfg.guidance_scale, num_steps=cfg.num_inference_steps,
-                latent_hw=hw, lora=theta, lora_scale=self.lora_scale,
-                item_index=item_index,
-            )
-        else:
-            latents = sana.one_step_generate(
-                frozen["params"], cfg.model, embeds, mask, key,
-                guidance_scale=cfg.guidance_scale, latent_hw=hw,
-                lora=theta, lora_scale=self.lora_scale,
-                item_index=item_index,
-            )
+        with jax.named_scope("generate"):
+            if cfg.backend_mode == "pipeline":
+                latents = sana.multistep_generate(
+                    frozen["params"], cfg.model, embeds, mask, key,
+                    guidance_scale=cfg.guidance_scale, num_steps=cfg.num_inference_steps,
+                    latent_hw=hw, lora=theta, lora_scale=self.lora_scale,
+                    item_index=item_index,
+                )
+            else:
+                latents = sana.one_step_generate(
+                    frozen["params"], cfg.model, embeds, mask, key,
+                    guidance_scale=cfg.guidance_scale, latent_hw=hw,
+                    lora=theta, lora_scale=self.lora_scale,
+                    item_index=item_index,
+                )
         if not cfg.decode_images:
             return latents
-        return dcae.decode(frozen["vae"], cfg.vae, latents / cfg.vae.scaling_factor)
+        with jax.named_scope("decode"):
+            return dcae.decode(frozen["vae"], cfg.vae, latents / cfg.vae.scaling_factor)
 
     def generate(self, theta: Pytree, flat_ids: jax.Array, key: jax.Array) -> jax.Array:
         return self.generate_p(self.frozen, theta, flat_ids, key)

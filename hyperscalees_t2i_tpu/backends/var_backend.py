@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..lora import LoRASpec, init_lora
 from ..models import var as var_mod
+from ..obs import block_if_tracing, span as obs_span
 from .base import StepInfo, default_step_info
 
 Pytree = Any
@@ -74,16 +75,18 @@ class VarBackend:
         self._spec = LoRASpec(rank=cfg.lora_r, alpha=cfg.lora_alpha, targets=cfg.lora_targets)
         pool = cfg.class_pool or tuple(range(cfg.model.num_classes))
         self.class_pool: Tuple[int, ...] = tuple(int(c) for c in pool)
-        names = load_class_names(cfg.model.num_classes, cfg.labels_path)
+        with obs_span("load_prompts"):
+            names = load_class_names(cfg.model.num_classes, cfg.labels_path)
         # catalog item i ↔ class self.class_pool[i]; prompt text for rewards
         self.prompts = [f"a photo of {names[c]}" for c in self.class_pool]
         self._pool_arr = jnp.asarray(self.class_pool, jnp.int32)
 
     def setup(self) -> None:
         if self.params is None:
-            self.params = var_mod.init_var(
-                jax.random.PRNGKey(self.cfg.seed_params), self.cfg.model
-            )
+            with obs_span("init_params"):
+                self.params = block_if_tracing(var_mod.init_var(
+                    jax.random.PRNGKey(self.cfg.seed_params), self.cfg.model
+                ))
 
     def init_theta(self, key: jax.Array) -> Pytree:
         return init_lora(key, self.params, self._spec)

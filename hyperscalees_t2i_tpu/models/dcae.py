@@ -111,17 +111,18 @@ def _decode_stage(stage: Params, x: jax.Array, cfg: DCAEConfig, si: int) -> jax.
     Factored out of :func:`decode` so each stage can be a remat boundary —
     the stage interiors at 512/1024px are the deepest activation temps of
     the whole generate→reward program."""
-    if si > 0:
-        up = nn.conv2d(stage["up"], x)
-        # channel-duplicating shortcut: repeat input to 4× channels, shuffle up.
-        rep = up.shape[-1] // x.shape[-1]
-        shortcut = jnp.repeat(x, rep, axis=-1) if rep > 0 else up
-        x = nn.depth_to_space(up + shortcut, 2)
-    for block in stage["blocks"]:
-        if "mla" in block:
-            x = _lite_mla(block["mla"], x, cfg.attn_heads)
-        else:
-            x = _res_block(block["res"], x)
+    with jax.named_scope(f"stage{si}"):  # device-time scope (obs/xla_cost.INNER_SCOPES)
+        if si > 0:
+            up = nn.conv2d(stage["up"], x)
+            # channel-duplicating shortcut: repeat input to 4× channels, shuffle up.
+            rep = up.shape[-1] // x.shape[-1]
+            shortcut = jnp.repeat(x, rep, axis=-1) if rep > 0 else up
+            x = nn.depth_to_space(up + shortcut, 2)
+        for block in stage["blocks"]:
+            if "mla" in block:
+                x = _lite_mla(block["mla"], x, cfg.attn_heads)
+            else:
+                x = _res_block(block["res"], x)
     return nn.remat_name(x, cfg.remat, "dcae_stage")
 
 

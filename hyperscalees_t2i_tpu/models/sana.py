@@ -151,29 +151,31 @@ def sana_forward(
     hw = (H // p, W // p)
     dt = cfg.compute_dtype
 
-    x = nn.conv2d(params["patch_embed"], latents.astype(dt), stride=p)
-    x = x.reshape(B, hw[0] * hw[1], d)
+    # device-time scopes (obs/xla_cost.INNER_SCOPES): names only
+    with jax.named_scope("dit_embed_out"):
+        x = nn.conv2d(params["patch_embed"], latents.astype(dt), stride=p)
+        x = x.reshape(B, hw[0] * hw[1], d)
 
-    # --- AdaLN-single conditioning (timestep ⊕ guidance) -------------------
-    t_emb = nn.mlp_embedder(
-        params["time_embed"]["timestep"], nn.timestep_embedding(timestep, cfg.time_freq_dim)
-    )
-    if cfg.guidance_embeds:
-        g = guidance if guidance is not None else jnp.zeros((B,), jnp.float32)
-        t_emb = t_emb + nn.mlp_embedder(
-            params["time_embed"]["guidance"], nn.timestep_embedding(g, cfg.time_freq_dim)
+        # --- AdaLN-single conditioning (timestep ⊕ guidance) ---------------
+        t_emb = nn.mlp_embedder(
+            params["time_embed"]["timestep"], nn.timestep_embedding(timestep, cfg.time_freq_dim)
         )
-    shared6 = nn.dense(
-        params["time_embed"]["linear"],
-        jax.nn.silu(t_emb),
-        lookup(lora, "time_embed/linear"),
-        lora_scale,
-    ).reshape(B, 6, d)
+        if cfg.guidance_embeds:
+            g = guidance if guidance is not None else jnp.zeros((B,), jnp.float32)
+            t_emb = t_emb + nn.mlp_embedder(
+                params["time_embed"]["guidance"], nn.timestep_embedding(g, cfg.time_freq_dim)
+            )
+        shared6 = nn.dense(
+            params["time_embed"]["linear"],
+            jax.nn.silu(t_emb),
+            lookup(lora, "time_embed/linear"),
+            lora_scale,
+        ).reshape(B, 6, d)
 
-    # --- caption projection -------------------------------------------------
-    c = nn.rms_norm(caption.astype(dt), params["caption_norm"])
-    c = nn.dense(params["caption_proj"]["linear_1"], c, lookup(lora, "caption_proj/linear_1"), lora_scale)
-    c = nn.dense(params["caption_proj"]["linear_2"], jax.nn.silu(c), lookup(lora, "caption_proj/linear_2"), lora_scale)
+        # --- caption projection ---------------------------------------------
+        c = nn.rms_norm(caption.astype(dt), params["caption_norm"])
+        c = nn.dense(params["caption_proj"]["linear_1"], c, lookup(lora, "caption_proj/linear_1"), lora_scale)
+        c = nn.dense(params["caption_proj"]["linear_2"], jax.nn.silu(c), lookup(lora, "caption_proj/linear_2"), lora_scale)
 
     # --- blocks: lax.scan over stacked layers -------------------------------
     block_params = params["blocks"]
@@ -198,31 +200,34 @@ def sana_forward(
         ]
 
         # self attention: ReLU linear attention (no L×L matrix)
-        h = nn.layer_norm(xc) * (1 + scale_msa) + shift_msa
-        q = _split_heads(nn.dense(bp["attn1"]["to_q"], h, bl.get("attn1/to_q"), lora_scale), cfg.n_heads)
-        k_ = _split_heads(nn.dense(bp["attn1"]["to_k"], h, bl.get("attn1/to_k"), lora_scale), cfg.n_heads)
-        v_ = _split_heads(nn.dense(bp["attn1"]["to_v"], h, bl.get("attn1/to_v"), lora_scale), cfg.n_heads)
-        a = _merge_heads(nn.linear_attention(q, k_, v_))
-        a = nn.dense(bp["attn1"]["to_out"], a, bl.get("attn1/to_out"), lora_scale)
-        xc = xc + gate_msa * a
+        with jax.named_scope("dit_self_attn"):
+            h = nn.layer_norm(xc) * (1 + scale_msa) + shift_msa
+            q = _split_heads(nn.dense(bp["attn1"]["to_q"], h, bl.get("attn1/to_q"), lora_scale), cfg.n_heads)
+            k_ = _split_heads(nn.dense(bp["attn1"]["to_k"], h, bl.get("attn1/to_k"), lora_scale), cfg.n_heads)
+            v_ = _split_heads(nn.dense(bp["attn1"]["to_v"], h, bl.get("attn1/to_v"), lora_scale), cfg.n_heads)
+            a = _merge_heads(nn.linear_attention(q, k_, v_))
+            a = nn.dense(bp["attn1"]["to_out"], a, bl.get("attn1/to_out"), lora_scale)
+            xc = xc + gate_msa * a
 
         # cross attention to caption (vanilla softmax, un-normed query — Sana layout)
-        q = _split_heads(nn.dense(bp["attn2"]["to_q"], xc, bl.get("attn2/to_q"), lora_scale), cfg.cross_n_heads)
-        k2 = _split_heads(nn.dense(bp["attn2"]["to_k"], c, bl.get("attn2/to_k"), lora_scale), cfg.cross_n_heads)
-        v2 = _split_heads(nn.dense(bp["attn2"]["to_v"], c, bl.get("attn2/to_v"), lora_scale), cfg.cross_n_heads)
-        a2 = _merge_heads(nn.attention(q, k2, v2, mask=caption_mask))
-        xc = xc + nn.dense(bp["attn2"]["to_out"], a2, bl.get("attn2/to_out"), lora_scale)
+        with jax.named_scope("dit_cross_attn"):
+            q = _split_heads(nn.dense(bp["attn2"]["to_q"], xc, bl.get("attn2/to_q"), lora_scale), cfg.cross_n_heads)
+            k2 = _split_heads(nn.dense(bp["attn2"]["to_k"], c, bl.get("attn2/to_k"), lora_scale), cfg.cross_n_heads)
+            v2 = _split_heads(nn.dense(bp["attn2"]["to_v"], c, bl.get("attn2/to_v"), lora_scale), cfg.cross_n_heads)
+            a2 = _merge_heads(nn.attention(q, k2, v2, mask=caption_mask))
+            xc = xc + nn.dense(bp["attn2"]["to_out"], a2, bl.get("attn2/to_out"), lora_scale)
 
         # gated mix-FFN
-        h = nn.layer_norm(xc) * (1 + scale_mlp) + shift_mlp
-        ff = bp["ff"]
-        y = nn.conv2d(ff["conv_inverted"], h.reshape(B, hw[0], hw[1], d))
-        y = jax.nn.silu(y)
-        y = nn.conv2d(ff["conv_depth"], y, groups=y.shape[-1])
-        y, gate = jnp.split(y, 2, axis=-1)
-        y = (y * jax.nn.silu(gate))
-        y = nn.conv2d(ff["conv_point"], y).reshape(B, hw[0] * hw[1], d)
-        xc = xc + gate_mlp * y
+        with jax.named_scope("dit_ffn"):
+            h = nn.layer_norm(xc) * (1 + scale_mlp) + shift_mlp
+            ff = bp["ff"]
+            y = nn.conv2d(ff["conv_inverted"], h.reshape(B, hw[0], hw[1], d))
+            y = jax.nn.silu(y)
+            y = nn.conv2d(ff["conv_depth"], y, groups=y.shape[-1])
+            y, gate = jnp.split(y, 2, axis=-1)
+            y = (y * jax.nn.silu(gate))
+            y = nn.conv2d(ff["conv_point"], y).reshape(B, hw[0] * hw[1], d)
+            xc = xc + gate_mlp * y
         # block boundary: the only value the "blocks" remat policy saves —
         # attention/FFN interiors recompute instead of persisting per layer
         xc = nn.remat_name(xc, cfg.remat, "sana_block")
@@ -231,14 +236,15 @@ def sana_forward(
     x = nn.stacked_scan(body, x, cfg.n_layers, cfg.remat, "sana_block")
 
     # --- output head --------------------------------------------------------
-    table = params["scale_shift_table"].astype(jnp.float32)[None] + t_emb[:, None, :]  # [B,2,d]
-    shift, scale = table[:, 0, None, :].astype(dt), table[:, 1, None, :].astype(dt)
-    x = nn.layer_norm(x) * (1 + scale) + shift
-    x = nn.dense(params["proj_out"], x, lookup(lora, "proj_out"), lora_scale)
+    with jax.named_scope("dit_embed_out"):
+        table = params["scale_shift_table"].astype(jnp.float32)[None] + t_emb[:, None, :]  # [B,2,d]
+        shift, scale = table[:, 0, None, :].astype(dt), table[:, 1, None, :].astype(dt)
+        x = nn.layer_norm(x) * (1 + scale) + shift
+        x = nn.dense(params["proj_out"], x, lookup(lora, "proj_out"), lora_scale)
 
-    # unpatchify → NHWC
-    x = x.reshape(B, hw[0], hw[1], p, p, cfg.out_channels)
-    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(B, H, W, cfg.out_channels)
+        # unpatchify → NHWC
+        x = x.reshape(B, hw[0], hw[1], p, p, cfg.out_channels)
+        x = x.transpose(0, 1, 3, 2, 4, 5).reshape(B, H, W, cfg.out_channels)
     return x.astype(jnp.float32)
 
 

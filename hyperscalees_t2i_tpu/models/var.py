@@ -233,62 +233,70 @@ def generate(
     dt = cfg.compute_dtype
     vq_cfg = cfg.vq
 
-    # CFG super-batch: cond rows then uncond rows (var.py:151).
-    lbl2 = jnp.concatenate([labels, jnp.full_like(labels, cfg.uncond_label)])
-    cond = params["class_emb"][lbl2]  # [2B, d]
-    # AdaLN modulation per layer precomputed once (class cond is constant
-    # through generation): [depth, 2B, 6, d].
-    ada = params["blocks"]["ada_lin"]
-    c = jax.nn.silu(cond.astype(jnp.float32))
-    cond6_all = (
-        jnp.einsum("bd,lde->lbe", c, resolve_kernel(ada, jnp.float32)) + ada["bias"][:, None, :]
-    ).reshape(cfg.depth, 2 * B, 6, d)
+    # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only
+    with jax.named_scope("generate"):
+        # CFG super-batch: cond rows then uncond rows (var.py:151).
+        lbl2 = jnp.concatenate([labels, jnp.full_like(labels, cfg.uncond_label)])
+        cond = params["class_emb"][lbl2]  # [2B, d]
+        # AdaLN modulation per layer precomputed once (class cond is constant
+        # through generation): [depth, 2B, 6, d].
+        ada = params["blocks"]["ada_lin"]
+        c = jax.nn.silu(cond.astype(jnp.float32))
+        cond6_all = (
+            jnp.einsum("bd,lde->lbe", c, resolve_kernel(ada, jnp.float32)) + ada["bias"][:, None, :]
+        ).reshape(cfg.depth, 2 * B, 6, d)
 
-    # head AdaLN (scale, shift) from the same cond (AdaLNBeforeHead).
-    hs, hb = jnp.split(nn.dense(params["head_ada"], jax.nn.silu(cond)), 2, axis=-1)
+        # head AdaLN (scale, shift) from the same cond (AdaLNBeforeHead).
+        hs, hb = jnp.split(nn.dense(params["head_ada"], jax.nn.silu(cond)), 2, axis=-1)
 
-    kC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
-    vC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
-    f_hat = jnp.zeros((B, vq_cfg.grid, vq_cfg.grid, vq_cfg.c_vae), jnp.float32)
+        kC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
+        vC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
+        f_hat = jnp.zeros((B, vq_cfg.grid, vq_cfg.grid, vq_cfg.c_vae), jnp.float32)
 
-    # first scale input: sos from class embedding + start/level/pos tables
-    x = (
-        cond[:, None, :]
-        + params["pos_start"]
-        + params["lvl_emb"][0][None, None, :]
-        + params["pos_emb"][None, :1, :]
-    ).astype(dt)
+        # first scale input: sos from class embedding + start/level/pos tables
+        x = (
+            cond[:, None, :]
+            + params["pos_start"]
+            + params["lvl_emb"][0][None, None, :]
+            + params["pos_emb"][None, :1, :]
+        ).astype(dt)
 
-    slices = _scale_slices(cfg)
-    for si, (pos, n) in enumerate(slices):
-        h, (kC, vC) = _blocks_step(params, cfg, x, cond6_all, (kC, vC), pos, lora, lora_scale)
-        h = nn.layer_norm(h) * (1.0 + hs[:, None, :].astype(dt)) + hb[:, None, :].astype(dt)
-        logits = nn.dense(params["head"], h).astype(jnp.float32)  # [2B, n, V]
-        t = cfgs * si / max(S - 1, 1)  # per-scale CFG ramp (var.py:172)
-        lg = (1.0 + t) * logits[:B] - t * logits[B:]
-        k_si = jax.random.fold_in(key, si)
-        img_keys = jax.vmap(lambda i: jax.random.fold_in(k_si, i))(item_idx)
-        ids = jax.vmap(
-            lambda kk, row: sample_top_k_top_p(
-                kk, row, top_k=tk, top_p=tp, temperature=cfg.temperature
-            )
-        )(img_keys, lg)  # [B, n]
-        f_hat, nxt = msvq.accumulate_scale(params["vq"], vq_cfg, f_hat, ids, si)
-        if si + 1 < S:
-            pn1 = cfg.patch_nums[si + 1]
-            n1 = pn1 * pn1
-            tok = nxt.reshape(B, n1, vq_cfg.c_vae)
-            emb = nn.dense(params["word_embed"], tok.astype(jnp.float32))
-            nxt_x = (
-                emb
-                + params["lvl_emb"][si + 1][None, None, :]
-                + params["pos_emb"][None, pos + n : pos + n + n1, :]
-            )
-            x = jnp.concatenate([nxt_x, nxt_x]).astype(dt)  # cond+uncond share input
+        slices = _scale_slices(cfg)
+        for si, (pos, n) in enumerate(slices):
+            with jax.named_scope(f"scale{si}"):
+                with jax.named_scope("blocks"):
+                    h, (kC, vC) = _blocks_step(params, cfg, x, cond6_all, (kC, vC), pos, lora, lora_scale)
+                with jax.named_scope("head"):
+                    h = nn.layer_norm(h) * (1.0 + hs[:, None, :].astype(dt)) + hb[:, None, :].astype(dt)
+                    logits = nn.dense(params["head"], h).astype(jnp.float32)  # [2B, n, V]
+                    t = cfgs * si / max(S - 1, 1)  # per-scale CFG ramp (var.py:172)
+                    lg = (1.0 + t) * logits[:B] - t * logits[B:]
+                with jax.named_scope("sample"):
+                    k_si = jax.random.fold_in(key, si)
+                    img_keys = jax.vmap(lambda i: jax.random.fold_in(k_si, i))(item_idx)
+                    ids = jax.vmap(
+                        lambda kk, row: sample_top_k_top_p(
+                            kk, row, top_k=tk, top_p=tp, temperature=cfg.temperature
+                        )
+                    )(img_keys, lg)  # [B, n]
+                with jax.named_scope("msvq_accumulate"):
+                    f_hat, nxt = msvq.accumulate_scale(params["vq"], vq_cfg, f_hat, ids, si)
+                if si + 1 < S:
+                    pn1 = cfg.patch_nums[si + 1]
+                    n1 = pn1 * pn1
+                    tok = nxt.reshape(B, n1, vq_cfg.c_vae)
+                    emb = nn.dense(params["word_embed"], tok.astype(jnp.float32))
+                    nxt_x = (
+                        emb
+                        + params["lvl_emb"][si + 1][None, None, :]
+                        + params["pos_emb"][None, pos + n : pos + n + n1, :]
+                    )
+                    x = jnp.concatenate([nxt_x, nxt_x]).astype(dt)  # cond+uncond share input
 
     if not decode:
         return f_hat
-    return msvq.decode_img(params["vq"], vq_cfg, f_hat)
+    with jax.named_scope("decode"):
+        return msvq.decode_img(params["vq"], vq_cfg, f_hat)
 
 
 def forward_teacher(

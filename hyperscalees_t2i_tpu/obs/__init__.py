@@ -1,9 +1,12 @@
 """Unified observability: span tracing, heartbeats/watchdog, metrics registry.
 
-Three complementary signals, one subsystem (ROADMAP: every later perf PR
-reports against this layer):
+One tracing system, three files a run leaves behind, each read by name by
+the benchmark's per-layer readers (``benchmarks/layer_metrics/``; PERF.md §3
+has the span/counter → metric table):
 
-- ``trace``     — nested host-side span timelines → ``trace.jsonl`` per run,
+- ``trace``     — nested host-side spans → ``trace.jsonl`` per run (build,
+  compile, dispatch → enqueue/fetch, logging); under a profiler session the
+  same spans are events of the profiler's host plane, on the device's clock;
   Chrome-trace export, aggregated by ``tools/trace_report.py``;
 - ``heartbeat`` — periodic liveness lines to **stderr** during long blocking
   phases (a flagship compile runs for minutes), with an optional
@@ -13,6 +16,10 @@ reports against this layer):
 - ``xla_cost``  — per-compiled-program ledger (``programs.jsonl``: normalized
   cost/memory analysis, StableHLO stats, donation audit) + roofline
   classification of measured steps; stdlib-only at import like the rest.
+  With tracing on it also writes ``scopes/<label>.json``, the instruction →
+  ``jax.named_scope`` table of each compiled program: device time gets its
+  name (generate / decode / reward / update) by joining a profiler trace's op
+  names to that table, not through ``--profile_epochs``.
 
 Plus two PR-2 layers on top of that plumbing:
 
@@ -110,13 +117,13 @@ from .xla_cost import (
 )
 from .trace import (
     Tracer,
+    block_if_tracing,
     get_tracer,
     load_events,
     set_span_observer,
     set_tracer,
     span,
     to_chrome,
-    traced,
 )
 from .xplane import (
     build_xspace,
@@ -139,6 +146,7 @@ __all__ = [
     "MetricsRegistry",
     "ProgramLedger",
     "Tracer",
+    "block_if_tracing",
     "build_xspace",
     "calib_gauges",
     "calibrate_run",
@@ -190,7 +198,6 @@ __all__ = [
     "set_tracer",
     "span",
     "to_chrome",
-    "traced",
     "trace_segment_path",
     "write_calib",
     "write_pod_summary",

@@ -1,35 +1,45 @@
-"""Nested-span tracer: structured host-side timelines per run.
+"""Nested-span tracer: the host side of one run, by name.
 
-The ES loop is overhead-bound at small populations (PERF.md: fixed per-step
-dispatch/sync costs dominate below pop≈64), and "where did the wall clock go"
-has so far been answered by ad-hoc ``time.perf_counter()`` pairs scattered
-through bench.py and the trainer. This module makes phase timing first-class:
+The spans exist for their readers. ``benchmarks/layer_metrics/`` takes the
+build (``build_backend``, ``backend_setup``, ``quantize``, ``build_reward``),
+the step builder (``compile`` → ``lower``) and the host loop
+(``dispatch`` → ``enqueue``, ``fetch``) from ``trace.jsonl`` by span name;
+``tools/trace_report.py`` / ``tools/run_report.py`` aggregate the same file
+for an operator. PERF.md §3 lists every span with the metric that reads it.
 
-- ``Tracer(path)`` appends one JSON line per *completed* span to
-  ``trace.jsonl`` (children close before parents, so child lines precede
-  their parent's); ``Tracer(None)`` is a zero-overhead no-op.
+- ``Tracer(path)`` records one JSON line per *completed* span (children close
+  before parents, so child lines precede their parent's). Lines are held in
+  memory and written through one file handle when a root span closes (the
+  trainer's ``epoch``), on ``event()`` and on ``close()`` — a crash loses at
+  most the epoch in flight. ``Tracer(None)`` is a zero-overhead no-op.
+- ``Tracer(enabled=True)`` has no file yet: ``train.cli.main`` makes it on
+  entry, so the build is under spans, and ``run_training`` gives it the run
+  directory's file (``attach``) once the directory's name is known.
 - Spans nest via a thread-local stack (``depth``/``parent`` are recorded per
-  event) and are timed with the monotonic clock — wall-clock steps from NTP
-  can never produce negative durations.
-- ``to_chrome(events)`` converts the event list to Chrome trace-event JSON
-  loadable in ``chrome://tracing`` / Perfetto (complete ``"ph": "X"`` events,
-  microsecond timestamps).
+  event) and are timed with the monotonic clock. The first line
+  (``trace_start``) carries the wall time the tracer was made, which maps
+  the offsets to any other clock.
+- An enabled span also enters ``jax.profiler.TraceAnnotation(name)`` (when
+  jax is already imported; a TraceMe costs nanoseconds with no profiler
+  session open): under a profiler session every span is an event of the
+  ``.xplane.pb`` host plane too, on the device events' own clock.
+- ``to_chrome(events)`` converts the event list to Chrome trace-event JSON.
 
 A process-global tracer (``set_tracer`` / ``get_tracer``) lets call sites in
-other layers (``parallel/pop_eval.py``, backends) emit spans without plumbing
-a tracer handle through every signature; the module-level ``span(...)``
-context manager and ``traced(...)`` decorator resolve it at call time.
+other layers (``train/cli.py``, ``parallel/pop_eval.py``, backends) emit
+spans without plumbing a handle; the module-level ``span(...)`` resolves it
+at call time.
 
-``jax.profiler`` traces (TrainConfig.profile_epochs) remain the tool for
-*device*-side op breakdowns; this tracer answers the host-side question —
-build vs compile vs dispatch vs logging — cheaply enough to leave on.
+Device-side attribution is not this file's: the step program carries
+``jax.named_scope`` names and ``obs/xla_cost.scope_table`` writes the
+op → scope table that joins a profiler trace to them.
 """
 
 from __future__ import annotations
 
-import functools
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -38,18 +48,22 @@ from typing import Any, Dict, Iterable, List, Optional, Union
 
 
 class Tracer:
-    """Thread-safe nested-span tracer appending to a JSONL file.
+    """Thread-safe nested-span tracer writing JSON lines.
 
-    ``path=None`` builds a disabled tracer: ``span()`` yields immediately and
-    writes nothing (the non-master-process / tracing-off case).
+    ``Tracer(None)`` is disabled: ``span()`` yields immediately and records
+    nothing (the tracing-off case). ``Tracer(enabled=True)`` records into
+    memory until :meth:`attach` names its file.
     """
 
-    def __init__(self, path: Optional[Union[str, Path]] = None):
+    def __init__(self, path: Optional[Union[str, Path]] = None, *, enabled: Optional[bool] = None):
         from .multihost import safe_process_index
 
         self.path = Path(path) if path is not None else None
+        self._enabled = self.path is not None if enabled is None else bool(enabled)
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._lines: List[str] = []  # recorded, not yet written
+        self._file = None            # the one handle, opened on first flush
         # Wall epoch + monotonic origin recorded together so offsets in the
         # file can be mapped back to absolute time by readers that care.
         self._wall0 = time.time()
@@ -57,25 +71,57 @@ class Tracer:
         # Captured once: a process's rank never changes, and per-event lookup
         # would put a (cheap but nonzero) call on every span close.
         self._process_index = safe_process_index()
-        if self.path is not None:
-            self.path.parent.mkdir(parents=True, exist_ok=True)
-            self._write({"meta": "trace_start", "wall_time": self._wall0,
-                         "pid": os.getpid(),
-                         "process_index": self._process_index})
+        if self._enabled:
+            self._record({"meta": "trace_start", "wall_time": self._wall0,
+                          "pid": os.getpid(),
+                          "process_index": self._process_index})
+            self.flush()
 
     @property
     def enabled(self) -> bool:
-        return self.path is not None
+        return self._enabled
 
-    def _write(self, obj: Dict[str, Any]) -> None:
+    def attach(self, path: Union[str, Path]) -> None:
+        """Name the file of a tracer made without one; what it recorded so
+        far (its ``trace_start`` line first) is written there."""
+        self.path = Path(path)
+        self.flush()
+
+    def _record(self, obj: Dict[str, Any]) -> None:
         line = json.dumps(obj, default=str) + "\n"
-        try:
-            with self._lock, self.path.open("a") as f:
-                f.write(line)
-        except OSError:
-            # observability must never kill the run (e.g. run_dir removed
-            # underneath a long job); drop the event instead
-            pass
+        with self._lock:
+            self._lines.append(line)
+
+    def flush(self) -> None:
+        """Write what was recorded since the last flush. Never raises:
+        observability must not kill the run (e.g. run_dir removed underneath
+        a long job) — the lines are dropped instead."""
+        if self.path is None:
+            return
+        with self._lock:
+            lines, self._lines = self._lines, []
+            if not lines:
+                return
+            try:
+                if self._file is None:
+                    self.path.parent.mkdir(parents=True, exist_ok=True)
+                    self._file = self.path.open("a")
+                self._file.writelines(lines)
+                self._file.flush()
+            except OSError:
+                pass
+
+    def close(self) -> None:
+        """Flush and release the file handle (the tracer stays usable: a
+        later flush reopens the file in append mode)."""
+        self.flush()
+        with self._lock:
+            if self._file is not None:
+                try:
+                    self._file.close()
+                except OSError:
+                    pass
+                self._file = None
 
     def _stack(self) -> List[str]:
         st = getattr(self._local, "stack", None)
@@ -98,12 +144,17 @@ class Tracer:
             yield
             return
         stack = self._stack()
+        annotation = _trace_annotation(name) if self.enabled else None
         t0 = time.perf_counter() - self._mono0
         parent = stack[-1] if stack else None
         stack.append(name)
+        if annotation is not None:
+            annotation.__enter__()
         try:
             yield
         finally:
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             stack.pop()
             t1 = time.perf_counter() - self._mono0
             if _OBSERVER is not None:
@@ -124,7 +175,9 @@ class Tracer:
                 }
                 if attrs:
                     ev["attrs"] = attrs
-                self._write(ev)
+                self._record(ev)
+                if not stack:
+                    self.flush()  # a root span closed (the trainer's ``epoch``)
 
     def event(
         self,
@@ -154,7 +207,21 @@ class Tracer:
         }
         if attrs:
             ev["attrs"] = attrs
-        self._write(ev)
+        self._record(ev)
+        self.flush()
+
+
+def _trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` where jax is already imported
+    (this package stays importable, and usable, without it), else None. The
+    bare name only: keyword arguments are encoded into the event's name, and
+    readers of the profiler's host plane match names by equality."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
 
 _NULL = Tracer(None)
 _GLOBAL: Tracer = _NULL
@@ -188,21 +255,16 @@ def span(name: str, **attrs: Any):
         yield
 
 
-def traced(name: Optional[str] = None, **attrs: Any):
-    """Decorator on the process-global tracer, resolved per call — a function
-    decorated at import time still traces once a tracer is installed."""
+def block_if_tracing(tree: Any) -> Any:
+    """``jax.block_until_ready(tree)`` when the process-global tracer is
+    enabled, else nothing; returns ``tree``. A span around work the device
+    runs asynchronously ends in this call, or its time would be charged to
+    the next span that waits; with tracing off no sync is added."""
+    if _GLOBAL.enabled and "jax" in sys.modules:
+        import jax
 
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with get_tracer().span(label, **attrs):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
+        jax.block_until_ready(tree)
+    return tree
 
 
 def load_events(path: Union[str, Path]) -> List[Dict[str, Any]]:

@@ -312,6 +312,140 @@ def collective_stats(compiled: Any) -> Dict[str, Any]:
     }
 
 
+# The device-time vocabulary: every ``jax.named_scope`` the step program
+# enters, and the only names :func:`scope_table` looks for. A top-level scope
+# is one stage of the ES step; the inner names split one stage; the indexed
+# ones carry a number (``scale3``, ``stage0``). Kept here, beside the table
+# writer, and nowhere else: a scope entered under another name is
+# ``unattributed``.
+TOP_SCOPES = ("es_noise", "generate", "decode", "reward", "es_update")
+INNER_SCOPES = (
+    "dit_embed_out", "dit_self_attn", "dit_cross_attn", "dit_ffn",     # Sana DiT
+    "blocks", "head", "sample", "msvq_accumulate",                     # VAR, inside scale<k>
+    "preprocess", "clip_b", "clip_h", "score",                         # rewards
+    "perturb",                                                         # es_noise: one member's adapter
+    "fitness", "update", "health",                                     # es_update
+)
+INDEXED_SCOPES = ("scale", "stage")  # VAR scale<k>, DC-AE decode stage<k>
+_INDEXED_SCOPE = r"(?:%s)\d+" % "|".join(INDEXED_SCOPES)
+UNATTRIBUTED = "unattributed"
+INFERRED = "~"  # prefix of a table entry that scope_table inferred from the graph
+
+
+def scope_of(op_name: str) -> str:
+    """Innermost vocabulary path in an instruction's ``op_name`` metadata:
+    ``jit(f)/while/body/closed_call/vmap(generate)/dit_ffn/mul`` →
+    ``generate/dit_ffn``. The whole path is searched (some ops carry only its
+    tail, a transform wraps a scope as ``vmap(generate)``); inner names count
+    only after a top-level one, each once, in the order they nest."""
+    import re
+
+    path: list = []
+    for token in re.findall(r"\w+", op_name):
+        if token in TOP_SCOPES:
+            path = [token]  # the innermost top-level scope wins
+        elif path and token not in path and (
+            token in INNER_SCOPES or re.fullmatch(_INDEXED_SCOPE, token)
+        ):
+            path.append(token)
+    return "/".join(path) if path else UNATTRIBUTED
+
+
+def scope_table(compiled: Any) -> Dict[str, str]:
+    """``{instruction name: scope}`` of the optimized HLO module — what joins
+    a profiler trace (whose device events are named by the optimized module's
+    instruction names and carry no ``op_name``) to the program's
+    ``jax.named_scope`` names. A fusion carries its root's metadata, so it
+    takes its root's scope. An instruction whose ``op_name`` holds no
+    vocabulary scope is the compiler's own (a layout copy, an async
+    ``copy-done``, the zero fill of a scan's output, a rewritten reduction
+    that kept only ``reduce_window_sum``): it exists for the instructions
+    that consume its result and takes the scope they share (the common
+    prefix of their paths, looked for through tuples and other such
+    instructions, never through a loop or a call), failing that the one its
+    operands share. Such an entry is a guess from the graph, not the
+    program's word, and is marked: ``~generate/dit_ffn`` (:data:`INFERRED`),
+    so that a reader can say how much of a scope's time is inferred. The
+    guess needs the operands printed as ``%name`` lists; on a jax that prints
+    them otherwise it finds nothing, and those instructions stay
+    ``unattributed``. Control flow and plumbing (``while``, ``call``,
+    ``conditional``, tuples, parameters, constants) are only ever what their
+    own metadata says, and what nothing scoped consumes or feeds (loop
+    counters, the member loop itself) stays ``unattributed``. Instructions
+    inside fused computations never run as ops of their own and are left out.
+    ``{}`` when the backend has no ``as_text``."""
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return {}
+    import os.path
+    import re
+
+    fused = set(re.findall(r"\bfusion\(.*?\bcalls=%?([\w.\-]+)", text))
+    head = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+    inst = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
+    opcode = re.compile(r"\s([a-z][\w\-]*)\(")  # the first `word(` after the shape
+    op_name = re.compile(r'op_name="([^"]*)"')
+    control = ("while", "call", "conditional")
+    plumbing = control + ("tuple", "get-tuple-element", "parameter", "constant")
+    table: Dict[str, str] = {}
+    opcodes: Dict[str, str] = {}
+    operands: Dict[str, list] = {}
+    users: Dict[str, list] = {}
+    skip = False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = head.match(line)
+            if m:
+                skip = m.group(1) in fused
+            continue
+        if skip:
+            continue
+        m = inst.match(line)
+        if m is None:
+            continue
+        name = m.group(1)
+        meta = op_name.search(line)
+        table[name] = scope_of(meta.group(1)) if meta else UNATTRIBUTED
+        op = opcode.search(line, m.end() - 1)
+        opcodes[name] = op.group(1) if op else ""
+        # operands are printed as %names, without shapes: up to the first `)`
+        args = line[op.end():line.find(")", op.end())] if op else ""
+        operands[name] = re.findall(r"%([\w.\-]+)", args)
+        for o in operands[name]:
+            users.setdefault(o, []).append(name)
+
+    def shared_scope(start: str, edges: Dict[str, list]) -> str:
+        """Common prefix of the scopes reached from ``start`` along ``edges``,
+        walking through instructions that have none themselves."""
+        found, seen, frontier = [], {start}, [start]
+        while frontier:
+            nxt = []
+            for n in frontier:
+                for o in edges.get(n, ()):
+                    if o in seen or o not in table:
+                        continue
+                    seen.add(o)
+                    if table[o] != UNATTRIBUTED:
+                        found.append(table[o].split("/"))
+                    elif opcodes[o] not in control:
+                        nxt.append(o)
+            frontier = nxt
+        # commonprefix compares any sequences element by element: here, paths
+        return "/".join(os.path.commonprefix(found)) or UNATTRIBUTED
+
+    inferred = {}
+    for name, scope in table.items():
+        if scope == UNATTRIBUTED and opcodes[name] not in plumbing:
+            got = shared_scope(name, users)
+            if got == UNATTRIBUTED:
+                got = shared_scope(name, operands)
+            if got != UNATTRIBUTED:
+                inferred[name] = INFERRED + got
+    table.update(inferred)
+    return table
+
+
 # ops through which the dequant dataflow cone propagates (elementwise /
 # data-movement steps between the s8 source and the consuming dot/conv);
 # `bitcast` is free in XLA (no buffer) and deliberately absent
@@ -679,25 +813,36 @@ def program_record(
 
 def record_compile(**kwargs: Any) -> Dict[str, Any]:
     """Build a program record, write it to the installed ledger, and surface
-    the headline numbers as ``obs/`` gauges (→ next ``metrics.jsonl`` row).
-    The one call every compile site makes. Never raises."""
+    the compiler's peak as an ``obs/`` gauge (→ next ``metrics.jsonl`` row).
+    With the tracer enabled, also write the program's op → scope table
+    (:func:`scope_table`) to ``scopes/<label>.json`` beside the ledger and
+    name it in the record. The one call every compile site makes. Never
+    raises."""
     try:
         rec = program_record(**kwargs)
     except Exception:
         return {}
-    get_ledger().write(rec)
+    ledger = get_ledger()
+    try:
+        from .trace import get_tracer
+
+        compiled = kwargs.get("compiled")
+        if compiled is not None and ledger.enabled and get_tracer().enabled:
+            table = scope_table(compiled)
+            if table:
+                rel = Path("scopes") / f"{rec['label']}.json"
+                path = ledger.path.parent / rel
+                path.parent.mkdir(parents=True, exist_ok=True)
+                path.write_text(json.dumps(table, sort_keys=True))
+                rec["scope_table"] = str(rel)
+    except Exception:
+        pass
+    ledger.write(rec)
     try:
         from .metrics import get_registry
 
-        reg = get_registry()
-        for gauge, key in (
-            ("program_flops", "flops"),
-            ("program_bytes_accessed", "bytes_accessed"),
-            ("program_peak_bytes", "peak_bytes"),
-            ("program_intensity", "intensity"),
-        ):
-            if rec.get(key) is not None:
-                reg.gauge(gauge, rec[key])
+        if rec.get("peak_bytes") is not None:
+            get_registry().gauge("program_peak_bytes", rec["peak_bytes"])
     except Exception:
         pass
     return rec

@@ -342,17 +342,20 @@ def make_population_evaluator(
         )
 
     def eval_one(frozen, theta, noise, flat_ids, item_index, gen_key, k, maps=None):
-        if pop_fuse:
-            theta_k = factored_member_theta(theta, noise, k, pop_size, es_cfg, maps)
-        else:
-            theta_k = perturb_member(theta, noise, k, pop_size, es_cfg)
+        # device-time scope (obs/xla_cost.INNER_SCOPES): a name only
+        with jax.named_scope("es_noise"), jax.named_scope("perturb"):
+            if pop_fuse:
+                theta_k = factored_member_theta(theta, noise, k, pop_size, es_cfg, maps)
+            else:
+                theta_k = perturb_member(theta, noise, k, pop_size, es_cfg)
         return eval_theta(frozen, theta_k, flat_ids, item_index, gen_key)
 
     def make_maps():
         # fused path only: device-side (signs, bases) built ONCE per trace
         # and threaded into every member lane (the materialized path keeps
         # its in-body construction so its HLO stays byte-identical)
-        return member_maps(pop_size, es_cfg.antithetic) if pop_fuse else None
+        with jax.named_scope("es_noise"), jax.named_scope("perturb"):
+            return member_maps(pop_size, es_cfg.antithetic) if pop_fuse else None
 
     # iteration domain: the whole population, or this host's member slice
     slice_lo, slice_n = host_slice if host_slice is not None else (0, pop_size)

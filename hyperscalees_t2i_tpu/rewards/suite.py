@@ -87,41 +87,50 @@ def compute_rewards_batch(
     degradation as ``rewards.py:239-241``).
     """
     M = clip_text_table.shape[0] - 2
-    pixels = clip_mod.preprocess_images(images, clip_cfg)
-    img = _normalize(clip_mod.image_features(clip_params, clip_cfg, pixels))  # [B, P]
+    # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only
+    with jax.named_scope("reward"):
+        with jax.named_scope("preprocess"):
+            pixels = clip_mod.preprocess_images(images, clip_cfg)
+        with jax.named_scope("clip_b"):
+            img = _normalize(clip_mod.image_features(clip_params, clip_cfg, pixels))  # [B, P]
 
-    aes_t = clip_text_table[M]
-    neg_t = clip_text_table[M + 1]
-    own_t = clip_text_table[prompt_ids]  # [B, P]
+        with jax.named_scope("score"):
+            aes_t = clip_text_table[M]
+            neg_t = clip_text_table[M + 1]
+            own_t = clip_text_table[prompt_ids]  # [B, P]
 
-    to01 = lambda s: (s + 1.0) / 2.0
-    clip_aesthetic = to01(img @ aes_t)
-    clip_text = to01(jnp.sum(img * own_t, axis=-1))
-    no_artifacts = 1.0 - to01(img @ neg_t)
+            to01 = lambda s: (s + 1.0) / 2.0
+            clip_aesthetic = to01(img @ aes_t)
+            clip_text = to01(jnp.sum(img * own_t, axis=-1))
+            no_artifacts = 1.0 - to01(img @ neg_t)
 
-    if pick_params is not None and pick_text_embeds is not None and pick_cfg is not None:
-        ppix = clip_mod.preprocess_images(images, pick_cfg)
-        pimg = _normalize(clip_mod.image_features(pick_params, pick_cfg, ppix))
-        pown = pick_text_embeds[prompt_ids]
-        pickscore = jnp.exp(pick_params["logit_scale"].astype(jnp.float32)) * jnp.sum(
-            pimg * pown, axis=-1
-        )
-    else:
-        pickscore = jnp.zeros(images.shape[0], jnp.float32)
+        if pick_params is not None and pick_text_embeds is not None and pick_cfg is not None:
+            with jax.named_scope("preprocess"):
+                ppix = clip_mod.preprocess_images(images, pick_cfg)
+            with jax.named_scope("clip_h"):
+                pimg = _normalize(clip_mod.image_features(pick_params, pick_cfg, ppix))
+            with jax.named_scope("score"):
+                pown = pick_text_embeds[prompt_ids]
+                pickscore = jnp.exp(pick_params["logit_scale"].astype(jnp.float32)) * jnp.sum(
+                    pimg * pown, axis=-1
+                )
+        else:
+            pickscore = jnp.zeros(images.shape[0], jnp.float32)
 
-    combined = (
-        weights.aesthetic * clip_aesthetic
-        + weights.align * clip_text
-        + weights.no_artifacts * no_artifacts
-        + weights.pickscore * pickscore
-    )
-    return {
-        "clip_aesthetic": clip_aesthetic.astype(jnp.float32),
-        "clip_text": clip_text.astype(jnp.float32),
-        "no_artifacts": no_artifacts.astype(jnp.float32),
-        "pickscore": pickscore.astype(jnp.float32),
-        "combined": combined.astype(jnp.float32),
-    }
+        with jax.named_scope("score"):
+            combined = (
+                weights.aesthetic * clip_aesthetic
+                + weights.align * clip_text
+                + weights.no_artifacts * no_artifacts
+                + weights.pickscore * pickscore
+            )
+            return {
+                "clip_aesthetic": clip_aesthetic.astype(jnp.float32),
+                "clip_text": clip_text.astype(jnp.float32),
+                "no_artifacts": no_artifacts.astype(jnp.float32),
+                "pickscore": pickscore.astype(jnp.float32),
+                "combined": combined.astype(jnp.float32),
+            }
 
 
 class RewardSuite:

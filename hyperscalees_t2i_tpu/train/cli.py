@@ -569,6 +569,7 @@ def load_clip_tower(name: str, cfg) -> Optional[Any]:
 
 def build_reward_fn(args, backend):
     from ..models import clip as clip_mod
+    from ..obs import block_if_tracing, span as obs_span
     from ..ops.quant import maybe_quantize_tree
     from ..rewards.suite import (
         AESTHETIC_TEXT,
@@ -601,7 +602,8 @@ def build_reward_fn(args, backend):
         ccfg = dataclasses.replace(
             clip_mod.CLIP_B32, compute_dtype=tower_dt, remat=tower_remat
         )
-        cparams = load_clip_tower(args.clip_model, ccfg)
+        with obs_span("clip_b"):
+            cparams = load_clip_tower(args.clip_model, ccfg)
         if cparams is None:
             if not args.allow_random_rewards:
                 sys.exit(
@@ -613,7 +615,8 @@ def build_reward_fn(args, backend):
             pcfg = dataclasses.replace(
                 clip_mod.CLIP_H14, compute_dtype=tower_dt, remat=tower_remat
             )
-            pparams = load_clip_tower(args.pickscore_model, pcfg)
+            with obs_span("clip_h"):
+                pparams = load_clip_tower(args.pickscore_model, pcfg)
             if pparams is None and args.allow_random_rewards:
                 # the smoke's program must be the flagship program: the
                 # largest reward tower is built from a seed like the CLIP-B
@@ -641,9 +644,6 @@ def build_reward_fn(args, backend):
                     flush=True,
                 )
 
-    texts = list(backend.texts)
-    ids, eot, mask = tokenize_with_hf(texts + [AESTHETIC_TEXT, NEGATIVE_TEXT], args.clip_model)
-    ptok = tokenize_with_hf(texts, args.pickscore_model) if pcfg is not None else None
     base_quant = getattr(args, "base_quant", "off")
 
     def towers(cparams, pparams):
@@ -664,7 +664,13 @@ def build_reward_fn(args, backend):
         out["cparams"] = maybe_quantize_tree(cparams, base_quant)
         return out
 
-    out = jax.jit(towers, donate_argnums=(0, 1))(cparams, pparams)
+    # tokenization and the one ``towers`` program (seeded towers where none
+    # was loaded, both text tables, the int8 towers)
+    with obs_span("text_tables"):
+        texts = list(backend.texts)
+        ids, eot, mask = tokenize_with_hf(texts + [AESTHETIC_TEXT, NEGATIVE_TEXT], args.clip_model)
+        ptok = tokenize_with_hf(texts, args.pickscore_model) if pcfg is not None else None
+        out = block_if_tracing(jax.jit(towers, donate_argnums=(0, 1))(cparams, pparams))
     return make_clip_reward_fn(
         out["cparams"], ccfg, out["table"], weights=weights,
         pick_params=out.get("pparams"), pick_cfg=pcfg,
@@ -675,10 +681,8 @@ def build_reward_fn(args, backend):
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
 
-    from ..parallel import POP_AXIS, initialize_multihost, make_mesh
+    from ..obs import Tracer, get_tracer, set_tracer
     from ..utils.compile_cache import place_compile_cache
-    from .config import TrainConfig
-    from .trainer import run_training
 
     place_compile_cache()
 
@@ -693,9 +697,32 @@ def main(argv=None) -> None:
         os.environ["JAX_COORDINATOR_ADDRESS"] = args.coordinator
         os.environ["JAX_NUM_PROCESSES"] = str(args.num_processes)
         os.environ["JAX_PROCESS_ID"] = str(args.process_id)
+    # --trace true: the tracer exists from here, so the build below is under
+    # spans. It has no file yet — run_training names the run directory and
+    # adopts it.
+    if args.trace:
+        set_tracer(Tracer(enabled=True))
+    try:
+        _run(args)
+    finally:
+        if args.trace:
+            get_tracer().close()
+            set_tracer(None)
+
+
+def _run(args) -> None:
+    """``main`` after the tracer is installed: build, mesh, train."""
+    from ..obs import block_if_tracing, span as obs_span
+    from ..parallel import POP_AXIS, initialize_multihost, make_mesh
+    from .config import TrainConfig
+    from .trainer import run_training
+
     initialize_multihost()
-    backend = build_backend(args)
-    backend.setup()
+    with obs_span("build_backend"):
+        backend = build_backend(args)
+    with obs_span("backend_setup"):
+        backend.setup()
+        block_if_tracing(backend.frozen)
     if args.base_quant == "int8":
         # quantize the frozen generator trees in place AFTER setup (params
         # exist) and BEFORE init_theta (the adapter tree then targets
@@ -703,12 +730,15 @@ def main(argv=None) -> None:
         # way, lora.init_lora). The trained delta never touches the base.
         from ..ops.quant import quantize_frozen
 
-        backend.params = quantize_frozen(backend.params, "int8")
-        if getattr(backend, "vae_params", None) is not None:
-            backend.vae_params = quantize_frozen(backend.vae_params, "int8")
+        with obs_span("quantize"):
+            backend.params = quantize_frozen(backend.params, "int8")
+            if getattr(backend, "vae_params", None) is not None:
+                backend.vae_params = quantize_frozen(backend.vae_params, "int8")
+            block_if_tracing(backend.frozen)
         print("[cli] base_quant=int8: frozen generator kernels stored int8 "
               "(per-output-channel, ops/quant.py)", flush=True)
-    reward_fn = build_reward_fn(args, backend)
+    with obs_span("build_reward"):
+        reward_fn = build_reward_fn(args, backend)
 
     # Host-sharded pods (the multi-process default) build a LOCAL mesh: each
     # process compiles programs over its own devices only — the population

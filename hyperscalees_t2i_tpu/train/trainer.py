@@ -23,7 +23,7 @@ import os
 import sys
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +35,7 @@ from ..obs import (
     ProgramLedger,
     Tracer,
     compile_cache_entries,
+    get_tracer,
     maybe_heartbeat,
     record_compile,
     record_device_memory,
@@ -95,65 +96,74 @@ def _combine_and_update(
     trace, golden program untouched."""
     from ..obs.es_health import es_health_metrics
 
-    # S_comb[k, j]: mean over repeats (grouped layout [r][m],
-    # unifed_es.py:208-215).
-    S = rewards["combined"].reshape(pop, repeats, num_unique).mean(axis=1)
-    if tc.promptnorm:
-        opt_scores, _, sigma_bar = prompt_normalized_scores(S)
-    else:
-        opt_scores = S.mean(axis=1)
-        sigma_bar = jnp.float32(0.0)
+    # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only,
+    # the traced program is the same
+    with jax.named_scope("es_update"):
+        with jax.named_scope("fitness"):
+            # S_comb[k, j]: mean over repeats (grouped layout [r][m],
+            # unifed_es.py:208-215).
+            S = rewards["combined"].reshape(pop, repeats, num_unique).mean(axis=1)
+            if tc.promptnorm:
+                opt_scores, _, sigma_bar = prompt_normalized_scores(S)
+            else:
+                opt_scores = S.mean(axis=1)
+                sigma_bar = jnp.float32(0.0)
+            fitness, n_finite = standardize_fitness_masked(opt_scores)
 
-    fitness, n_finite = standardize_fitness_masked(opt_scores)
-    if update_fn is not None:
-        theta_new = update_fn(theta, noise, fitness)
-    else:
-        theta_new = es_update(theta, noise, fitness, pop, es_cfg, lr=lr)
-    theta_new, step_scale = cap_step_norm(theta, theta_new, tc.max_step_norm)
-    theta_new, theta_scale = cap_theta_norm(theta_new, tc.theta_max_norm)
+        with jax.named_scope("update"):
+            if update_fn is not None:
+                theta_new = update_fn(theta, noise, fitness)
+            else:
+                theta_new = es_update(theta, noise, fitness, pop, es_cfg, lr=lr)
+            theta_new, step_scale = cap_step_norm(theta, theta_new, tc.max_step_norm)
+            theta_new, theta_scale = cap_theta_norm(theta_new, tc.theta_max_norm)
+            delta = jax.tree_util.tree_map(lambda a, b: a - b, theta_new, theta)
 
-    delta = jax.tree_util.tree_map(lambda a, b: a - b, theta_new, theta)
-    metrics = {
-        "opt_score_mean": opt_scores.mean(),
-        "opt_score_best": opt_scores.max(),
-        "opt_score_worst": opt_scores.min(),
-        "sigma_bar": sigma_bar,
-        "n_finite": n_finite,
-        "theta_norm": global_norm(theta_new),
-        "delta_norm": global_norm(delta),
-    }
-    # ES-semantic health diagnostics (es/ prefix) ride along in the same
-    # metrics pytree — no extra dispatches (obs/es_health.py contract).
-    metrics.update(
-        es_health_metrics(
-            opt_scores=opt_scores,
-            fitness=fitness,
-            delta=delta,
-            prev_delta=prev_delta,
-            cap_theta_scale=theta_scale,
-            cap_step_scale=step_scale,
-            pop_size=pop,
-            antithetic=es_cfg.antithetic,
-        )
-    )
-    for k in REWARD_KEYS:
-        if k in rewards:
-            metrics[f"reward/{k}_mean"] = rewards[k].mean()
-    # per-prompt raw means (reference per-prompt W&B panels,
-    # unifed_es.py:307-310)
-    metrics["per_prompt_mean"] = S.mean(axis=0)  # [m]
-    # per-prompt × per-term quality attribution (quality/ prefix) rides the
-    # same pytree — zero extra dispatches (obs/quality.py, the es_health
-    # contract; CI asserts the obs/dispatches counter is identical on/off)
-    if getattr(tc, "quality", True):
-        from ..obs.quality import quality_metrics
-
-        metrics.update(
-            quality_metrics(
-                rewards, pop=pop, num_unique=num_unique, repeats=repeats,
-                reward_keys=REWARD_KEYS,
+        with jax.named_scope("health"):
+            metrics = {
+                "opt_score_mean": opt_scores.mean(),
+                "opt_score_best": opt_scores.max(),
+                "opt_score_worst": opt_scores.min(),
+                "sigma_bar": sigma_bar,
+                "n_finite": n_finite,
+                "theta_norm": global_norm(theta_new),
+                "delta_norm": global_norm(delta),
+            }
+            # ES-semantic health diagnostics (es/ prefix) ride along in the same
+            # metrics pytree — no extra dispatches (obs/es_health.py contract).
+            metrics.update(
+                es_health_metrics(
+                    opt_scores=opt_scores,
+                    fitness=fitness,
+                    delta=delta,
+                    prev_delta=prev_delta,
+                    cap_theta_scale=theta_scale,
+                    cap_step_scale=step_scale,
+                    pop_size=pop,
+                    antithetic=es_cfg.antithetic,
+                )
             )
-        )
+            for k in REWARD_KEYS:
+                if k in rewards:
+                    metrics[f"reward/{k}_mean"] = rewards[k].mean()
+            # per-prompt raw means (reference per-prompt W&B panels,
+            # unifed_es.py:307-310)
+            metrics["per_prompt_mean"] = S.mean(axis=0)  # [m]
+            # per-member raw combined reward, before promptnorm: the one row
+            # of metrics.jsonl that depends on each member's perturbation
+            metrics["es/member_reward"] = S.mean(axis=1)  # [pop]
+            # per-prompt × per-term quality attribution (quality/ prefix) rides the
+            # same pytree — zero extra dispatches (obs/quality.py, the es_health
+            # contract; CI asserts the obs/dispatches counter is identical on/off)
+            if getattr(tc, "quality", True):
+                from ..obs.quality import quality_metrics
+
+                metrics.update(
+                    quality_metrics(
+                        rewards, pop=pop, num_unique=num_unique, repeats=repeats,
+                        reward_keys=REWARD_KEYS,
+                    )
+                )
     return theta_new, delta, metrics, opt_scores
 
 
@@ -179,6 +189,20 @@ def _resolve_update_fn(tc: TrainConfig, es_cfg, mesh):
         True,
         int(mesh.shape[POP_AXIS]),
     )
+
+
+def host_reduce_keys(scalars: Dict[str, Any]) -> List[str]:
+    """Keys of one epoch's ``scalars`` that a pod averages across hosts in the
+    per-epoch ``host_scalar_allgather``: the host-local clocks and the scalar
+    ``es/`` health figures. Vector rows (``es/member_reward``, ``[pop]``) stay
+    out — the gather carries one float a key, and reward rows are already
+    replicated-global (pop_eval all-gathers scores in-graph)."""
+    return [
+        k for k, v in scalars.items()
+        if not isinstance(v, (list, tuple))
+        and (k in ("step_time_s", "images_per_sec", "mfu")
+             or (k.startswith("es/") and not k.startswith("es/leaf_")))
+    ]
 
 
 def make_host_sharded_programs(
@@ -235,7 +259,8 @@ def make_host_sharded_programs(
 
     def eval_slice(frozen: Pytree, theta: Pytree, flat_ids: jax.Array, key: jax.Array):
         k_noise, k_gen = jax.random.split(key)
-        noise = sample_noise(k_noise, theta, pop, es_cfg)
+        with jax.named_scope("es_noise"):
+            noise = sample_noise(k_noise, theta, pop, es_cfg)
         return eval_slice_pop(frozen, theta, noise, flat_ids, k_gen)
 
     # The pod's replicated update composes with the pop-sharded contraction:
@@ -247,7 +272,8 @@ def make_host_sharded_programs(
     def update(theta: Pytree, prev_delta: Pytree,
                rewards: Dict[str, jax.Array], key: jax.Array):
         k_noise, _ = jax.random.split(key)
-        noise = sample_noise(k_noise, theta, pop, es_cfg)
+        with jax.named_scope("es_noise"):
+            noise = sample_noise(k_noise, theta, pop, es_cfg)
         return _combine_and_update(
             theta, prev_delta, noise, rewards, tc=tc, es_cfg=es_cfg,
             pop=pop, num_unique=num_unique, repeats=repeats,
@@ -310,7 +336,8 @@ def make_es_step(
         key: jax.Array,
     ):
         k_noise, k_gen = jax.random.split(key)
-        noise = sample_noise(k_noise, theta, pop, es_cfg)
+        with jax.named_scope("es_noise"):
+            noise = sample_noise(k_noise, theta, pop, es_cfg)
 
         rewards = eval_pop(frozen, theta, noise, flat_ids, k_gen)  # dict of [pop, B]
         # trace-time geometry for the enclosing compile's ledger record
@@ -653,7 +680,14 @@ def run_training(
     # (parallel/pop_eval.py) emit into the same file. The registry is fresh
     # per run — a second same-process run's counters must not include the
     # first run's activity.
-    tracer = set_tracer(Tracer(trace_segment_path(run_dir)) if tc.trace else None)
+    # A tracer that train.cli.main made on entry (enabled, no file yet: it
+    # holds the build's spans) is adopted and given this run's file; any
+    # other caller gets a fresh one, as before.
+    tracer = get_tracer()
+    if tc.trace and tracer.enabled and tracer.path is None:
+        tracer.attach(trace_segment_path(run_dir))
+    else:
+        tracer = set_tracer(Tracer(trace_segment_path(run_dir)) if tc.trace else None)
     registry = set_registry(MetricsRegistry())
     # Per-compiled-program XLA ledger (obs/xla_cost.py): one JSON record per
     # AOT compile → run_dir/programs.jsonl. Master-only like metrics.jsonl —
@@ -1383,22 +1417,23 @@ def run_training(
                                     backend, reward_fn, tc_live, m, r, mesh,
                                     (host_lo, host_lpop),
                                 )
-                                t_l0 = time.perf_counter()
-                                lowered = eval_j.lower(frozen, state.theta, flat_ids, key)
-                                # reward-leaf structs come from the lowering
-                                # already in hand — jax.eval_shape here would
-                                # re-trace the whole generate→reward program
-                                # (the largest in the system) a second time
-                                rew_struct = jax.tree_util.tree_map(
-                                    lambda s: jax.ShapeDtypeStruct(
-                                        (n_live * s.shape[0], *s.shape[1:]), s.dtype
-                                    ),
-                                    lowered.out_info,
-                                )
-                                lowered_u = upd_j.lower(
-                                    state.theta, prev_delta, rew_struct, key
-                                )
-                                lowering_s = time.perf_counter() - t_l0
+                                with tracer.span("lower"):
+                                    t_l0 = time.perf_counter()
+                                    lowered = eval_j.lower(frozen, state.theta, flat_ids, key)
+                                    # reward-leaf structs come from the lowering
+                                    # already in hand — jax.eval_shape here would
+                                    # re-trace the whole generate→reward program
+                                    # (the largest in the system) a second time
+                                    rew_struct = jax.tree_util.tree_map(
+                                        lambda s: jax.ShapeDtypeStruct(
+                                            (n_live * s.shape[0], *s.shape[1:]), s.dtype
+                                        ),
+                                        lowered.out_info,
+                                    )
+                                    lowered_u = upd_j.lower(
+                                        state.theta, prev_delta, rew_struct, key
+                                    )
+                                    lowering_s = time.perf_counter() - t_l0
                                 t_c0 = time.perf_counter()
                                 compiled_e = lowered.compile()
                                 compiled_u = lowered_u.compile()
@@ -1447,11 +1482,12 @@ def run_training(
                                     backend, reward_fn, tc_live, m, r, mesh,
                                     stateful_delta=True,
                                 )
-                                t_l0 = time.perf_counter()
-                                lowered = jitted.lower(
-                                    frozen, state.theta, prev_delta, flat_ids, key
-                                )
-                                lowering_s = time.perf_counter() - t_l0
+                                with tracer.span("lower"):
+                                    t_l0 = time.perf_counter()
+                                    lowered = jitted.lower(
+                                        frozen, state.theta, prev_delta, flat_ids, key
+                                    )
+                                    lowering_s = time.perf_counter() - t_l0
                                 t_c0 = time.perf_counter()
                                 compiled = lowered.compile()
                                 compile_s = time.perf_counter() - t_c0
@@ -1526,11 +1562,12 @@ def run_training(
 
                             logger.info(f"compiling {K}-epoch chained step for (m={m}, r={r})")
                             with tracer.span("compile", m=m, r=r, chain=K), _hb("compile"):
-                                t_l0 = time.perf_counter()
-                                lowered_k = jax.jit(multi, donate_argnums=(1, 2)).lower(
-                                    frozen, state.theta, prev_delta, ids_k, keys_k
-                                )
-                                lowering_s = time.perf_counter() - t_l0
+                                with tracer.span("lower"):
+                                    t_l0 = time.perf_counter()
+                                    lowered_k = jax.jit(multi, donate_argnums=(1, 2)).lower(
+                                        frozen, state.theta, prev_delta, ids_k, keys_k
+                                    )
+                                    lowering_s = time.perf_counter() - t_l0
                                 t_c0 = time.perf_counter()
                                 chain_cache[(m, r, K)] = compiled_k = lowered_k.compile()
                                 compile_s = time.perf_counter() - t_c0
@@ -1554,13 +1591,15 @@ def run_training(
                         # no device gauges inside the timed window — a gauge is a
                         # device query contending with the dispatch being measured
                         with tracer.span("dispatch", epochs=K), _hb("dispatch", gauges=None):
-                            state.theta, prev_delta, metrics, opt_scores = chain_cache[(m, r, K)](
-                                frozen, state.theta, prev_delta, ids_k, keys_k
-                            )
+                            with tracer.span("enqueue"):
+                                state.theta, prev_delta, metrics, opt_scores = chain_cache[(m, r, K)](
+                                    frozen, state.theta, prev_delta, ids_k, keys_k
+                                )
                             # device_get is the execution sync (the fetched values
                             # depend on every chained epoch), so it belongs inside
                             # the dispatch span.
-                            metrics = jax.device_get(metrics)
+                            with tracer.span("fetch"):
+                                metrics = jax.device_get(metrics)
                         info = infos[-1]  # logged prompts = the chain's last epoch
                     else:
                         hist_due = master and tc.log_hist_every and (epoch + 1) % tc.log_hist_every == 0
@@ -1584,11 +1623,16 @@ def run_training(
                                 from ..resilience import slow_fault_seconds
 
                                 time.sleep(slow_fault_seconds())
-                            state.theta, prev_delta, metrics, opt_scores = step(
-                                frozen, state.theta, prev_delta, flat_ids, key
-                            )
+                            # enqueue: the call of the compiled step returns once
+                            # the program is launched; fetch: device_get waits for
+                            # it and copies the metrics out
+                            with tracer.span("enqueue"):
+                                state.theta, prev_delta, metrics, opt_scores = step(
+                                    frozen, state.theta, prev_delta, flat_ids, key
+                                )
                             out_struct.setdefault((m, r), (metrics, opt_scores))
-                            metrics = jax.device_get(metrics)
+                            with tracer.span("fetch"):
+                                metrics = jax.device_get(metrics)
 
                     # the timing boundary first: the memory gauge below is a
                     # device query whose latency must not leak into step_time_s
@@ -1695,11 +1739,7 @@ def run_training(
                     # instead of a special case.
                     t_anchor0 = t_anchor1 = time.perf_counter()
                     if pc > 1:
-                        reduce_keys = [
-                            k for k in scalars
-                            if k in ("step_time_s", "images_per_sec", "mfu")
-                            or (k.startswith("es/") and not k.startswith("es/leaf_"))
-                        ]
+                        reduce_keys = host_reduce_keys(scalars)
                         desync_due = (
                             tc.desync_check_every > 0
                             and (epoch_last + 1) % tc.desync_check_every == 0
@@ -2164,6 +2204,7 @@ def run_training(
         # run (tests, sweeps); re-arm per run via config/env
         set_fault_plan(None)
         set_resilience_registry(None)
+        tracer.close()
         set_tracer(None)
         set_registry(None)
         set_ledger(None)
