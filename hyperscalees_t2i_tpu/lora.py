@@ -15,7 +15,12 @@ Conventions
 -----------
 - dense kernels are ``[d_in, d_out]`` (or stacked ``[L, d_in, d_out]`` for
   scan-over-layers blocks); LoRA factors are ``a: [.., d_in, r]``,
-  ``b: [.., r, d_out]``.
+  ``b: [.., r, d_out]``. The leading axis of a 3D kernel may equally be an
+  **expert axis** (``[E, d_in, d_out]``, models/lm.py): every expert gets its
+  own factors, its own EGGROLL noise (``es/noiser.sample_noise`` draws
+  ``U: [base, E, m, r]``) and, in a :class:`FactoredDelta`, its own
+  ``u[e] v[e]ᵀ`` under the member's one coefficient; ``ops/grouped.py``
+  consumes them.
 - init matches PEFT: ``a ~ N(0, 1/d_in)``, ``b = 0`` → the adapter starts as
   the identity, exactly like ``get_peft_model`` with default init.
 - targeting is by parameter-path substring match, compatible in spirit with

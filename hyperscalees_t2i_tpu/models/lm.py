@@ -1,0 +1,638 @@
+"""A sparse-expert decoder language model as an autoregressive image-token
+generator: multi-head latent attention (MLA) over a latent cache, sandwich
+norm, a sigmoid top-k router over routed experts of which this chip holds a
+share, a shared expert, and a multi-token-prediction (MTP) module.
+
+Sizes come from a ``config.json``-shaped file (:meth:`LMConfig.from_json`):
+the published keys of a DeepSeek-V3-family ``config.json`` (``hidden_size``,
+``q_lora_rank``, ``kv_lora_rank``, ``n_routed_experts`` …) plus the share this
+chip holds of a stated deployment (``experts_held``, ``expert_offset``,
+``vocab_rows_held``) and the system's own use of the model (``image_tokens``).
+No preset table: a user with a checkpoint directory states sizes the same way.
+
+The layer equations (the plain float32 form is ``reference/lm_reference.py``,
+written from the same description and sharing no code with this file):
+
+- block: ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(FFN(N3(h)))``;
+- MLA: ``cq = Nq(u Wdq)``, ``q = cq Wuq`` → heads × (nope | rope);
+  ``[ckv | kr] = u Wdkv``, ``ckv ← Nkv(ckv)``, ``[k_nope | v] = ckv Wukv``;
+  RoPE on ``q_rope`` and on the one ``kr`` all heads share; the **cache holds
+  ``[ckv | rope(kr)]``** — ``kv_lora_rank + qk_rope_head_dim`` numbers a token
+  a layer, not per-head K/V. Prefill expands K/V; a decode step uses the
+  absorbed form (``Wukv``'s K half folded into the query, its V half applied
+  after the weighted sum over ``ckv``), LoRA delta included;
+- router: ``s = sigmoid(f32(u) Wrᵀ)``, top-k by ``s``,
+  ``w = s_top / (Σ s_top + 1e-20) · routed_scaling_factor``;
+  ``MoE(u) = Shared(u) + Σ_{e ∈ top-k, e held} w_e E_e(u)`` — the router keeps
+  every output, the chip computes its own experts' part for the tokens routed
+  to them, normalized over all k chosen; what absent experts would add is
+  left out and no code stands in for them or their exchange;
+- MTP: ``h' = Block([Nh(h_i) ; Ne(Emb(t_{i+1}))] Wp)``, shared final norm and
+  head. In the model and the reference; not run by :func:`generate` (at plain
+  sampling the family discards it).
+
+Generation: prefill the (padded, masked) prompt ids into the latent cache,
+then ``image_tokens.count`` steps of ``lax.scan`` — embed the last id (the
+begin-of-image id first), one pass of the blocks over the cache, final norm,
+head over the rows held, the image-id range of the logits, top-k/top-p
+sampling under a key shared by the members — and the VQ decoder of
+``models/msvq.py`` over the sampled grid.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+from pathlib import Path
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..lora import LoRASpec, effective_factor, lookup
+from ..ops import grouped
+from ..ops.quant import maybe_quantize_tree, resolve_kernel
+from ..ops.sampling import sample_top_k_top_p
+from . import msvq, nn
+
+Params = Dict[str, Any]
+
+# every MLA projection, the dense FFN, the shared expert and each held routed
+# expert's three matrices; router, norms, embedding, head and MTP stay frozen
+LM_LORA_TARGETS: Tuple[str, ...] = (
+    r"^layers/\d+/mla/(wdq|wuq|wdkv|wukv|wo)$",
+    r"^layers/\d+/ffn/(gate|up|down)$",
+    r"^layers/\d+/moe/shared/(gate|up|down)$",
+    r"^layers/\d+/moe/experts/(gate|up|down)$",
+)
+
+PUBLISHED_KEYS = (
+    "hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "rope_theta", "rms_norm_eps",
+    "intermediate_size", "moe_intermediate_size", "n_routed_experts", "num_experts_per_tok",
+    "n_shared_experts", "routed_scaling_factor", "norm_topk_prob", "num_hidden_layers",
+    "first_k_dense_replace", "num_nextn_predict_layers", "vocab_size",
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    # --- the model's own config.json keys
+    hidden_size: int = 7680
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 25_600_000.0
+    rms_norm_eps: float = 1e-5
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 3
+    num_nextn_predict_layers: int = 1
+    vocab_size: int = 153600
+    # --- this chip's share of the deployment (model-configs §4)
+    experts_held: int = 256
+    expert_offset: int = 0
+    vocab_rows_held: int = 153600
+    # --- the system's use of it: image ids and how they are sampled
+    image_vocab: int = 4096
+    image_id_offset: int = 0
+    boi_id: int = 1
+    grid: int = 16
+    max_prompt_len: int = 64
+    top_k: int = 900
+    top_p: float = 0.96
+    decode_batch: int = 0  # images a member decodes at a time (0: all of its batch)
+    vq: msvq.MSVQConfig = dataclasses.field(default_factory=msvq.MSVQConfig)
+    compute_dtype: Any = jnp.bfloat16
+
+    def __post_init__(self) -> None:
+        if self.n_shared_experts != 1:
+            raise ValueError("one shared expert is what this model code writes down")
+        if not 0 <= self.expert_offset <= self.n_routed_experts - self.experts_held:
+            raise ValueError(f"experts [{self.expert_offset}, +{self.experts_held}) of {self.n_routed_experts}")
+        if self.image_id_offset + self.image_vocab > self.vocab_rows_held:
+            raise ValueError("the image-id range lies outside the vocabulary rows held")
+        if self.vq.vocab_size != self.image_vocab or self.vq.patch_nums[-1] != self.grid:
+            raise ValueError("the VQ codebook and grid must match image_vocab and grid")
+
+    @classmethod
+    def from_json(cls, path: str) -> "LMConfig":
+        """A ``config.json``-shaped file: published keys, the share keys, an
+        ``image_tokens`` group, ``vq`` (``MSVQConfig`` keys) and ``torch_dtype``."""
+        raw = json.loads(Path(path).read_text())
+        kw = {k: raw[k] for k in PUBLISHED_KEYS if k in raw}
+        kw["experts_held"] = raw.get("experts_held", kw.get("n_routed_experts", cls.n_routed_experts))
+        kw["expert_offset"] = raw.get("expert_offset", 0)
+        kw["vocab_rows_held"] = raw.get("vocab_rows_held", kw.get("vocab_size", cls.vocab_size))
+        img = raw.get("image_tokens", {})
+        for k in ("image_vocab", "image_id_offset", "boi_id", "grid", "max_prompt_len", "top_k", "top_p",
+                  "decode_batch"):
+            if k in img:
+                kw[k] = img[k]
+        dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[raw.get("torch_dtype", "bfloat16")]
+        vq = {k: tuple(v) if isinstance(v, list) else v for k, v in raw.get("vq", {}).items()}
+        grid = kw.get("grid", cls.grid)
+        vq.setdefault("vocab_size", kw.get("image_vocab", cls.image_vocab))
+        vq["patch_nums"] = (grid,)  # one scale: the ids are the grid
+        return cls(vq=msvq.MSVQConfig(compute_dtype=dt, **vq), compute_dtype=dt, **kw)
+
+    @property
+    def image_tokens(self) -> int:
+        return self.grid * self.grid
+
+    @property
+    def cache_len(self) -> int:
+        return self.max_prompt_len + self.image_tokens
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def is_moe(self, layer: int) -> bool:
+        return layer >= self.first_k_dense_replace
+
+    def lora_spec(self, rank: int = 8, alpha: float = 16.0) -> LoRASpec:
+        return LoRASpec(rank=rank, alpha=alpha, targets=LM_LORA_TARGETS)
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def _kernel(key, shape, dt, std=None) -> Params:
+    std = std if std is not None else 1.0 / math.sqrt(shape[-2])
+    return {"kernel": (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)}
+
+
+def _norm(dim: int) -> Params:
+    return {"scale": jnp.ones((dim,), jnp.float32)}
+
+
+def _swiglu_init(key, d: int, f: int, dt, experts: int = 0) -> Params:
+    kg, ku, kd = jax.random.split(key, 3)
+    lead = (experts,) if experts else ()
+    return {"gate": _kernel(kg, lead + (d, f), dt), "up": _kernel(ku, lead + (d, f), dt),
+            "down": _kernel(kd, lead + (f, d), dt)}
+
+
+def _block_init(key, cfg: LMConfig, moe: bool) -> Params:
+    d, H, dt = cfg.hidden_size, cfg.num_attention_heads, cfg.compute_dtype
+    ks = jax.random.split(key, 8)
+    p: Params = {
+        "n1": _norm(d), "n2": _norm(d), "n3": _norm(d), "n4": _norm(d),
+        "mla": {
+            "wdq": _kernel(ks[0], (d, cfg.q_lora_rank), dt),
+            "q_norm": _norm(cfg.q_lora_rank),
+            "wuq": _kernel(ks[1], (cfg.q_lora_rank, H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)), dt),
+            "wdkv": _kernel(ks[2], (d, cfg.cache_width), dt),
+            "kv_norm": _norm(cfg.kv_lora_rank),
+            "wukv": _kernel(ks[3], (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt),
+            "wo": _kernel(ks[4], (H * cfg.v_head_dim, d), dt),
+        },
+    }
+    if moe:
+        p["moe"] = {
+            # float32 and never quantized: the model's code routes in float32
+            "router": {"weight": jax.random.normal(ks[5], (cfg.n_routed_experts, d), jnp.float32) / math.sqrt(d)},
+            "experts": _swiglu_init(ks[6], d, cfg.moe_intermediate_size, dt, experts=cfg.experts_held),
+            "shared": _swiglu_init(ks[7], d, cfg.moe_intermediate_size, dt),
+        }
+    else:
+        p["ffn"] = _swiglu_init(ks[6], d, cfg.intermediate_size, dt)
+    return p
+
+
+def init_lm(key: jax.Array, cfg: LMConfig, base_quant: str = "off") -> Params:
+    """Seeded parameters. ``base_quant="int8"`` quantizes each kernel inside
+    the same program (``ops/quant.maybe_quantize_tree``, what ``train.cli``'s
+    later ``quantize_frozen`` pass would do and then finds done): at the
+    published widths the float tree (10 GB in bf16) and its int8 copy do not
+    fit one chip together, so a kernel's float form lives only until its
+    int8 form exists."""
+    d, dt = cfg.hidden_size, cfg.compute_dtype
+    L = cfg.num_hidden_layers
+    ks = jax.random.split(key, L + 5)
+    q = lambda tree: maybe_quantize_tree(tree, base_quant)
+    params: Params = {
+        "embed": (jax.random.normal(ks[0], (cfg.vocab_rows_held, d), jnp.float32) * 0.02).astype(dt),
+        "layers": [q(_block_init(ks[1 + i], cfg, cfg.is_moe(i))) for i in range(L)],
+        "final_norm": _norm(d),
+        "head": q(_kernel(ks[L + 1], (d, cfg.vocab_rows_held), dt)),
+        "vq": q(msvq.init_msvq(ks[L + 2], cfg.vq)),
+    }
+    if cfg.num_nextn_predict_layers:
+        km = jax.random.split(ks[L + 3], 2 * cfg.num_nextn_predict_layers)
+        params["mtp"] = [q({
+            "nh": _norm(d), "ne": _norm(d),
+            "proj": _kernel(km[2 * i], (2 * d, d), dt),
+            "block": _block_init(km[2 * i + 1], cfg, moe=True),
+        }) for i in range(cfg.num_nextn_predict_layers)]
+    return params
+
+
+# ---------------------------------------------------------------------------
+# layers
+# ---------------------------------------------------------------------------
+
+def _rms(x: jax.Array, p: Params, cfg: LMConfig) -> jax.Array:
+    return nn.rms_norm(x, p, eps=cfg.rms_norm_eps)
+
+
+def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """Plain RoPE, rotate-half convention (assumed: the config has no scaling
+    keys): ``x [..., dr]`` at positions ``pos`` broadcastable to ``x[..., 0]``."""
+    half = x.shape[-1] // 2
+    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[..., None] * freqs
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def _swiglu(p: Params, u: jax.Array, lora: Optional[Params], path: str, scale: float) -> jax.Array:
+    g = nn.dense(p["gate"], u, lookup(lora, f"{path}/gate"), scale)
+    v = nn.dense(p["up"], u, lookup(lora, f"{path}/up"), scale)
+    return nn.dense(p["down"], jax.nn.silu(g) * v, lookup(lora, f"{path}/down"), scale)
+
+
+def _mla_project(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array,
+                 lora: Optional[Params], path: str, scale: float, entry_only: bool = False):
+    """``u [..., d]`` at positions ``pos [...]`` → ``q_nope [..., H, dn]``,
+    roped ``q_rope [..., H, dr]`` and the cache entry ``[ckv | rope(kr)]``
+    (``entry_only``: the entry alone, the query path not run)."""
+    H, dn, dr, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    kvr = nn.dense(p["wdkv"], u, lookup(lora, f"{path}/wdkv"), scale)
+    entry = jnp.concatenate([_rms(kvr[..., :c], p["kv_norm"], cfg),
+                             _rope(kvr[..., c:], pos, cfg.rope_theta)], axis=-1)
+    if entry_only:
+        return entry
+    cq = _rms(nn.dense(p["wdq"], u, lookup(lora, f"{path}/wdq"), scale), p["q_norm"], cfg)
+    q = nn.dense(p["wuq"], cq, lookup(lora, f"{path}/wuq"), scale).reshape(*u.shape[:-1], H, dn + dr)
+    q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], pos[..., None], cfg.rope_theta)
+    return q_nope, q_rope, entry
+
+
+def mla_prefill(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array, valid: jax.Array,
+                lora: Optional[Params], path: str, scale: float) -> Tuple[jax.Array, jax.Array]:
+    """Whole-sequence causal MLA with expanded K/V: ``u [S, T, d]``, positions
+    ``pos [S, T]``, key validity ``valid [S, T]`` → (out ``[S, T, d]``, cache
+    entries ``[S, T, c + dr]``)."""
+    S, T, _ = u.shape
+    H, dn, dv, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    q_nope, q_rope, entry = _mla_project(p, cfg, u, pos, lora, path, scale)
+    kv = nn.dense(p["wukv"], entry[..., :c], lookup(lora, f"{path}/wukv"), scale).reshape(S, T, H, dn + dv)
+    with jax.named_scope("attend"):
+        f32 = jnp.float32
+        sc = (jnp.einsum("sqhj,skhj->shqk", q_nope, kv[..., :dn], preferred_element_type=f32)
+              + jnp.einsum("sqhr,skr->shqk", q_rope, entry[..., c:], preferred_element_type=f32))
+        sc = sc / math.sqrt(dn + cfg.qk_rope_head_dim)
+        see = jnp.tril(jnp.ones((T, T), bool))[None, None] & valid[:, None, None, :]
+        pr = jax.nn.softmax(jnp.where(see, sc, -1e30), axis=-1)
+        o = jnp.einsum("shqk,skhv->sqhv", pr.astype(u.dtype), kv[..., dn:]).reshape(S, T, H * dv)
+    return nn.dense(p["wo"], o, lookup(lora, f"{path}/wo"), scale), entry
+
+
+def mla_decode(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array, cache: jax.Array, slot: jax.Array,
+               valid: jax.Array, lora: Optional[Params], path: str, scale: float) -> Tuple[jax.Array, jax.Array]:
+    """One position a sequence over the latent cache, absorbed form:
+    ``u [S, d]`` at positions ``pos [S]``; ``cache [S, Tmax, c + dr]`` gets the
+    new entry at ``slot``; ``valid [S, Tmax]`` names the slots a query sees.
+    ``Wukv`` (and its LoRA delta, kept in factors) never expands the cache:
+    its K half is folded into the query, its V half applied to the weighted
+    sum over ``ckv``."""
+    S = u.shape[0]
+    H, dn, dv, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    dt = u.dtype
+    q_nope, q_rope, entry = _mla_project(p, cfg, u, pos, lora, path, scale)
+    cache = jax.lax.dynamic_update_slice(cache, entry[:, None, :].astype(cache.dtype), (0, slot, 0))
+    with jax.named_scope("attend"):
+        f32 = jnp.float32
+        w = resolve_kernel(p["wukv"], dt).reshape(c, H, dn + dv)
+        leaf = lookup(lora, f"{path}/wukv")
+        q_lat = jnp.einsum("shj,chj->shc", q_nope, w[..., :dn])
+        if leaf is not None:
+            a = effective_factor(leaf["a"], dt)                                # [c, r]
+            b = effective_factor(leaf["b"], dt).reshape(-1, H, dn + dv)        # [r, H, dn + dv]
+            s = jnp.asarray(scale, dt)
+            q_lat = q_lat + s * jnp.einsum("shr,cr->shc", jnp.einsum("shj,rhj->shr", q_nope, b[..., :dn]), a)
+        ckv, kr = cache[..., :c], cache[..., c:]
+        sc = (jnp.einsum("shc,stc->sht", q_lat, ckv, preferred_element_type=f32)
+              + jnp.einsum("shr,str->sht", q_rope, kr, preferred_element_type=f32))
+        sc = sc / math.sqrt(dn + cfg.qk_rope_head_dim)
+        pr = jax.nn.softmax(jnp.where(valid[:, None, :], sc, -1e30), axis=-1)
+        o_lat = jnp.einsum("sht,stc->shc", pr.astype(dt), ckv)
+        o = jnp.einsum("shc,chv->shv", o_lat, w[..., dn:])
+        if leaf is not None:
+            o = o + s * jnp.einsum("shr,rhv->shv", jnp.einsum("shc,cr->shr", o_lat, a), b[..., dn:])
+    return nn.dense(p["wo"], o.reshape(S, H * dv), lookup(lora, f"{path}/wo"), scale), cache
+
+
+def route(p: Params, cfg: LMConfig, u: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``u [R, d]`` → (expert ids ``[R, k]`` of all ``n_routed_experts``,
+    weights ``[R, k]`` normalized over the k chosen). No groups, no bias."""
+    s = jax.nn.sigmoid(u.astype(jnp.float32) @ p["router"]["weight"].T)
+    top_s, top_i = jax.lax.top_k(s, cfg.num_experts_per_tok)
+    w = top_s / (top_s.sum(-1, keepdims=True) + 1e-20) if cfg.norm_topk_prob else top_s
+    return top_i.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+# Rows a member at or under which every held expert multiplies every row
+# (:func:`_routed_dense`). Any row count up to the MXU's height costs the MXU
+# the same, and on a v5e a product whose FLOPs take less time than its int8
+# base takes to read (rows < 197e12 / (2 x 819e9) = 120) is bound by that
+# read either way: there the dense form reads each expert once with the
+# dequantization fused into the dot's operand, where the compiler's grouped
+# kernel measured 1.3 ms a [16, 7680, 2048] s8 kernel against a 0.31 ms read
+# (my chip run, PR 27). The member axis is not visible under ``vmap``, so the
+# choice is by a member's rows: a chunk of 8 members is 8 x 16 = 128 rows.
+DENSE_ROWS = 16
+
+
+def _expert_einsum(spec: str, x: jax.Array, node: Params) -> jax.Array:
+    """``einsum(spec, x, kernel)`` over an ``[E, din, dout]`` node whose output
+    is ``[..., E, dout]``; an int8 kernel enters the dot as it is (the convert
+    fuses into the operand read) and the per-(expert, channel) scale is
+    applied to the result."""
+    if "kernel" in node:
+        return jnp.einsum(spec, x, node["kernel"].astype(x.dtype))
+    qk = node["kernel_q8"]
+    y = jnp.einsum(spec, x, qk["q8"].astype(x.dtype), preferred_element_type=jnp.float32)
+    return (y * qk["scale"][:, 0, :]).astype(x.dtype)
+
+
+def _routed_dense(p: Params, cfg: LMConfig, u: jax.Array, wt: jax.Array,
+                  factors: Optional[Dict[str, Any]], scale: float) -> jax.Array:
+    """Few rows: all ``E`` held experts over all rows ``u [R, d]``, weighted by
+    ``wt [R, E]`` (0 where a row did not choose the expert)."""
+    E = cfg.experts_held
+    g = _expert_einsum("td,edf->tef", u, p["gate"])
+    v = _expert_einsum("td,edf->tef", u, p["up"])
+    s = jnp.asarray(scale, u.dtype)
+    if factors is not None:
+        (ag, bg), (au, bu), (ad, bd) = factors["gate"], factors["up"], factors["down"]
+        R = u.shape[0]
+        g = g + s * jnp.einsum("ter,erf->tef", (u @ ag).reshape(R, E, -1), bg.reshape(E, -1, bg.shape[-1]))
+        v = v + s * jnp.einsum("ter,erf->tef", (u @ au).reshape(R, E, -1), bu.reshape(E, -1, bu.shape[-1]))
+    h = jax.nn.silu(g) * v
+    y = _expert_einsum("tef,efd->ted", h, p["down"])
+    if factors is not None:
+        z = jnp.einsum("tef,fer->ter", h, ad.reshape(ad.shape[0], E, -1))
+        y = y + s * jnp.einsum("ter,erd->ted", z, bd.reshape(E, -1, bd.shape[-1]))
+    return jnp.einsum("te,ted->td", wt.astype(u.dtype), y)
+
+
+def routed_experts(p: Params, cfg: LMConfig, u: jax.Array, top_i: jax.Array, top_w: jax.Array,
+                   row_valid: jax.Array, factors: Optional[Dict[str, Any]], scale: float):
+    """``Σ_{e ∈ top-k, e held} w_e E_e(u)`` for ``u [R, d]``: every pair whose
+    expert is held here is computed, none dropped. ``factors``: this member's
+    :func:`expert_factors` of the layer. Returns (``[R, d]``, local expert of
+    each pair ``[R, k]`` with ``experts_held`` for "not here"). Many rows go
+    through the grouped products of ``ops/grouped.py``, a few through
+    :func:`_routed_dense` (:data:`DENSE_ROWS`); both give the same sums."""
+    R, d = u.shape
+    K, E = cfg.num_experts_per_tok, cfg.experts_held
+    local = top_i - cfg.expert_offset
+    held = (local >= 0) & (local < E) & row_valid[:, None]
+    e = jnp.where(held, local, E)
+    w = jnp.where(held, top_w, 0.0)
+    if R <= DENSE_ROWS:
+        wt = (jax.nn.one_hot(e, E + 1, dtype=jnp.float32)[..., :E] * w[..., None]).sum(1)
+        return _routed_dense(p, cfg, u, wt, factors, scale), e
+    e = e.reshape(R * K)
+    xp = jnp.repeat(u, K, axis=0)
+    g = grouped.grouped_matmul(xp, e, p["gate"])
+    v = grouped.grouped_matmul(xp, e, p["up"])
+    s = jnp.asarray(scale, u.dtype)
+    if factors is not None:
+        (ag, bg), (au, bu), (ad, bd) = factors["gate"], factors["up"], factors["down"]
+        g = g + s * grouped.expert_lora_rows(jnp.repeat(u @ ag, K, axis=0), e, bg, E)
+        v = v + s * grouped.expert_lora_rows(jnp.repeat(u @ au, K, axis=0), e, bu, E)
+    h = jax.nn.silu(g) * v
+    y = grouped.grouped_matmul(h, e, p["down"])
+    if factors is not None:
+        y = y + s * grouped.expert_lora_rows(h @ ad, e, bd, E)
+    return jnp.einsum("rk,rkd->rd", w.astype(u.dtype), y.reshape(R, K, d)), e.reshape(R, K)
+
+
+def expert_factors(lora: Optional[Params], cfg: LMConfig, dtype) -> Optional[List[Optional[Dict[str, Any]]]]:
+    """Per layer, one member's routed-expert LoRA factors laid side by side
+    (``ops/grouped.expert_lora_factors``): built once a generation, outside
+    the decode loop, from the adapter tree (raw or ``FactoredDelta`` leaves)."""
+    if lora is None:
+        return None
+    out: List[Optional[Dict[str, Any]]] = []
+    for li in range(cfg.num_hidden_layers):
+        leaves = {m: lookup(lora, f"layers/{li}/moe/experts/{m}") for m in ("gate", "up", "down")}
+        out.append(None if any(v is None for v in leaves.values()) else
+                   {m: grouped.expert_lora_factors(v, dtype) for m, v in leaves.items()})
+    return out
+
+
+def moe(p: Params, cfg: LMConfig, u: jax.Array, row_valid: jax.Array, lora: Optional[Params],
+        factors: Optional[Dict[str, Any]], path: str, scale: float):
+    """``u [R, d]`` → (``[R, d]``, counters of this call). ``row_valid`` marks
+    rows that are tokens (padding is routed nowhere)."""
+    with jax.named_scope("router"):
+        top_i, top_w = route(p, cfg, u)
+    with jax.named_scope("shared"):
+        shared = _swiglu(p["shared"], u, lora, f"{path}/shared", scale)
+    with jax.named_scope("experts"):
+        routed, e = routed_experts(p["experts"], cfg, u, top_i, top_w, row_valid, factors, scale)
+        stats = {
+            "assign": (e < cfg.experts_held).sum(-1).astype(jnp.int32),           # [R] pairs computed here
+            "load": grouped.expert_load_ratio(e.reshape(-1), cfg.experts_held),   # scalar, this call
+            "topk": jnp.sort(top_i, axis=-1),                                     # [R, k] as a set
+        }
+    return shared + routed, stats
+
+
+def block(p: Params, cfg: LMConfig, li: int, x: jax.Array, attn, row_valid: jax.Array,
+          lora: Optional[Params], factors, scale: float, prefix: str = "layers"):
+    """Sandwich-norm block on ``x [..., d]``; ``attn(u) -> (out, extra)`` is
+    the MLA form the caller is in (prefill or decode). Returns (y, extra, MoE
+    stats or None)."""
+    path = f"{prefix}/{li}"
+    with jax.named_scope("lm_mla"):
+        a, extra = attn(_rms(x, p["n1"], cfg))
+        h = x + _rms(a, p["n2"], cfg)
+    u = _rms(h, p["n3"], cfg)
+    if "moe" in p:
+        with jax.named_scope("lm_moe"):
+            flat = u.reshape(-1, u.shape[-1])
+            f, stats = moe(p["moe"], cfg, flat, row_valid.reshape(-1), lora,
+                           factors, f"{path}/moe", scale)
+            f = f.reshape(u.shape)
+    else:
+        with jax.named_scope("lm_dense_ffn"):
+            f, stats = _swiglu(p["ffn"], u, lora, f"{path}/ffn", scale), None
+    return h + _rms(f, p["n4"], cfg), extra, stats
+
+
+def _embed(params: Params, cfg: LMConfig, ids: jax.Array) -> jax.Array:
+    return params["embed"][ids].astype(cfg.compute_dtype)
+
+
+def _head(params: Params, cfg: LMConfig, h: jax.Array) -> jax.Array:
+    return nn.dense(params["head"], _rms(h, params["final_norm"], cfg)).astype(jnp.float32)
+
+
+def prefill(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
+            lora: Optional[Params] = None, lora_scale: float = 1.0, factors=None, cache_only: bool = False):
+    """``ids [S, T]`` (right-padded, ``lens [S]`` real) through every block.
+    Returns (hidden ``[S, T, d]`` before the final norm, per-layer cache
+    entries ``[S, T, c + dr]``, per-MoE-layer stats with rows ``[S, T]``).
+    ``cache_only`` (generation: the begin-of-image position, not the prompt's
+    last, yields the first logits): the last layer stops at its cache entry —
+    nothing reads what its attention and FFN would add, so they are neither
+    run nor counted, and the hidden state returned is None."""
+    S, T = ids.shape
+    pos = jnp.broadcast_to(jnp.arange(T), (S, T))
+    valid = pos < lens[:, None]
+    factors = factors if factors is not None else expert_factors(lora, cfg, cfg.compute_dtype)
+    x = _embed(params, cfg, ids)
+    entries, stats = [], []
+    for li, p in enumerate(params["layers"]):
+        if cache_only and li == len(params["layers"]) - 1:
+            with jax.named_scope("lm_mla"):
+                entries.append(_mla_project(p["mla"], cfg, _rms(x, p["n1"], cfg), pos, lora,
+                                            f"layers/{li}/mla", lora_scale, entry_only=True))
+            return None, entries, stats
+        attn = lambda u, p=p, li=li: mla_prefill(p["mla"], cfg, u, pos, valid, lora, f"layers/{li}/mla", lora_scale)
+        x, entry, st = block(p, cfg, li, x, attn, valid, lora, factors[li] if factors else None, lora_scale)
+        entries.append(entry)
+        if st is not None:
+            stats.append({"assign": st["assign"].reshape(S, T), "load": st["load"],
+                          "topk": st["topk"].reshape(S, T, -1)})
+    return x, entries, stats
+
+
+def forward_logits(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
+                   lora: Optional[Params] = None, lora_scale: float = 1.0) -> jax.Array:
+    """Teacher-forced logits ``[S, T, vocab_rows_held]`` (tests)."""
+    return _head(params, cfg, prefill(params, cfg, ids, lens, lora, lora_scale)[0])
+
+
+def mtp_logits(params: Params, cfg: LMConfig, hidden: jax.Array, next_ids: jax.Array, lens: jax.Array,
+               module: int = 0) -> jax.Array:
+    """The MTP module over a whole sequence: ``hidden [S, T, d]`` (the main
+    model's, before its final norm) and ``next_ids [S, T]`` (``t_{i+1}``) →
+    logits for ``t_{i+2}``. Embedding, final norm and head are the main
+    model's; the block is the module's own MoE block. Not adapted by LoRA."""
+    p = params["mtp"][module]
+    S, T = next_ids.shape
+    pos = jnp.broadcast_to(jnp.arange(T), (S, T))
+    valid = pos < lens[:, None]
+    x = jnp.concatenate([_rms(hidden, p["nh"], cfg), _rms(_embed(params, cfg, next_ids), p["ne"], cfg)], axis=-1)
+    x = nn.dense(p["proj"], x)
+    attn = lambda u: mla_prefill(p["block"]["mla"], cfg, u, pos, valid, None, "mtp", 1.0)
+    y, _, _ = block(p["block"], cfg, 0, x, attn, valid, None, None, 1.0, prefix="mtp")
+    return _head(params, cfg, y)
+
+
+PROBE_EVERY = 16  # logits are kept at every 16th sampled position
+
+
+def generate(
+    params: Params,
+    cfg: LMConfig,
+    prompt_ids: jax.Array,   # [B, max_prompt_len] right-padded
+    prompt_len: jax.Array,   # [B]
+    key: jax.Array,
+    lora: Optional[Params] = None,
+    lora_scale: float = 1.0,
+    decode: bool = True,
+    item_index: Optional[jax.Array] = None,
+):
+    """Prefill + ``image_tokens`` sampled steps + VQ decode. Returns (images
+    ``[B, H, W, 3]`` in [0, 1] — or the sampled ids when ``decode=False`` — and
+    per-image rows: ``ids`` ``[B, n]``, ``topk`` ``[B, Tmax, moe layers, k]``
+    (the router's choice at every cache slot, -1 where none), ``assign``
+    ``[B]`` (token–expert pairs computed here), ``load`` ``[B]`` (largest
+    expert load ratio of any call, the same for every image of a call) and
+    ``logits`` ``[B, n / PROBE_EVERY, image_vocab]``).
+
+    Sampling keys fold in the step and each image's *global* batch position
+    (``item_index``), so outputs do not depend on how the batch is chunked."""
+    B, P = prompt_ids.shape
+    n, Tmax, dt = cfg.image_tokens, cfg.cache_len, cfg.compute_dtype
+    item_idx = jnp.arange(B) if item_index is None else item_index
+    lo, hi = cfg.image_id_offset, cfg.image_id_offset + cfg.image_vocab
+    slots = jnp.arange(Tmax)
+
+    with jax.named_scope("generate"):
+        factors = expert_factors(lora, cfg, dt)
+        with jax.named_scope("lm_prefill"):
+            _, entries, stats = prefill(params, cfg, prompt_ids, prompt_len, lora, lora_scale, factors,
+                                        cache_only=True)
+            caches = tuple(jnp.zeros((B, Tmax, cfg.cache_width), dt).at[:, :P].set(e.astype(dt)) for e in entries)
+            assign = sum(st["assign"].sum(-1) for st in stats) if stats else jnp.zeros((B,), jnp.int32)
+            load = jnp.max(jnp.stack([st["load"] for st in stats])) if stats else jnp.float32(0.0)
+            # the router's choice at each prompt slot, -1 at padding and at the
+            # last layer (cache_only: it routes no prompt row)
+            in_prompt = (jnp.arange(P) < prompt_len[:, None])[..., None]           # [B, P, 1]
+            n_moe = cfg.num_hidden_layers - cfg.first_k_dense_replace
+            topk_p = jnp.full((B, P, n_moe, cfg.num_experts_per_tok), -1, jnp.int32)
+            for j, st in enumerate(stats):
+                topk_p = topk_p.at[:, :, j].set(jnp.where(in_prompt, st["topk"], -1))
+
+        def step(carry, i):
+            last, caches, assign, load, probe = carry
+            with jax.named_scope("lm_decode_step"):
+                slot = P + i
+                pos = prompt_len + i
+                valid = (slots[None, :] < prompt_len[:, None]) | ((slots[None, :] >= P) & (slots[None, :] <= slot))
+                x = _embed(params, cfg, last)
+                new_caches, tk = [], []
+                for li, p in enumerate(params["layers"]):
+                    attn = lambda u, p=p, li=li: mla_decode(
+                        p["mla"], cfg, u, pos, caches[li], slot, valid, lora, f"layers/{li}/mla", lora_scale)
+                    x, cache, st = block(p, cfg, li, x, attn, jnp.ones((B,), bool), lora,
+                                         factors[li] if factors else None, lora_scale)
+                    new_caches.append(cache)
+                    if st is not None:
+                        assign = assign + st["assign"]
+                        load = jnp.maximum(load, st["load"])
+                        tk.append(st["topk"])
+                with jax.named_scope("lm_head"):
+                    logits = _head(params, cfg, x)[:, lo:hi]  # sampling sees the image-id range only
+                with jax.named_scope("sample"):
+                    k_i = jax.random.fold_in(key, i)
+                    keys = jax.vmap(lambda j: jax.random.fold_in(k_i, j))(item_idx)
+                    ids = jax.vmap(lambda kk, row: sample_top_k_top_p(kk, row, top_k=cfg.top_k, top_p=cfg.top_p))(
+                        keys, logits)
+                    j = i // PROBE_EVERY
+                    old = jax.lax.dynamic_slice_in_dim(probe, j, 1, axis=1)
+                    probe = jax.lax.dynamic_update_slice_in_dim(
+                        probe, jnp.where(i % PROBE_EVERY == 0, logits[:, None, :], old), j, axis=1)
+            tk = jnp.stack(tk, axis=1) if tk else jnp.zeros((B, 0, cfg.num_experts_per_tok), jnp.int32)
+            return (ids + lo, tuple(new_caches), assign, load, probe), (ids, tk)
+
+        probe0 = jnp.zeros((B, n // PROBE_EVERY, cfg.image_vocab), jnp.float32)
+        boi = jnp.full((B,), cfg.boi_id, jnp.int32)
+        (_, _, assign, load, probe), (ids, topk_d) = jax.lax.scan(
+            step, (boi, caches, assign, load, probe0), jnp.arange(n))
+        ids = ids.T                                                           # [B, n]
+        topk = jnp.concatenate([topk_p, jnp.moveaxis(topk_d, 0, 1)], axis=1)  # [B, Tmax, layers, k]
+
+    rows = {"ids": ids, "topk": topk, "assign": assign,
+            "load": jnp.broadcast_to(load, (B,)), "logits": probe}
+    if not decode:
+        return ids, rows
+    with jax.named_scope("decode"):
+        f_hat = msvq.embed_ids(params["vq"], ids).reshape(B, cfg.grid, cfg.grid, cfg.vq.c_vae).astype(jnp.float32)
+        # the decoder's activations (256 px x 160 channels an image) are the
+        # step's largest: a member chunk decodes ``decode_batch`` images a
+        # member at a time, not its whole batch
+        images = jax.lax.map(lambda f: msvq.decode_img(params["vq"], cfg.vq, f[None])[0], f_hat,
+                             batch_size=cfg.decode_batch or B)
+        return images, rows

@@ -322,6 +322,8 @@ TOP_SCOPES = ("es_noise", "generate", "decode", "reward", "es_update")
 INNER_SCOPES = (
     "dit_embed_out", "dit_self_attn", "dit_cross_attn", "dit_ffn",     # Sana DiT
     "blocks", "head", "sample", "msvq_accumulate",                     # VAR, inside scale<k>
+    "lm_prefill", "lm_decode_step",                                    # lm_ar: the two phases of generate
+    "lm_mla", "attend", "lm_dense_ffn", "lm_moe", "router", "experts", "shared", "lm_head",  # inside them
     "preprocess", "clip_b", "clip_h", "score",                         # rewards
     "perturb",                                                         # es_noise: one member's adapter
     "fitness", "update", "health",                                     # es_update

@@ -16,6 +16,9 @@ Kernel layouts (the repo's conventions — models/nn.py initializers):
 
 - 2D ``[din, dout]`` dense                      → scale ``[1, dout]``
 - 3D ``[L, din, dout]`` scan-stacked dense      → scale ``[L, 1, dout]``
+- 3D ``[E, din, dout]`` routed experts          → scale ``[E, 1, dout]`` (the
+  same rule: each expert is an independent matrix, one scale per expert and
+  output channel — models/lm.py, tests/test_lm.py)
 - 4D ``[kh, kw, cin, cout]`` conv HWIO          → scale ``[1, 1, 1, cout]``
 - 5D ``[L, kh, kw, cin, cout]`` stacked conv    → scale ``[L, 1, 1, 1, cout]``
 

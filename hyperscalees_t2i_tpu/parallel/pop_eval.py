@@ -60,6 +60,22 @@ def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+GEN_ROWS = "gen/"  # prefix of a generator's own rows in the evaluator's result
+
+
+def _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index):
+    """One member's image batch: generate, reward. A generator may return
+    ``(images, rows)`` — per-image rows of its own (a sampler's ids, a router's
+    counters; each ``[B, ...]``): they ride beside the reward rows under
+    ``gen/<name>``, through the same member loop and gathers, and the step
+    hands them to the backend's ``step_metrics``."""
+    out = generate_p(frozen["gen"], theta_k, flat_ids, gen_key, item_index)
+    images, rows = out if isinstance(out, tuple) else (out, {})
+    rewards = dict(reward_apply(frozen["reward"], images, flat_ids))
+    rewards.update({GEN_ROWS + k: v for k, v in rows.items()})
+    return rewards
+
+
 def _fused_qlora_routing() -> bool:
     """Trace-time resolution of the unified int8+LoRA routing knob
     (ops/fused_qlora.py), stamped into every program's ledger geometry so a
@@ -210,8 +226,7 @@ def make_fleet_evaluator(
     n_lanes = W * pop_size
 
     def run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key):
-        images = generate_p(frozen["gen"], theta_k, flat_ids, gen_key, item_index)
-        return reward_apply(frozen["reward"], images, flat_ids)
+        return _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index)
 
     def eval_theta(frozen, theta_k, flat_ids, item_index, gen_key):
         B = flat_ids.shape[0]
@@ -324,8 +339,7 @@ def make_population_evaluator(
     """
 
     def run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key):
-        images = generate_p(frozen["gen"], theta_k, flat_ids, gen_key, item_index)
-        return reward_apply(frozen["reward"], images, flat_ids)
+        return _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index)
 
     def eval_theta(frozen, theta_k, flat_ids, item_index, gen_key):
         B = flat_ids.shape[0]

@@ -52,9 +52,17 @@ def add_backend_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
     """Flags consumed by ``build_backend`` — shared with the eval harness so
     every CLI that constructs a backend accepts the same surface."""
     p.add_argument("--backend", required=True,
-                   choices=["sana_one_step", "sana_pipeline", "var", "zimage", "infinity"])
+                   choices=["sana_one_step", "sana_pipeline", "var", "zimage", "infinity", "lm_ar"])
     p.add_argument("--model_scale", default="full", choices=["tiny", "small", "full"],
-                   help="architecture size (tiny/small for smoke runs)")
+                   help="architecture size (tiny/small for smoke runs); lm_ar takes its "
+                        "sizes from --lm_config and this sizes its reward towers only")
+    p.add_argument("--lm_config", default=None,
+                   help="lm_ar: a config.json-shaped file — the model's published keys "
+                        "plus this chip's share (experts_held, expert_offset, "
+                        "vocab_rows_held) and image_tokens (models/lm.LMConfig.from_json)")
+    p.add_argument("--prompt_token_ids", default=None,
+                   help='lm_ar: {"prompts": [text], "ids": [[int]]} from the model\'s own '
+                        "tokenizer; without it ids are synthesized from --prompts_txt")
     # data
     p.add_argument("--prompts_txt", default=None)
     p.add_argument("--encoded_prompts", default=None,
@@ -391,6 +399,20 @@ def build_backend(args):
             lora_r=args.lora_r, lora_alpha=args.lora_alpha,
         )
         return SanaBackend(cfg, params=params)
+
+    if args.backend == "lm_ar":
+        from ..backends.lm_backend import LMArBackend, LMBackendConfig
+        from ..models import lm
+
+        if not args.lm_config:
+            sys.exit("ERROR: --backend lm_ar needs --lm_config <config.json-shaped file> "
+                     "(the model's published keys plus experts_held / vocab_rows_held)")
+        return LMArBackend(LMBackendConfig(
+            model=lm.LMConfig.from_json(args.lm_config),
+            prompts_txt_path=args.prompts_txt, prompt_token_ids_path=args.prompt_token_ids,
+            base_quant=getattr(args, "base_quant", "off"),
+            lora_r=args.lora_r, lora_alpha=args.lora_alpha,
+        ))
 
     if args.backend == "var":
         vq_kw = _scaled(args, {}, dict(ch=80, ch_mult=(1, 2, 2, 4), num_res_blocks=1),

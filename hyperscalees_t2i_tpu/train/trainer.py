@@ -76,6 +76,7 @@ def _combine_and_update(
     repeats: int,
     update_fn: Optional[Callable] = None,
     lr: Optional[jax.Array] = None,
+    gen_metrics_fn: Optional[Callable] = None,
 ):
     """Rewards → scores → fitness → EGGROLL update → metrics: the back half
     of the epoch step, shared verbatim between the fused single-program step
@@ -93,8 +94,16 @@ def _combine_and_update(
     host-precomputed ``f32(lr_scale_j·σ_j)`` so one compiled program serves
     any per-job hyperparameter mix. ``None`` (every solo caller) resolves to
     ``es_cfg.lr`` inside ``es_update`` exactly as before — byte-identical
-    trace, golden program untouched."""
+    trace, golden program untouched.
+
+    ``gen_metrics_fn`` (a backend's ``step_metrics``) reduces the generator's
+    own rows (``gen/<name>``, ``[pop, B, ...]``, parallel/pop_eval.py) to
+    metrics of the step; without it such rows are dropped."""
     from ..obs.es_health import es_health_metrics
+    from ..parallel.pop_eval import GEN_ROWS
+
+    gen_rows = {k[len(GEN_ROWS):]: v for k, v in rewards.items() if k.startswith(GEN_ROWS)}
+    rewards = {k: v for k, v in rewards.items() if not k.startswith(GEN_ROWS)}
 
     # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only,
     # the traced program is the same
@@ -164,6 +173,8 @@ def _combine_and_update(
                         reward_keys=REWARD_KEYS,
                     )
                 )
+            if gen_rows and gen_metrics_fn is not None:
+                metrics.update(gen_metrics_fn(gen_rows, pop=pop, antithetic=es_cfg.antithetic))
     return theta_new, delta, metrics, opt_scores
 
 
@@ -189,6 +200,23 @@ def _resolve_update_fn(tc: TrainConfig, es_cfg, mesh):
         True,
         int(mesh.shape[POP_AXIS]),
     )
+
+
+PROBE = "probe/"  # prefix of a step's probe arrays (a backend's step_metrics)
+
+
+def _write_probe_once(metrics: Dict[str, Any], run_dir: Optional[Path], epoch: int) -> Dict[str, Any]:
+    """``probe/*`` arrays are what one member produced, kept so that it can be
+    checked against a reference (sampled ids, routing, logits): megabytes, not
+    a row of ``metrics.jsonl``. The first epoch a run executes writes them to
+    ``probe_epoch<k>.npz`` in its run directory; they are dropped from every
+    epoch's metrics."""
+    probe = {k[len(PROBE):]: v for k, v in metrics.items() if k.startswith(PROBE)}
+    if not probe:
+        return metrics
+    if run_dir is not None and not list(run_dir.glob("probe_epoch*.npz")):
+        np.savez(run_dir / f"probe_epoch{epoch}.npz", **probe)
+    return {k: v for k, v in metrics.items() if not k.startswith(PROBE)}
 
 
 def host_reduce_keys(scalars: Dict[str, Any]) -> List[str]:
@@ -245,7 +273,7 @@ def make_host_sharded_programs(
     everywhere; the drift is purely reward-side rounding.
     """
     from ..backends.base import generate_parts, reward_parts
-    from ..parallel.pop_eval import make_population_evaluator
+    from ..parallel.pop_eval import GEN_ROWS, make_population_evaluator
 
     es_cfg = tc.es_config()
     pop = tc.pop_size
@@ -261,7 +289,9 @@ def make_host_sharded_programs(
         k_noise, k_gen = jax.random.split(key)
         with jax.named_scope("es_noise"):
             noise = sample_noise(k_noise, theta, pop, es_cfg)
-        return eval_slice_pop(frozen, theta, noise, flat_ids, k_gen)
+        rewards = eval_slice_pop(frozen, theta, noise, flat_ids, k_gen)
+        # a generator's own rows stay on their host: only reward rows cross
+        return {k: v for k, v in rewards.items() if not k.startswith(GEN_ROWS)}
 
     # The pod's replicated update composes with the pop-sharded contraction:
     # the LOCAL mesh's pop axis splits the fitness-weighted noise sum, one
@@ -351,6 +381,7 @@ def make_es_step(
             theta, prev_delta, noise, rewards, tc=tc, es_cfg=es_cfg,
             pop=pop, num_unique=num_unique, repeats=repeats,
             update_fn=update_fn,
+            gen_metrics_fn=getattr(backend, "step_metrics", None),
         )
 
     # ``donate=False`` (bench.py --fleet): repeated in-process executions of
@@ -1652,6 +1683,7 @@ def run_training(
                     registry.observe("train_step_time_seconds", dt / K)
                     record_device_memory(registry)
                     n_images = tc.pop_size * m * r * K
+                    metrics = _write_probe_once(metrics, run_dir if master else None, epoch_last)
                     scalars = {
                         k: (v.tolist() if getattr(v, "ndim", 0) else float(v)) for k, v in metrics.items()
                     }

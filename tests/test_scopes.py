@@ -220,7 +220,18 @@ VAR_SCOPES = (
 )
 
 
-@pytest.mark.parametrize("family, want", [("sana_one_step", SANA_SCOPES), ("var", VAR_SCOPES)])
+LM_SCOPES = (
+    {"es_noise", "es_noise/perturb", "decode",
+     "reward/preprocess", "reward/clip_b", "reward/clip_h", "reward/score",
+     "es_update/fitness", "es_update/update", "es_update/health"}
+    | {f"generate/{phase}/{inner}" for phase in ("lm_prefill", "lm_decode_step")
+       for inner in ("lm_mla", "lm_mla/attend", "lm_dense_ffn", "lm_moe/router", "lm_moe/experts", "lm_moe/shared")}
+    | {"generate/lm_decode_step/lm_head", "generate/lm_decode_step/sample"}
+)
+
+
+@pytest.mark.parametrize("family, want", [("sana_one_step", SANA_SCOPES), ("var", VAR_SCOPES),
+                                          ("lm_ar", LM_SCOPES)])
 def test_compiled_step_carries_every_scope(family, want, tmp_path):
     """The guard against a refactor of the member loop silently dropping a
     scope: the tiny step of each family, compiled, names every top-level
@@ -232,9 +243,14 @@ def test_compiled_step_carries_every_scope(family, want, tmp_path):
 
     prompts = tmp_path / "p.txt"
     prompts.write_text("a red square\na blue circle\n")
-    args = build_parser().parse_args(
-        ["--backend", family, "--model_scale", "tiny", "--prompts_txt", str(prompts),
-         "--lora_r", "2", "--lora_alpha", "4"])
+    argv = ["--backend", family, "--model_scale", "tiny", "--prompts_txt", str(prompts),
+            "--lora_r", "2", "--lora_alpha", "4"]
+    if family == "lm_ar":
+        from tests.test_lm import TOY
+
+        (tmp_path / "config.json").write_text(json.dumps(TOY))
+        argv += ["--lm_config", str(tmp_path / "config.json")]
+    args = build_parser().parse_args(argv)
     backend = build_backend(args)
     backend.setup()
     reward_fn = _tiny_clip_reward(backend)
@@ -524,6 +540,16 @@ def test_member_reward_row_stays_out_of_the_pod_scalar_gather(tmp_path):
     assert "es/member_reward" not in keys and "per_prompt_mean" not in keys
     assert {"step_time_s", "images_per_sec", "es/reward_std", "es/fitness_zero"} <= set(keys)
     assert not any(k.startswith("es/leaf_") for k in keys)
+    # the lm_ar generator's rows: its probe arrays never reach a row (the
+    # trainer writes them to a file), and no vector of its could enter the
+    # gather, which carries one float a key
+    from hyperscalees_t2i_tpu.train.trainer import _write_probe_once
+
+    lm_row = _write_probe_once({**scalars, "moe/local_assignments": 12.0, "moe/max_expert_load": 2.0,
+                                "moe/pair_route_flip": 0.1, "probe/ids": np.zeros((4, 16), np.int32),
+                                "probe/logits": np.zeros((4, 1, 16), np.float32)}, None, 0)
+    assert not any(k.startswith("probe/") for k in lm_row)
+    assert set(host_reduce_keys(lm_row)) == set(keys)
     # the pc > 1 payload path of run_training, as it builds and reads it
     payload = {k: scalars[k] for k in keys}
     payload["_preempt_req"] = 0.0
