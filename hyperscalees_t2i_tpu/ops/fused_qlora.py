@@ -7,15 +7,48 @@ leaf over an int8 base node::
         a_k = a + c_a·U_a V_aᵀ,   b_k = b + c_b·U_b V_bᵀ
 
 The Pallas kernel tiles tokens (outer) and output channels (inner, in
-order). A grid step dequantizes one ``[din, bn]`` s8 base tile in VMEM
-(convert + per-output-channel scale, rounded to the activations' dtype — the
-s8 bytes are the only base bytes that cross HBM) and gives it to the MXU
-with the token block as it arrives: bf16 operands, f32 accumulation, the
-same values an f32 dot at default precision produced from the same tile
-without the f32 copy of ``x``. The perturbed-LoRA chain is
-f32 at ``Precision.HIGHEST``; its din side (``x @ a_k``, and ``·U_b``) does
-not depend on the output tile and is computed once a token block into a VMEM
-scratch, its dout side is one thin dot a tile (:func:`_qlora_kernel`).
+order): grid (token blocks, dout tiles). A grid step dequantizes one
+``[din, bn]`` s8 base tile in VMEM (convert + per-output-channel scale,
+rounded to the activations' dtype — the s8 bytes are the only base bytes that
+cross HBM) and gives it to the MXU with the token block as it arrives: bf16
+operands, f32 accumulation, the same values an f32 dot at default precision
+produced from the same tile without the f32 copy of ``x``. The
+perturbed-LoRA chain is f32 at ``Precision.HIGHEST``; its din side
+(``x @ a_k``, and ``·U_b``) does not depend on the output tile and is
+computed once a token block into a VMEM scratch, its dout side is one thin
+dot a tile (:func:`_qlora_kernel`).
+
+What crosses HBM how often: ``x`` and the din-side factors once a token
+block, the s8 base once a token block too — every dout tile of it, in order.
+So the base's bytes are paid per token block, and a call is bound by them
+when its blocks hold few rows.
+
+The member axis (PR 28). ``pop_eval`` reaches the kernel through ``vmap``
+inside ``lax.map(batch_size=member_batch)``. Pallas's default rule puts a
+batch axis in front of the grid: each of ``M`` members sweeps the dout tiles
+over its own rows, and the base crosses HBM ``M`` times a call — at a decode
+step of the ``lm_ar`` cell (8 members x 8 rows against a 141.6 MB base) nine
+tenths of the call. The kernel call therefore has its own rule
+(:func:`_members_into_rows`, a ``custom_vmap`` like
+``ops/grouped.py::_flatten_members``): with the base and the unperturbed
+``w`` shared and ``x``, ``u``, ``v``, ``c`` per member, ``x`` is viewed
+``[M·T, din]`` and a token block is ``g`` whole members
+(:func:`_members_per_block`: where a member has fewer rows than the MXU's
+128, the largest divisor of ``M`` whose ``g·T`` rows fit the fitted block),
+grid (``M/g`` member groups, dout tiles). The base tile follows only the
+dout index, so it is fetched, dequantized and multiplied once a group — with
+``g = M`` once a call. The base dot is the one-member kernel's, row for row;
+in the chain the ``g`` members' thin operands lie side by side (``[din, r_l +
+g·r_e]``, one 128-lane operand like one member's) and a row keeps its own
+member's columns by a mask (:func:`_qlora_group_kernel`). Around the call XLA
+still meets ``[M, T, ·]`` arrays (two optimization barriers): left to carry
+the reshapes into its own fusions it sums an RMS norm between two sites in
+another order and the sampled tokens of the ``lm_ar`` cell change. ``g = 1``
+— 128 rows a member or more, as at Sana's 1024 and every prefill — or a base
+per member is the default batching of the one-member call, the program it
+always was. Nothing selects this: ``g`` follows from ``M``, ``T`` and the
+fitted block, and the call says it in its metadata (``members_per_block``,
+which ``obs/xla_cost`` writes into ``programs.jsonl``).
 
 What the chip says about it (PERF.md §5–§6; TPU v5e). The kernel is bound by
 MXU *passes*, not by HBM and not by the FLOPs it needs: a thin dot of 8 or
@@ -110,19 +143,25 @@ def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
     return -(-rows // sub) * sub * -(-cols // _LANES) * _LANES * itemsize
 
 
-def _declared_blocks(din, a, b, block_t, block_n, x_dtype):
+def _declared_blocks(din, a, b, block_t, block_n, x_dtype, g: int = 1):
     """What the kernel declares to Pallas, as ``(shape, dtype)``: the block
     of every operand in call order with the output's last — each one
     double-buffered by the pipeline — and the one VMEM scratch. Both
-    :func:`_pallas_fused_qlora` and :func:`_kernel_vmem_bytes` read this."""
-    f32, r = jnp.float32, a.w.shape[-1] + a.u.shape[-1]
+    :func:`_pallas_fused_qlora` and :func:`_kernel_vmem_bytes` read this.
+    ``a``, ``b`` are one member's factors; with ``g`` > 1 members a token
+    block (``block_t`` = their rows together) the noise factors of the ``g``
+    lie side by side: ``r`` = r_l + g·r_e columns, inside one 128-lane
+    operand like one member's r_l + r_e."""
+    f32, r_l, r_e = jnp.float32, a.w.shape[-1], a.u.shape[-1]
+    r = r_l + g * r_e
     blocks = [
         ((block_t, din), x_dtype),       # x: one token block, din whole
         ((din, block_n), jnp.int8),      # s8 base tile
         ((1, block_n), f32),             # per-channel scale
         ((din, r), f32),                 # [a.w | a.u]
-        (a.v.shape, a.v.dtype),          # a.v  [r_l, r_e]
-        (b.u.shape, b.u.dtype),          # b.u  [r_l, r_e]
+        # a.v [r_l, r_e]; the g members' a.vᵀ stacked [g·r_e, r_l]
+        (a.v.shape if g == 1 else (g * r_e, r_l), a.v.dtype),
+        ((r_l, g * r_e), b.u.dtype),     # b.u, the g members' side by side
         ((r, block_n), f32),             # [b.w ; b.vᵀ], dout-tiled
         ((block_t, block_n), x_dtype),   # out
     ]
@@ -214,11 +253,41 @@ def xla_fused_qlora(
     return dequant_matmul(x, qk) + fused_lora_delta(x, leaf, lora_scale)
 
 
+def _dot(p, q, precision=jax.lax.Precision.HIGHEST):
+    return jax.lax.dot_general(
+        p, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=precision,
+    )
+
+
+def _base_plus_thin(x_ref, q_ref, s_ref, b_ref, z_ref, o_ref, lora_scale: float):
+    """What every (token block, dout tile) step does: the base dot plus
+    ``z @ [b.w ; b.vᵀ]`` (= xa@b_k), the one thin dot a tile.
+
+    The base term is the ops/quant_mm contract: the tile is dequantized in
+    VMEM (convert + per-channel scale in f32) and handed to the MXU in x's
+    dtype. For bf16 activations that is bit for bit what an f32 dot at
+    default precision did with the same tile — the MXU rounds f32 operands
+    to bf16 (measured on the v5e, PR 26: 0 of 2-8 M outputs differ) —
+    without the f32 copy of x. 16-bit operands take one MXU pass whatever
+    the process's default precision asks of f32 dots; an f32 caller's dot
+    follows that default, as it always did. A row's result does not depend on
+    which other rows share its block, so a block of several members' rows
+    (:func:`_qlora_group_kernel`) leaves it as it was."""
+    f32 = jnp.float32
+    x = x_ref[...]
+    w = (q_ref[...].astype(f32) * s_ref[...].astype(f32)).astype(x.dtype)
+    y = _dot(x, w, None if x.dtype == f32 else jax.lax.Precision.DEFAULT)
+    d = _dot(z_ref[...], b_ref[...])
+    o_ref[...] = (y + d * lora_scale).astype(o_ref.dtype)
+
+
 def _qlora_kernel(
     x_ref, q_ref, s_ref, a_ref, av_ref, bu_ref, b_ref, ca_ref, cb_ref,
     o_ref, z_ref, *, lora_scale: float, r_l: int,
 ):
-    """One (token block, dout tile) step: the base dot plus one thin dot.
+    """One (token block, dout tile) step of one member's rows: the base dot
+    plus one thin dot.
 
     The dout axis is the inner, sequential grid axis. Everything that does
     not depend on the dout tile is done once a token block, at its first
@@ -229,40 +298,67 @@ def _qlora_kernel(
         z               = [xa | cb·(xa@b.u)]
 
     Every dout step then adds ``z @ [b.w ; b.vᵀ]`` (= xa@b_k, K = r_l + r_e)
-    to the base dot. The chain is f32 at ``Precision.HIGHEST`` throughout
-    (the parity pin is against the materialized path's full-precision ε).
-    The base dot takes ``x`` as it arrives and the s8 tile dequantized to
-    ``x``'s dtype; f32 accumulation."""
+    to the base dot (:func:`_base_plus_thin`). The chain is f32 at
+    ``Precision.HIGHEST`` throughout (the parity pin is against the
+    materialized path's full-precision ε). The base dot takes ``x`` as it
+    arrives and the s8 tile dequantized to ``x``'s dtype; f32 accumulation."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
 
-    def dot(p, q, precision=jax.lax.Precision.HIGHEST):
-        return jax.lax.dot_general(
-            p, q, (((1,), (0,)), ((), ())), preferred_element_type=f32,
-            precision=precision,
-        )
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        xc = _dot(x_ref[...].astype(f32), a_ref[...])  # [bt, r_l + r_e]
+        xa = xc[:, :r_l] + ca_ref[0, 0] * _dot(xc[:, r_l:], av_ref[...].astype(f32).T)
+        xb = cb_ref[0, 0] * _dot(xa, bu_ref[...].astype(f32))
+        z_ref[...] = jnp.concatenate([xa, xb], axis=1)
+
+    _base_plus_thin(x_ref, q_ref, s_ref, b_ref, z_ref, o_ref, lora_scale)
+
+
+def _qlora_group_kernel(
+    x_ref, q_ref, s_ref, a_ref, av_ref, bu_ref, b_ref, ca_ref, cb_ref,
+    o_ref, z_ref, *, lora_scale: float, r_l: int, g: int, rows: int,
+):
+    """One (member group, dout tile) step: :func:`_qlora_kernel` for a token
+    block that holds ``g`` whole members of ``rows`` rows each, so the base
+    tile is fetched, dequantized and multiplied once for all of them.
+
+    The members' noise factors lie side by side (``a_ref`` ``[din, r_l +
+    g·r_e]``, ``av_ref`` the ``g`` ``a.vᵀ`` stacked, ``bu_ref`` ``[r_l,
+    g·r_e]``, ``b_ref`` ``[r_l + g·r_e, bn]``; ``a.w``, ``b.w`` are every
+    member's), one MXU-wide thin operand where a member alone had one, and a
+    row keeps the ``r_e`` columns of its own member: the others are set to
+    exact zeros before they meet a ``v``, so each row's chain is its member's
+    ``x @ a_k`` and ``xa @ b_k`` term for term. ``ca_ref``/``cb_ref`` hold
+    every group's ``g`` coefficients in SMEM; a row takes its member's."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    group, bt, wide = pl.program_id(0), x_ref.shape[0], bu_ref.shape[1]
+    r_e = wide // g
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        xc = dot(x_ref[...].astype(f32), a_ref[...])  # [bt, r_l + r_e]
-        xa = xc[:, :r_l] + ca_ref[0, 0] * dot(xc[:, r_l:], av_ref[...].astype(f32).T)
-        xb = cb_ref[0, 0] * dot(xa, bu_ref[...].astype(f32))
+
+        def of_member(k, shape, axis, step):  # index // step == k, without a division
+            at = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            return (at >= k * step) & (at < (k + 1) * step)
+
+        own = functools.reduce(jnp.logical_or, (
+            of_member(k, (bt, wide), 0, rows) & of_member(k, (bt, wide), 1, r_e)
+            for k in range(g)
+        ))
+        per_row = lambda c_ref: sum(
+            jnp.where(of_member(k, (bt, 1), 0, rows), c_ref[group, k], 0.0) for k in range(g)
+        )
+        xc = _dot(x_ref[...].astype(f32), a_ref[...])  # [bt, r_l + g·r_e]
+        xu = jnp.where(own, xc[:, r_l:], 0.0)
+        xa = xc[:, :r_l] + per_row(ca_ref) * _dot(xu, av_ref[...].astype(f32))
+        xb = per_row(cb_ref) * jnp.where(own, _dot(xa, bu_ref[...].astype(f32)), 0.0)
         z_ref[...] = jnp.concatenate([xa, xb], axis=1)
 
-    # base term, the ops/quant_mm contract: the tile is dequantized in VMEM
-    # (convert + per-channel scale in f32) and handed to the MXU in x's dtype.
-    # For bf16 activations that is bit for bit what an f32 dot at default
-    # precision did with the same tile — the MXU rounds f32 operands to bf16
-    # (measured on the v5e, PR 26: 0 of 2-8 M outputs differ) — without the
-    # f32 copy of x. 16-bit operands take one MXU pass whatever the process's
-    # default precision asks of f32 dots; an f32 caller's dot follows that
-    # default, as it always did.
-    x = x_ref[...]
-    w = (q_ref[...].astype(f32) * s_ref[...].astype(f32)).astype(x.dtype)
-    y = dot(x, w, None if x.dtype == f32 else jax.lax.Precision.DEFAULT)
-    d = dot(z_ref[...], b_ref[...])
-    o_ref[...] = (y + d * lora_scale).astype(o_ref.dtype)
+    _base_plus_thin(x_ref, q_ref, s_ref, b_ref, z_ref, o_ref, lora_scale)
 
 
 def _token_blocks(T: int, block_t: int) -> tuple:
@@ -275,22 +371,89 @@ def _token_blocks(T: int, block_t: int) -> tuple:
     return (T if n == 1 else -(-T // (n * 16)) * 16), n
 
 
+def _members_per_block(M: int, T: int, block_t: int, r_l: int, r_e: int, itemsize: int) -> int:
+    """How many of ``M`` members of ``T`` rows each share a token block.
+
+    Few rows a member — under :data:`MIN_BLOCK`, the MXU's 128: such a
+    member's dout step costs the MXU a full pass whatever its rows and lasts
+    as long as the base tile's read (``din·bn`` bytes at 819 GB/s against
+    ``2·T·din·bn`` FLOPs at 197 TFLOP/s: the read is the longer below 120
+    rows) — take the largest divisor ``g`` of ``M`` whose ``g·T`` rows fit
+    the fitted ``block_t`` and whose ``r_l + g·r_e`` thin columns fit one
+    128-lane operand (so ``g`` members cost the MXU what one did). A block
+    that is not the whole array has to be made of whole sublane tiles. From
+    128 rows a member on the step is bound by its own product and sharing
+    the tile saves grid steps only; in the VAR cell blocks of 2 x 512 rows
+    cost 20 ms a step more in the ops around the call than the kernel saved
+    (PERF.md §6, PR 28): 1 = a block is a member's own rows, the program it
+    always was."""
+    if T >= MIN_BLOCK:
+        return 1
+    sub = _SUBLANES * max(1, 4 // itemsize)
+    for g in range(M, 1, -1):
+        if (M % g == 0 and g * T <= block_t and r_l + g * r_e <= _LANES
+                and (g == M or g * T % sub == 0)):
+            return g
+    return 1
+
+
+def _side_by_side(a, b, g: int):
+    """The thin operands of :func:`_qlora_group_kernel` from factors whose
+    ``u``, ``v`` carry the member axis in front: per group of ``g``
+    consecutive members ``[a.w | a.u…]`` ``[din, r_l + g·r_e]``, the ``a.vᵀ``
+    stacked ``[g·r_e, r_l]``, the ``b.u`` ``[r_l, g·r_e]`` and ``[b.w ;
+    b.vᵀ…]`` ``[r_l + g·r_e, dout]`` — f32 where the one-member kernel has
+    f32, the store dtype where it has that."""
+    f32, n = jnp.float32, a.u.shape[0] // g
+
+    def cols(t):  # [M, m, r_e] -> [n, m, g·r_e]: a group's members side by side
+        m, r_e = t.shape[1:]
+        return t.reshape(n, g, m, r_e).transpose(0, 2, 1, 3).reshape(n, m, g * r_e)
+
+    def rows(t):  # [M, m, r_e] -> [n, g·r_e, m]: a group's members' transposes stacked
+        return t.transpose(0, 2, 1).reshape(n, g * t.shape[2], t.shape[1])
+
+    shared = lambda w: jnp.broadcast_to(w.astype(f32), (n, *w.shape))
+    a_cat = jnp.concatenate([shared(a.w), cols(a.u.astype(f32))], axis=2)
+    b_cat = jnp.concatenate([shared(b.w), rows(b.v.astype(f32))], axis=1)
+    return a_cat, rows(a.v), cols(b.u), b_cat
+
+
 def _pallas_fused_qlora(
-    x2, q8, scale, a, b, lora_scale, block_t: int, block_n: int, interpret: bool
+    x2, q8, scale, a, b, lora_scale, block_t: int, block_n: int, interpret: bool,
+    g: int = 1,
 ):
+    """The one Pallas call of a site. ``g`` = 1: ``x2`` ``[T, din]`` and the
+    factors are one member's. ``g`` > 1: ``x2`` is ``[M·T, din]``, the rows of
+    ``M`` members in order, ``a``/``b`` carry the member axis in front of
+    ``u``, ``v``, ``c`` (``w`` is shared), and a token block is ``g`` whole
+    members (:func:`_members_per_block`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     T, din = x2.shape
     dout = q8.shape[-1]
-    block_t, n_tblk = _token_blocks(T, block_t)
     block_n = min(block_n, dout)
     n_nblk = -(-dout // block_n)
-    # the thin factors, side by side: one MXU operand each side of the chain
-    # ([din, r_l + r_e] and [r_l + r_e, dout], f32 — b.vᵀ lies lane-dense)
-    a_cat = jnp.concatenate([a.w.astype(f32), a.u.astype(f32)], axis=1)
-    b_cat = jnp.concatenate([b.w.astype(f32), b.v.astype(f32).T], axis=0)
+    r_l = a.w.shape[-1]
+    if g == 1:
+        block_t, n_tblk = _token_blocks(T, block_t)
+        # the thin factors, side by side: one MXU operand each side of the chain
+        # ([din, r_l + r_e] and [r_l + r_e, dout], f32 — b.vᵀ lies lane-dense)
+        a_cat = jnp.concatenate([a.w.astype(f32), a.u.astype(f32)], axis=1)
+        b_cat = jnp.concatenate([b.w.astype(f32), b.v.astype(f32).T], axis=0)
+        thin = (a_cat, a.v, b.u, b_cat)
+        kernel = functools.partial(_qlora_kernel, lora_scale=float(lora_scale), r_l=r_l)
+    else:
+        M = a.c.shape[0]
+        rows, n_tblk = T // M, M // g
+        block_t = g * rows
+        thin = _side_by_side(a, b, g)
+        kernel = functools.partial(
+            _qlora_group_kernel, lora_scale=float(lora_scale), r_l=r_l, g=g, rows=rows
+        )
+    c_shape = (n_tblk, g) if g > 1 else (1, 1)
 
     # Nothing is padded in HBM: where the rows or the output channels do not
     # fill the last block, Pallas reads it short (the rest of the VMEM block
@@ -299,18 +462,25 @@ def _pallas_fused_qlora(
     #
     # The s8 tile, its scale and [b.w ; b.vᵀ] follow the dout step; x and the
     # din-side factors keep their index over it, so Pallas leaves them where
-    # they are, and the s8 base crosses HBM once per token block.
+    # they are, and the s8 base crosses HBM once per token block. A member
+    # group's thin operands carry the group axis in front (squeezed away in
+    # the block) and follow the group as x does.
     by_t, by_n, whole = (lambda t, n: (t, 0)), (lambda t, n: (0, n)), (lambda t, n: (0, 0))
-    index_maps = [by_t, by_n, by_n, whole, whole, whole, by_n, lambda t, n: (t, n)]
-    blocks, scratch = _declared_blocks(din, a, b, block_t, block_n, x2.dtype)
+    blocks, scratch = _declared_blocks(din, a, b, block_t, block_n, x2.dtype, g)
+    if g == 1:
+        index_maps = [by_t, by_n, by_n, whole, whole, whole, by_n, lambda t, n: (t, n)]
+    else:
+        of_group, of_group_by_n = (lambda t, n: (t, 0, 0)), (lambda t, n: (t, 0, n))
+        index_maps = [by_t, by_n, by_n, of_group, of_group, of_group, of_group_by_n,
+                      lambda t, n: (t, n)]
+        blocks = [((None, *shape) if 3 <= i <= 6 else shape, dt)
+                  for i, (shape, dt) in enumerate(blocks)]
     *in_specs, out_spec = (
         pl.BlockSpec(shape, imap) for (shape, _), imap in zip(blocks, index_maps)
     )
-    scalar = pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM)
+    scalar = pl.BlockSpec(c_shape, whole, memory_space=pltpu.SMEM)
     out = pl.pallas_call(
-        functools.partial(
-            _qlora_kernel, lora_scale=float(lora_scale), r_l=a.w.shape[-1]
-        ),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((T, dout), x2.dtype),
         grid=(n_tblk, n_nblk),
         in_specs=[*in_specs, scalar, scalar],
@@ -324,12 +494,65 @@ def _pallas_fused_qlora(
         ),
         interpret=interpret,
         name="fused_qlora",
+        # read back from the lowered step by obs/xla_cost.stablehlo_stats:
+        # programs.jsonl says for every site how many members share a block
+        metadata={"members_per_block": str(g)},
     )(
-        x2, q8, scale, a_cat, a.v, b.u, b_cat,
-        a.c.astype(f32).reshape(1, 1),
-        b.c.astype(f32).reshape(1, 1),
+        x2, q8, scale, *thin,
+        a.c.astype(f32).reshape(c_shape),
+        b.c.astype(f32).reshape(c_shape),
     )
     return out
+
+
+def _members_into_rows(lora_scale, block_t: int, block_n: int, interpret: bool):
+    """The kernel call of one site with its own rule for ``vmap`` (the
+    repo's precedent: ``ops/grouped.py::_flatten_members``). Pallas's default
+    puts a batch axis in front of the grid: every member runs the whole dout
+    sweep over its few rows and pulls the whole s8 base across HBM again. The
+    rule sees what ``pop_eval`` hands it — ``x`` and the noise slices (``u``,
+    ``v``, ``c`` of both factors) per member, ``q8``, ``scale`` and the
+    unperturbed ``w`` shared — and makes the member axis rows of ONE call
+    whose token blocks hold :func:`_members_per_block` whole members. Where
+    that is 1, or the base or a ``w`` is per member, it is the default
+    batching of the one-member call: the parent's program."""
+    from ..lora import FactoredDelta
+
+    def one_member(x2, q8, scale, a, b):
+        return _pallas_fused_qlora(x2, q8, scale, a, b, lora_scale, block_t, block_n, interpret)
+
+    call = jax.custom_batching.custom_vmap(one_member)
+
+    @call.def_vmap
+    def rule(M, in_batched, x2, q8, scale, a, b):
+        x_b, q_b, s_b, a_b, b_b = in_batched
+        T, g = x2.shape[-2], 1
+        if not (q_b or s_b or a_b.w or b_b.w):
+            g = _members_per_block(
+                M, T, block_t, a.w.shape[-1], a.u.shape[-1], x2.dtype.itemsize
+            )
+        if g == 1:
+            in_axes = jax.tree_util.tree_map(lambda batched: 0 if batched else None, in_batched)
+            return jax.vmap(one_member, in_axes=in_axes)(x2, q8, scale, a, b), True
+        # what the members share of their own (activations every member
+        # reads, an antithetic pair's one (u, v)) gets the member axis too
+        per_member = lambda t, batched: t if batched else jnp.broadcast_to(t, (M, *t.shape))
+        x2 = per_member(x2, x_b)
+        a, b = (
+            FactoredDelta(f.w, *map(per_member, f[1:], f_b[1:]))
+            for f, f_b in ((a, a_b), (b, b_b))
+        )
+        # XLA meets the shapes the default batching showed it, [M, T, ·] on
+        # both sides: without the barriers it carries the two reshapes into the
+        # neighbouring fusions (an RMS norm between two sites becomes a 2D
+        # reduce) and their rounding is no longer the parent program's
+        x2 = jax.lax.optimization_barrier(x2)
+        out = _pallas_fused_qlora(
+            x2.reshape(M * T, -1), q8, scale, a, b, lora_scale, block_t, block_n, interpret, g
+        )
+        return jax.lax.optimization_barrier(out.reshape(M, T, -1)), True
+
+    return call
 
 
 def fused_qlora_dense(
@@ -354,7 +577,9 @@ def fused_qlora_dense(
     :func:`xla_fused_qlora` — the byte-identical round-14 composition.
     ``use_pallas=None`` auto-selects via :func:`use_fused_qlora_pallas`.
     The selection is final: a selected kernel that fails to trace or
-    compile raises. ``interpret`` is for tests only.
+    compile raises. ``interpret`` is for tests only. Under ``vmap`` the
+    kernel call follows its own rule (:func:`_members_into_rows`): members of
+    few rows share a token block and the base is read once for them.
 
     Parity boundary: at an f32 serving dtype kernel and XLA composition
     agree to ~1e-5. At bf16 the difference is bf16-ROUNDING class (measured
@@ -379,9 +604,8 @@ def fused_qlora_dense(
     if fitted is None or not (use_pallas or interpret):
         return xla_fused_qlora(x, qk, leaf, lora_scale)
     lead = x.shape[:-1]
-    out = _pallas_fused_qlora(
-        x.reshape(-1, x.shape[-1]), q8, scale, a, b, lora_scale, *fitted, interpret
-    )
+    call = _members_into_rows(lora_scale, *fitted, interpret)
+    out = call(x.reshape(-1, x.shape[-1]), q8, scale, a, b)
     return out.reshape(*lead, out.shape[-1])
 
 

@@ -1,10 +1,12 @@
 """Grouped matmul over the routed experts a chip holds, with a per-(member,
 expert) factored LoRA delta.
 
-``ops/fused_qlora.py`` assumes one dense base tile shared by every member and
-rows that belong to one member. A routed expert layer breaks both: a member's
-rows scatter over experts, and an expert sees rows of several members. The
-formulation here keeps the two parts apart:
+``ops/fused_qlora.py`` assumes one dense base shared by every member and a
+token block made of whole members, each row meeting its own member's factors
+(its ``vmap`` rule lays members of few rows side by side in one block). A
+routed expert layer breaks both: a member's rows scatter over experts, and an
+expert sees rows of several members in no order. The formulation here keeps
+the two parts apart:
 
 - **base** (:func:`grouped_matmul`): every (row, expert) pair of the call —
   all members of a ``lax.map`` chunk together — is sorted by expert and

@@ -52,6 +52,10 @@ class Case:
     xla_fn: Callable[..., jax.Array]
     tol: float  # bound on max|kernel − xla| / max|xla|
     tol_reason: str
+    # the same kernel called once a member (what vmap's default batching of a
+    # Pallas call amounts to): where set, the chip run times both and counts
+    # the outputs that differ bit for bit
+    per_member_fn: Optional[Callable[..., jax.Array]] = None
 
 
 def _factored(key, m: int, n: int, r_e: int, noise_dtype):
@@ -102,10 +106,16 @@ def _qlora_case(label: str, T: int, din: int, dout: int, members: int = 0) -> Ca
         axes = FactoredDelta(None, 0, 0, 0)
         return jax.vmap(one, in_axes=(0, None, axes, axes))
 
+    kernel = lambda x, qk, leaf, s: fused_qlora_dense(x, qk, leaf, s, use_pallas=True)
+
+    def per_member(x, qk, a, b):
+        pick = lambda f, k: FactoredDelta(f.w, f.u[k], f.v[k], f.c[k])
+        return jnp.stack([kernel(x[k], qk, {"a": pick(a, k), "b": pick(b, k)}, 2.0)
+                          for k in range(members)])
+
     return Case(
-        "fused_qlora", label, make,
-        run(lambda x, qk, leaf, s: fused_qlora_dense(x, qk, leaf, s, use_pallas=True)),
-        run(xla_fused_qlora),
+        "fused_qlora", label, make, run(kernel), run(xla_fused_qlora),
+        per_member_fn=per_member if members > 1 else None,
         tol=4 * _BF16_EPS,
         tol_reason="the XLA form rounds a_k, b_k and both partial products to "
                    "bf16 before the sum; the kernel keeps f32 until one final "
@@ -222,6 +232,23 @@ def cases() -> List[Case]:
                                 ("fc1", 1024, 4096), ("fc2", 4096, 1024))
         for rows in (8, 128, 2048)
     ]
+    # what ops/fused_qlora.py's own rule for the member axis is for: members of
+    # few rows, whose rows share a token block and the base's one read. The
+    # decode sites of the lm_ar cell (openPangu-Ultra-MoE widths, member_batch
+    # 8 x 8 sequences: ffn gate/up, MLA wuq, wdkv, wdq, the shared expert's
+    # down) and VAR's small scales at fc1 (member_batch 4).
+    out += [
+        _qlora_case(f"lm_ar decode {site}, member axis 8: x[8,8,{din}] @ s8[{din},{dout}]",
+                    8, din, dout, members=8)
+        for site, din, dout in (("ffn gate/up", 7680, 18432), ("mla wuq", 1536, 24576),
+                                ("mla wdkv", 7680, 576), ("mla wdq", 7680, 1536),
+                                ("shared down", 2048, 7680))
+    ]
+    out += [
+        _qlora_case(f"var fc1 small scale, member axis 4: x[4,{rows},1024] @ s8[1024,4096]",
+                    rows, 1024, 4096, members=4)
+        for rows in (8, 72, 200)
+    ]
     out += [
         _attention_case(f"var scale {i}: q[8,{nq},16,64] vs cache[8,680,16,64] kv_len {kv}",
                         8, nq, 680, kv)
@@ -240,6 +267,27 @@ def cases() -> List[Case]:
         _int8mm_case("sana attn: x[1024,2240] @ s8[2240,2240]", 1024, 2240, 2240),
     ]
     return out
+
+
+def _us_per_call(fn: Callable[..., jax.Array], args: Tuple[Any, ...], calls: int = 100) -> float:
+    """Microseconds a call of ``fn(*args)``: ``calls`` of them in one program
+    (each one's first argument takes a zero made from the last one's output,
+    so they run in order and none is hoisted), by the host's clock around
+    the whole, after one run that compiles."""
+    import time
+
+    def chain(x, *rest):
+        def step(x, _):
+            y = fn(x, *rest)
+            return x + (y[..., :1] * 0).astype(x.dtype), None
+
+        return jax.lax.scan(step, x, None, length=calls)[0]
+
+    chain = jax.jit(chain)
+    jax.block_until_ready(chain(*args))
+    t0 = time.perf_counter()
+    jax.block_until_ready(chain(*args))
+    return (time.perf_counter() - t0) / calls * 1e6
 
 
 def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str, Any]:
@@ -264,6 +312,13 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
     diff = float(jnp.max(jnp.abs(got - ref)))
     scale = float(jnp.max(jnp.abs(ref)))
     rel = diff / max(scale, 1e-30)
+    if case.per_member_fn is not None:
+        per = jax.jit(case.per_member_fn)(*args).astype(jnp.float32)
+        rec.update(
+            differ_from_per_member=int(jnp.sum(got != per)), outputs=int(got.size),
+            us_per_call=round(_us_per_call(case.kernel_fn, args), 2),
+            us_per_call_per_member=round(_us_per_call(case.per_member_fn, args), 2),
+        )
     return {
         **rec, "max_abs_diff": diff, "max_abs_ref": scale, "rel": rel,
         "tol": case.tol, "tol_reason": case.tol_reason,

@@ -256,6 +256,8 @@ _CELL_CALLS = {
     "var-fc1": (2048, 1024, 4096, 4),
     "var-fc2": (2048, 4096, 1024, 4),
     "var-qkv-scale0": (8, 1024, 3072, 4),
+    "var-fc1-scale2": (72, 1024, 4096, 4),
+    "lm-ffn-gate-decode": (8, 7680, 18432, 8),
 }
 
 
@@ -327,19 +329,293 @@ def v5e_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("call", ["sana-attn", "var-fc2"])
+@pytest.mark.parametrize(
+    "call", ["sana-attn", "var-fc2", "lm-ffn-gate-decode", "var-fc1-scale2", "sana-caption_proj"])
 def test_mosaic_compiles_the_largest_cell_calls(call, v5e_chip):
     """The two largest calls of the benchmark's cells — Sana's attention
     site (the most work) and VAR's fc2 (the widest contraction, where
     ``_fit_blocks`` halves) — compile for a v5e at their real shapes with the
     member axis in front: block shapes, the scratch and the VMEM limit are
-    Mosaic's to refuse, and the interpreter accepts anything."""
+    Mosaic's to refuse, and the interpreter accepts anything. PR 28: and the
+    calls whose token block is several whole members — the ``lm_ar`` cell's
+    dense-FFN gate at a decode step (8 members x 8 rows), a small VAR scale
+    (4 x 72) and Sana's caption projection (2 x 300, rows that are no
+    multiple of 16: at 128 rows a member or more the rule keeps the one-member
+    call, batched) — whose masks and stacked thin operands are Mosaic's to
+    refuse too."""
     from hyperscalees_t2i_tpu.tools import kernel_check
 
     rows, din, dout, members = _CELL_CALLS[call]
     case = kernel_check._qlora_case(call, rows, din, dout, members=members)
     rec = kernel_check.run_case(case, compile_only_device=v5e_chip)
     assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
+
+
+# ---------------------------------------------------------------------------
+# the member axis: a token block made of whole members (PR 28)
+# ---------------------------------------------------------------------------
+
+_MEMBER_AXES = FactoredDelta(None, 0, 0, 0)  # what pop_eval batches: u, v, c
+
+
+def _members(key, M, T, din=32, dout=300, r_l=8, r_e=4, x_dtype=jnp.float32,
+             noise_dtype=jnp.bfloat16):
+    """(x [M, T, din], qk, a, b): ``M`` members over one int8 base, each with
+    its own activations, noise slices and coefficient; ``w`` shared. dout 300
+    is not a multiple of the tests' 128-channel tile."""
+    ks = jax.random.split(key, 10)
+    qk = quantize_kernel(jax.random.normal(ks[0], (din, dout)) * 0.1)
+    noise = lambda k, *shape: jax.random.normal(k, shape).astype(noise_dtype)
+    a = FactoredDelta(jax.random.normal(ks[1], (din, r_l)), noise(ks[2], M, din, r_e),
+                      noise(ks[3], M, r_l, r_e), 0.03 * jax.random.normal(ks[4], (M,)))
+    b = FactoredDelta(jax.random.normal(ks[5], (r_l, dout)), noise(ks[6], M, r_l, r_e),
+                      noise(ks[7], M, dout, r_e), 0.04 * jax.random.normal(ks[8], (M,)))
+    return jax.random.normal(ks[9], (M, T, din)).astype(x_dtype), qk, a, b
+
+
+def _member(f, k):
+    return FactoredDelta(f.w, f.u[k], f.v[k], f.c[k])
+
+
+def _by_vmap(x, qk, a, b, lora_scale=2.0, axes=_MEMBER_AXES, x_axis=0, **kw):
+    return jax.vmap(
+        lambda xx, aa, bb: fused_qlora_dense(
+            xx, qk, {"a": aa, "b": bb}, lora_scale, interpret=True, **kw),
+        in_axes=(x_axis, axes, axes),
+    )(x, a, b)
+
+
+def _by_member(x, qk, a, b, lora_scale=2.0, **kw):
+    """The per-member form: the same kernel called once a member."""
+    return jnp.stack([
+        fused_qlora_dense(x[k], qk, {"a": _member(a, k), "b": _member(b, k)},
+                          lora_scale, interpret=True, **kw)
+        for k in range(x.shape[0])
+    ])
+
+
+def _recorded_calls(monkeypatch):
+    """Every ``pl.pallas_call`` the code under test builds, as a dict of its
+    grid, metadata, scratch and (block shape, dtype) of each VMEM operand
+    with the output's last; the call itself returns zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    calls = []
+
+    def recorder(kernel, *, out_shape, grid, in_specs, out_specs, scratch_shapes,
+                 metadata=None, **kw):
+        def call_(*operands):
+            calls.append(dict(grid=grid, scratch=scratch_shapes, metadata=metadata, specs=[
+                (tuple(d for d in spec.block_shape if d is not None), op.dtype)
+                for spec, op in zip([*in_specs, out_specs], [*operands, out_shape])
+                if spec.memory_space != pltpu.SMEM
+            ]))
+            return jnp.zeros(out_shape.shape, out_shape.dtype)
+
+        return call_
+
+    monkeypatch.setattr(pl, "pallas_call", recorder)
+    return calls
+
+
+# (M, T, block_t) -> members a token block: every one when their rows fit, the
+# largest divisor of M otherwise (a block short of the whole array is made of
+# whole sublane tiles), 1 when nothing divides, a member alone fills it, or a
+# member has the MXU's 128 rows or more
+_GROUPINGS = {
+    (8, 8, 128): 8, (4, 72, 512): 4, (3, 8, 128): 3, (3, 8, 16): 1, (1, 8, 128): 1,
+    (6, 5, 128): 6, (4, 40, 128): 2, (4, 72, 128): 1, (6, 5, 16): 1,
+    (2, 128, 512): 1, (4, 200, 1024): 1,
+}
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("M,T,block_t", sorted(_GROUPINGS))
+def test_member_groups_match_the_per_member_kernel(M, T, block_t, x_dtype):
+    """Under ``vmap`` the kernel's own rule puts ``g`` whole members into a
+    token block (rows not a multiple of 8, a dout that does not fill its last
+    tile, groups short of the whole chunk). Every member's rows come out as
+    the per-member kernel's — the f32 sums' order aside, which belongs to the
+    machine's dot — and as the formula's; where ``g`` is 1 the call is the
+    per-member one and the outputs are its outputs bit for bit."""
+    from hyperscalees_t2i_tpu.ops.fused_qlora import _members_per_block
+
+    g = _members_per_block(M, T, block_t, 8, 4, jnp.dtype(x_dtype).itemsize)
+    assert g == _GROUPINGS[M, T, block_t]
+    x, qk, a, b = _members(jax.random.PRNGKey(100 * M + T), M, T, x_dtype=x_dtype)
+    kw = dict(block_t=block_t, block_n=128)
+    out, per = _by_vmap(x, qk, a, b, **kw), _by_member(x, qk, a, b, **kw)
+    assert out.shape == per.shape == (M, T, 300) and out.dtype == x_dtype
+    if g == 1:
+        np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(per, np.float32))
+    for k in range(M):
+        base, delta = _formula(x[k], qk, {"a": _member(a, k), "b": _member(b, k)}, 2.0)
+        if x_dtype == jnp.float32:  # f32 sums of ~300 terms, relative to the largest
+            for ref in (base + delta, per[k]):
+                np.testing.assert_allclose(
+                    np.asarray(out[k]), np.asarray(ref), atol=2e-6 * float(jnp.abs(ref).max()))
+        else:  # two bf16 roundings of f32 results an ulp apart: at most one spacing
+            _assert_within_output_rounding(out[k], base + delta, x_dtype)
+            got, ref = np.asarray(out[k], np.float32), np.asarray(per[k], np.float32)
+            assert np.all(np.abs(got - ref) <= 2.0 ** -7 * np.abs(ref) + 1e-5 * np.abs(ref).max())
+    if x_dtype == jnp.float32:  # the file's tolerance of the XLA composition
+        ref = jax.vmap(
+            lambda xx, aa, bb: xla_fused_qlora(xx, qk, {"a": aa, "b": bb}, 2.0),
+            in_axes=(0, _MEMBER_AXES, _MEMBER_AXES),
+        )(x, a, b)
+        scale = float(jnp.abs(ref).max())
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5 * scale, rtol=1e-5)
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("M,T", [(8, 8), (3, 8), (6, 5), (4, 40)])
+def test_member_groups_leave_the_base_term_bit_identical(M, T, x_dtype):
+    """``lora_scale`` 0 leaves the base dot. Integer activations and
+    power-of-two scales make every product and every partial sum exact in
+    f32, so whatever order a machine's dot sums in, a row's base term is one
+    number: grouped and per-member must agree bit for bit, and with the
+    formula. (On the chip, where the order is the MXU's, ``tools/kernel_check``
+    counts the outputs that differ on seeded normal data.)"""
+    x, qk, a, b = _members(jax.random.PRNGKey(7), M, T, din=512, x_dtype=x_dtype)
+    x = jnp.round(x.astype(jnp.float32) * 3).astype(x_dtype)
+    qk = {"q8": qk["q8"], "scale": 2.0 ** -jnp.round(6 + jnp.abs(qk["scale"]) * 1e3 % 3)}
+    kw = dict(lora_scale=0.0, block_t=128, block_n=128)
+    out, per = _by_vmap(x, qk, a, b, **kw), _by_member(x, qk, a, b, **kw)
+    np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(per, np.float32))
+    base = jnp.stack([_formula(x[k], qk, {"a": _member(a, k), "b": _member(b, k)}, 0.0)[0]
+                      for k in range(M)])
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(base.astype(x_dtype), np.float32))
+
+
+def test_member_groups_antithetic_pair_shares_its_noise():
+    """An antithetic pair is one (u, v) with opposite ``c``: only ``c`` (and
+    the activations) carry the member axis, the rule gives the rest one, and
+    the pair's outputs are base ± perturbation around the unperturbed."""
+    x, qk, a, b = _members(jax.random.PRNGKey(71), 2, 8)
+    pair = lambda f, c: FactoredDelta(f.w, f.u[0], f.v[0], jnp.array([c, -c]))
+    a, b = pair(a, 0.03), pair(b, -0.04)
+    axes = FactoredDelta(None, None, None, 0)
+    out = _by_vmap(x, qk, a, b, axes=axes, block_t=128, block_n=128)
+    for k in range(2):
+        pick = lambda f: FactoredDelta(f.w, f.u, f.v, f.c[k])
+        base, delta = _formula(x[k], qk, {"a": pick(a), "b": pick(b)}, 2.0)
+        np.testing.assert_allclose(np.asarray(out[k]), np.asarray(base + delta),
+                                   atol=2e-6 * float(jnp.abs(base + delta).max()))
+    # shared activations too (a caption every member reads): x has no axis
+    out = _by_vmap(x[0], qk, a, b, axes=axes, x_axis=None, block_t=128, block_n=128)
+    zero = lambda f: FactoredDelta(f.w, f.u, f.v, jnp.float32(0.0))
+    mid = fused_qlora_dense(x[0], qk, {"a": zero(a), "b": zero(b)}, 2.0, interpret=True)
+    half = np.asarray(out[0] - out[1]) / 2
+    assert np.abs(half).max() > 1e-3 * np.abs(np.asarray(mid)).max()
+    # the pair straddles the unperturbed output up to the second-order term c_a·c_b
+    second = np.abs(np.asarray((out[0] + out[1]) / 2 - mid)).max()
+    assert second < 0.1 * np.abs(half).max()
+
+
+def test_member_groups_leak_nothing_between_members():
+    """A row keeps only its own member's ``r_e`` columns (the others are
+    exact zeros before they meet a ``v``): another member's noise, however
+    large, does not move a bit of this member's output."""
+    x, qk, a, b = _members(jax.random.PRNGKey(72), 4, 8)
+    kw = dict(block_t=128, block_n=128)
+    out = _by_vmap(x, qk, a, b, **kw)
+    loud = lambda f: FactoredDelta(
+        f.w, f.u.at[2].multiply(1e3), f.v.at[2].multiply(-7.0), f.c.at[2].set(0.9))
+    out2 = _by_vmap(x, qk, loud(a), loud(b), **kw)
+    for k in (0, 1, 3):
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(out2[k]))
+    assert not np.allclose(np.asarray(out[2]), np.asarray(out2[2]))
+
+
+def test_member_axis_rule_by_what_is_batched(monkeypatch):
+    """The rule engages on the pattern pop_eval hands it (base and ``w``
+    shared). A base per member keeps Pallas's default batching of the
+    one-member call, and so does ``g`` = 1: the parent's call — a member's
+    own rows a token block, the one-member blocks — with ``members_per_block``
+    in the call's metadata either way."""
+    from hyperscalees_t2i_tpu.ops import fused_qlora as fq
+
+    M, T, din, dout = 4, 8, 32, 300
+    x, qk, a, b = _members(jax.random.PRNGKey(73), M, T, din=din, dout=dout)
+    qks = jax.vmap(lambda s: quantize_kernel(
+        jax.random.normal(jax.random.PRNGKey(0), (din, dout)) * s))(jnp.arange(1.0, M + 1))
+    per_base = lambda xx, q, aa, bb: fused_qlora_dense(
+        xx, q, {"a": aa, "b": bb}, 2.0, interpret=True, block_t=128, block_n=128)
+    out = jax.vmap(per_base, in_axes=(0, 0, _MEMBER_AXES, _MEMBER_AXES))(x, qks, a, b)
+    for k in range(M):
+        one = per_base(x[k], jax.tree_util.tree_map(lambda t: t[k], qks),
+                       _member(a, k), _member(b, k))
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(one))
+
+    calls = _recorded_calls(monkeypatch)
+
+    def last_call(thunk):
+        # custom_vmap traces the one-member call before vmap asks its rule:
+        # the call that reaches the program is the last one built
+        del calls[:]
+        thunk()
+        return calls[-1]
+
+    made = [
+        last_call(lambda: jax.vmap(per_base, in_axes=(0, 0, _MEMBER_AXES, _MEMBER_AXES))(
+            x, qks, a, b)),                                            # a base a member
+        last_call(lambda: _by_vmap(x, qk, a, b, block_t=16, block_n=128)),   # 2 x 8 rows fit 16
+        last_call(lambda: _by_vmap(x, qk, a, b, block_t=8, block_n=128)),    # a member fills it
+        last_call(lambda: _by_vmap(x, qk, a, b, block_t=128, block_n=128)),  # every member
+        last_call(lambda: per_base(x[0], qk, _member(a, 0), _member(b, 0))),  # no vmap at all
+    ]
+    want = [(T, 1, 1), (2 * T, 2, 2), (T, 1, 1), (4 * T, 4, 1), (T, 1, 1)]  # block rows, g, groups
+    for c, (bt, g, groups) in zip(made, want):
+        assert c["metadata"] == {"members_per_block": str(g)}
+        assert c["grid"] == (groups, 3)
+        blocks, scratch = fq._declared_blocks(
+            din, _member(a, 0), _member(b, 0), bt, 128, x.dtype, g)
+        assert c["specs"] == [(tuple(shape), jnp.dtype(dt)) for shape, dt in blocks]
+        (z,) = c["scratch"]
+        assert (tuple(z.shape), z.dtype) == (scratch[0], jnp.dtype(scratch[1]))
+
+
+def test_members_per_block_reaches_the_program_record():
+    """``programs.jsonl`` says for every ``fused_qlora`` site how many
+    members share a token block: the call's metadata, read back from the
+    lowered text (here lowered for the TPU without one) beside
+    ``pallas_kernels``."""
+    from hyperscalees_t2i_tpu.obs.xla_cost import stablehlo_stats
+
+    x, qk, a, b = _members(jax.random.PRNGKey(74), 4, 8, din=128, dout=256, x_dtype=jnp.bfloat16)
+
+    def two_sites(x, a, b):
+        run = lambda xx, aa, bb, bt: fused_qlora_dense(
+            xx, qk, {"a": aa, "b": bb}, 2.0, use_pallas=True, block_t=bt, block_n=128)
+        grouped = jax.vmap(lambda *m: run(*m, 128), in_axes=(0, _MEMBER_AXES, _MEMBER_AXES))
+        alone = jax.vmap(lambda *m: run(*m, 8), in_axes=(0, _MEMBER_AXES, _MEMBER_AXES))
+        return grouped(x, a, b) + alone(x, a, b) + run(x[0], _member(a, 0), _member(b, 0), 128)
+
+    lowered = jax.jit(two_sites).trace(x, a, b).lower(lowering_platforms=("tpu",))
+    stats = stablehlo_stats(lowered)
+    assert stats["pallas_kernels"] == {"fused_qlora": 3}
+    assert stats["pallas_members_per_block"] == {"fused_qlora": {"4": 1, "1": 2}}
+
+
+def test_member_groups_inside_shard_map():
+    """pop_eval vmaps a member chunk inside ``shard_map`` over the pop axis
+    (the four-chip cell): the kernel's own batching rule has to trace there
+    as it does under a bare ``vmap``."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from hyperscalees_t2i_tpu.parallel.mesh import shard_map
+
+    M = 4  # two members a device on a two-device pop axis
+    x, qk, a, b = _members(jax.random.PRNGKey(75), M, 8)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("pop",))
+    spec = FactoredDelta(P(), P("pop"), P("pop"), P("pop"))
+    local = lambda x, a, b: _by_vmap(x, qk, a, b, block_t=128, block_n=128)
+    out = shard_map(local, mesh=mesh, in_specs=(P("pop"), spec, spec),
+                    out_specs=P("pop"), check_vma=False)(x, a, b)
+    ref = _by_member(x, qk, a, b, block_t=128, block_n=128)
+    _assert_close(out, ref, tol=1e-5 * float(jnp.abs(ref).max()))
 
 
 def test_kernel_declines_oversize_layer():
