@@ -472,7 +472,6 @@ def run_rung(rung: str, allow_env_overrides: bool = True) -> dict:
     import jax.numpy as jnp
 
     from hyperscalees_t2i_tpu.backends.base import make_frozen
-    from hyperscalees_t2i_tpu.ops.fused_qlora import unified_routing_enabled
     from hyperscalees_t2i_tpu.ops.pallas_gate import selected_kernels
     from hyperscalees_t2i_tpu.parallel import gcd_pop_data_mesh, replicated
     from hyperscalees_t2i_tpu.train.config import TrainConfig
@@ -491,7 +490,7 @@ def run_rung(rung: str, allow_env_overrides: bool = True) -> dict:
 
     _log(f"{rung}: building models (scale={scale} pop={pop} m={m} "
          f"remat={opt['remat']} tile={opt['reward_tile']} noise={opt['noise_dtype']} "
-         f"towers={opt['tower_dtype']} fuse={opt.get('pop_fuse', False)} "
+         f"towers={opt['tower_dtype']} "
          f"base={opt.get('base_quant', 'off')})")
     t_build0 = time.perf_counter()
     with Heartbeat(rung, "build"):
@@ -511,7 +510,6 @@ def run_rung(rung: str, allow_env_overrides: bool = True) -> dict:
                      batches_per_gen=repeats, member_batch=member_batch, promptnorm=True,
                      remat=opt["remat"], reward_tile=opt["reward_tile"],
                      noise_dtype=opt["noise_dtype"],
-                     pop_fuse=opt.get("pop_fuse", False),
                      base_quant=opt.get("base_quant", "off"),
                      quality=opt.get("quality", False))
     num_unique = min(m, backend.num_items)
@@ -740,7 +738,6 @@ def run_rung(rung: str, allow_env_overrides: bool = True) -> dict:
         "reward_tile": opt["reward_tile"],
         "noise_dtype": opt["noise_dtype"],
         "tower_dtype": opt["tower_dtype"],
-        "pop_fuse": opt.get("pop_fuse", False),
         "base_quant": opt.get("base_quant", "off"),
         "steps_timed": steps,
         "step_time_s": round(headline_time, 4),
@@ -789,13 +786,11 @@ def run_rung(rung: str, allow_env_overrides: bool = True) -> dict:
         "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR") or None,
         # kernel provenance (round 15): the Pallas env flags active for this
         # measurement, what every kernel gate selected on this backend, the
-        # Mosaic custom calls the compiled step really carries, and the
-        # unified int8+LoRA routing state — what makes kernel-on and
-        # kernel-off artifacts distinguishable in the trend
+        # Mosaic custom calls the compiled step really carries — what makes
+        # kernel-on and kernel-off artifacts distinguishable in the trend
         "pallas_env": active_pallas_flags(),
         "pallas_selected": selected_kernels(),
         "pallas_kernels": prog.get("pallas_kernels"),
-        "fused_qlora": unified_routing_enabled(),
         # device-truth provenance (round 21): where the --profile capture
         # landed (None = unprofiled) — obs/calib.py joins its .xplane.pb
         # module timings back to this rung's ledger record
@@ -1439,14 +1434,12 @@ def run_fleet_bench(rung: str, widths, batches: int = 3,
     def job_tc(j):
         # distinct per-job hypers: σ/lr_scale/seed differ per job, cohort
         # geometry shared — exactly what the fused program argument-batches.
-        # pop_fuse on BOTH paths: the comparison isolates job batching, not
-        # the round-12 fused-perturbation win.
         return TrainConfig(
             pop_size=pop, sigma=0.01 * (1.0 + 0.5 * j), lr_scale=1.0 + 0.25 * j,
             egg_rank=4, prompts_per_gen=m, batches_per_gen=1,
             member_batch=member_batch, promptnorm=True,
             remat=opt["remat"], reward_tile=opt["reward_tile"],
-            noise_dtype=opt["noise_dtype"], pop_fuse=True,
+            noise_dtype=opt["noise_dtype"],
             base_quant=opt.get("base_quant", "off"), quality=False, seed=11 + j,
         )
 
@@ -1625,7 +1618,6 @@ def run_fleet_bench(rung: str, widths, batches: int = 3,
         "pop": pop,
         "prompts": num_unique,
         "member_batch": member_batch,
-        "pop_fuse": True,
         "batches_timed": batches,
         "widths": rows_out,
         # flat-retrace evidence: fleet_traces must equal the number of fused

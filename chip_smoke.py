@@ -127,7 +127,7 @@ def main(argv=None) -> int:
         "--prompts_txt", str(ROOT / "data" / "prompts_train.txt"),
         "--remat", opt["remat"], "--reward_tile", str(opt["reward_tile"]),
         "--noise_dtype", opt["noise_dtype"], "--tower_dtype", opt["tower_dtype"],
-        "--pop_fuse", str(opt["pop_fuse"]).lower(), "--base_quant", opt["base_quant"],
+        "--base_quant", opt["base_quant"],
         "--pop_size", str(args.pop_size), "--prompts_per_gen", "4",
         "--member_batch", "1", "--num_epochs", str(EPOCHS),
         "--allow_random_rewards", "true",

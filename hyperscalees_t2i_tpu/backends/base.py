@@ -94,6 +94,9 @@ class ESBackend(Protocol):
     ) -> jax.Array:
         """Pure function: [B] catalog indices → images [B, H, W, 3] in [0,1].
         Reads arrays only from ``frozen``/``theta`` args (static config aside).
+        In a step program ``theta`` is a population member's: its matrix leaves
+        arrive as ``lora.FactoredDelta`` (``nn.dense``/``conv2d`` consume them;
+        code that reads a leaf itself builds it with ``lora.effective_factor``).
         ``item_index`` is each image's *global* batch position (default
         ``arange(B)``): per-image noise keys must fold it in so outputs are
         invariant to batch chunking and data-axis sharding."""

@@ -23,8 +23,11 @@ TPU-first redesign (NOT a port):
   antithetic pairing. A full materialized population of perturbations is never
   allocated. This is the actual point of EGGROLL: factors cost ``r(m+n)`` per
   member instead of ``m·n``.
-- a member's perturbed parameters are materialized *inside* the (vmapped /
-  shard_mapped) evaluation, one member per lane: ``θ_k = θ + σ·s_k·U_b V_bᵀ/√r``.
+- a member's perturbed parameters ``θ_k = θ + σ·s_k·U_b V_bᵀ/√r`` are built
+  *inside* the (vmapped / shard_mapped) evaluation, one member per lane, and
+  stay factored up to the dense that consumes them
+  (:func:`factored_member_theta`); :func:`perturb_member` is the plain
+  reference that materializes them.
 - the ES update contracts fitness into the factors with one batched einsum per
   leaf: ``Δ = Σ_b c_b · U_b V_bᵀ / (n·√r)`` with ``c_b = Σ_{k: base(k)=b} f_k s_k``
   (a segment-sum). No ``[pop, D]`` matrix ever exists.
@@ -113,11 +116,10 @@ def member_signs_and_bases(pop_size: int, antithetic: bool) -> Tuple[np.ndarray,
 
     Deliberately *uncached*: returning one shared ndarray object would let
     jax deduplicate the resulting jnp constants across call sites, which
-    changes the lowered program text — and the materialized path's StableHLO
-    is pinned bit-for-bit (the all-knobs-off parity anchor, PERF.md round
-    12). The fused path instead goes through :func:`member_maps`, which IS
-    cached and threads one device-side table pair through the whole member
-    loop.
+    changes the lowered text of every program built on the update
+    (:func:`fitness_coeffs`, :func:`es_update`). The member loop goes through
+    :func:`member_maps`, which IS cached and threads one device-side table
+    pair through the whole loop.
     """
     if not antithetic:
         return np.ones(pop_size, np.float32), np.arange(pop_size, dtype=np.int32)
@@ -143,11 +145,10 @@ def _cached_member_tables(pop_size: int, antithetic: bool) -> Tuple[np.ndarray, 
 
 
 def member_maps(pop_size: int, antithetic: bool) -> Tuple[jax.Array, jax.Array]:
-    """Device-side ``(signs, bases)`` lookup tables for the fused member
-    loop: the numpy tables are built once per (pop, antithetic) geometry
-    (lru-cached — the materialized path used to rebuild them on every
-    ``materialize_member_eps`` call) and wrapped once per trace, threaded
-    through the loop as explicit arguments instead of re-wrapped per member."""
+    """Device-side ``(signs, bases)`` lookup tables for the member loop: the
+    numpy tables are built once per (pop, antithetic) geometry (lru-cached)
+    and wrapped once per trace, threaded through the loop as explicit
+    arguments instead of re-wrapped per member."""
     signs, bases = _cached_member_tables(pop_size, antithetic)
     return jnp.asarray(signs), jnp.asarray(bases)
 
@@ -223,7 +224,8 @@ def _noise_leaves(theta: Pytree, noise: Pytree) -> Tuple[List[jax.Array], List[A
 def materialize_member_eps(theta: Pytree, noise: Pytree, k: jax.Array, pop_size: int, cfg: EggRollConfig) -> Pytree:
     """Materialize member ``k``'s full-rank perturbation ε_k as a theta-shaped pytree.
 
-    ``k`` may be a traced scalar (e.g. inside ``vmap``/``lax.map``).
+    ``k`` may be a traced scalar (e.g. inside ``vmap``/``lax.map``). No step
+    program calls this: it is the reference of :func:`perturb_member`.
     """
     signs, bases = member_signs_and_bases(pop_size, cfg.antithetic)
     s = jnp.asarray(signs)[k]
@@ -255,20 +257,18 @@ def perturb_member(
     k: jax.Array,
     pop_size: int,
     cfg: EggRollConfig,
-    sigma: Optional[jax.Array] = None,
 ) -> Pytree:
     """θ_k = θ + σ · ε_k, materialized for one population member (jit/vmap-safe).
 
-    ``sigma`` (optional traced f32 scalar) overrides ``cfg.sigma`` — the fleet
-    path's lane-indexed per-job σ_j (ISSUE 20). ``None`` keeps the static
-    ``cfg.sigma`` constant and traces the byte-identical pre-fleet program
-    (the all-knobs-off StableHLO pin); a traced σ equal to ``f32(cfg.sigma)``
-    applies the same multiply in the same position, so per-member results stay
-    bitwise identical to the solo program's.
+    The plain reference of the member path: the step programs hand members to
+    the forward factored (:func:`factored_member_theta`) and are held to this
+    within float rounding (tests/test_fused.py; the benchmark's
+    ``drivers/es_train_ref.py``). Its one use outside a comparison is the
+    single-member regeneration of ``trainer.regenerate_member_images``, which
+    runs a backend's plain ``generate`` on raw leaves.
     """
     eps = materialize_member_eps(theta, noise, k, pop_size, cfg)
-    s = cfg.sigma if sigma is None else sigma
-    return jax.tree_util.tree_map(lambda t, e: t + s * e.astype(t.dtype), theta, eps)
+    return jax.tree_util.tree_map(lambda t, e: t + cfg.sigma * e.astype(t.dtype), theta, eps)
 
 
 def factored_member_theta(
@@ -283,9 +283,8 @@ def factored_member_theta(
 ) -> Pytree:
     """Member ``k``'s perturbed adapter with the perturbation kept *factored*.
 
-    The fused evaluation path's replacement for :func:`perturb_member`: every
-    low-rank-noised leaf becomes a ``lora.FactoredDelta(w=θ_leaf, u=U[b],
-    v=V[b], c=σ·s_k/√r)`` node — the dense ``U@Vᵀ`` product is never built;
+    What every step program hands the forward: every low-rank-noised leaf
+    becomes a ``lora.FactoredDelta(w=θ_leaf, u=U[b], v=V[b], c=σ·s_k/√r)`` node — the dense ``U@Vᵀ`` product is never built;
     consumers (models/nn.py ``dense``/``conv2d`` via lora.matmul_factored)
     apply it as chained thin contractions with f32 accumulation over the
     (possibly bf16) noise store. Dense-noised leaves (conv-4D ``a`` factors,
@@ -301,7 +300,7 @@ def factored_member_theta(
     dense-leaf σ. Both must be passed together, precomputed host-side with
     one rounding each (``np.float32(σ_j / sqrt(r))``) so a fleet lane whose
     σ_j equals ``cfg.sigma`` computes the bitwise-identical member theta.
-    ``None`` keeps the static-constant trace (the pinned solo program).
+    ``None`` keeps the static-constant trace (the solo program).
     """
     from ..lora import FactoredDelta
 
@@ -380,8 +379,8 @@ def fitness_coeffs(fitness: jax.Array, pop_size: int, cfg: EggRollConfig) -> jax
     the pop-sharded update (``parallel/pop_update.py``) can compute the tiny
     ``[base]`` vector once (replicated) and hand each pop shard its slice.
     Deliberately NOT called from :func:`es_update` itself: the replicated
-    update's lowered program is the bit-for-bit parity anchor (the
-    all-knobs-off StableHLO golden) and stays textually untouched."""
+    update's lowered program is the bit-for-bit parity anchor of the sharded
+    one and stays textually untouched."""
     signs, bases = member_signs_and_bases(pop_size, cfg.antithetic)
     base = base_pop_size(pop_size, cfg.antithetic)
     w = fitness.astype(jnp.float32) * jnp.asarray(signs)  # [pop]

@@ -52,8 +52,8 @@ class FactoredDelta(NamedTuple):
     consuming dot reading the activations exactly once. Do NOT apply it as
     a chained ``x@w + c·(x@u)@vᵀ`` expansion in XLA: that form re-reads the
     activations per term and was measured to move MORE bytes (PERF.md
-    round 12 dead end); the chain is correct only inside the Pallas kernel
-    (ops/fused_lora.py), where the token tile is VMEM-resident. A
+    round 12 dead end); the chain is right only inside a Pallas kernel
+    (ops/fused_qlora.py), where the token tile is VMEM-resident. A
     NamedTuple, so it flows through jit/vmap/lax.map/shard_map as an
     ordinary pytree node.
     """
@@ -188,29 +188,14 @@ def lora_delta(x: jax.Array, leaf: Optional[Dict[str, jax.Array]], scale: float)
     return (x @ a) @ b * scale
 
 
-def fused_lora_delta(x: jax.Array, leaf: Dict[str, Any], scale: float) -> jax.Array:
-    """(alpha/r)·(x@a_k)@b_k where either factor may be a :class:`FactoredDelta`.
-
-    The fused-member hot path's LoRA delta. Default (every platform): two
-    dots whose perturbed operands ``a_k``/``b_k`` are each built in ONE
-    fused expression at the point of use (:func:`effective_factor`) — no
-    per-member staged adapter, activations read once per dot. Behind
-    ``HSES_POP_FUSE_PALLAS=1`` on a capable TPU backend the whole thing
-    instead runs as one Pallas kernel (ops/fused_lora.py), where the
-    four-matmul *chain* form is the right shape because the token tile is
-    VMEM-resident (in XLA that chain was the measured dead end — PERF.md
-    round 12).
-    """
-    from .ops.fused_lora import member_lora_delta, use_fused_pallas, xla_member_lora_delta
-
-    a, b = leaf["a"], leaf["b"]
-    if (
-        isinstance(a, FactoredDelta) and isinstance(b, FactoredDelta)
-        and a.w.ndim == 2 and b.w.ndim == 2
-        and use_fused_pallas()
-    ):
-        return member_lora_delta(x, a, b, scale, use_pallas=True)
-    return xla_member_lora_delta(x, a, b, scale)
+def factored_lora_delta(x: jax.Array, leaf: Dict[str, Any], scale: float) -> jax.Array:
+    """(alpha/r)·(x@a_k)@b_k where either factor may be a :class:`FactoredDelta`
+    — a training member's LoRA delta on every platform: two dots whose
+    perturbed operands ``a_k``/``b_k`` are each built in ONE fused expression
+    at the point of use (:func:`effective_factor`), f32 accumulation over the
+    noise store, the activations read once per dot."""
+    h = matmul_factored(x, leaf["a"])
+    return matmul_factored(h, leaf["b"]) * jnp.asarray(scale, x.dtype)
 
 
 def stack_adapters(trees: Sequence[Pytree]) -> Pytree:

@@ -111,8 +111,12 @@ def phi_index(cfg: BSQConfig, si: int) -> int:
 
 
 def phi_apply(params: Params, cfg: BSQConfig, h: jax.Array, si: int) -> jax.Array:
-    k = phi_index(cfg, si)
-    p = {"kernel": params["phi"]["kernel"][k], "bias": params["phi"]["bias"][k]}
+    node = params["phi"]
+    # the kernel, float or int8, then the bias: the order the step programs
+    # have always sliced them in (their lowered text depends on it)
+    p = nn.slice_stacked(
+        {name: node[name] for name in sorted(node, reverse=True)}, phi_index(cfg, si)
+    )
     return 0.5 * h + 0.5 * nn.conv2d(p, h)
 
 

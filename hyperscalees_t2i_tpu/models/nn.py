@@ -16,6 +16,11 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..lora import FactoredDelta, factored_lora_delta, matmul_factored
+from ..ops.fused_qlora import conv_kernel_q8_matmul, fused_qlora_applies, fused_qlora_dense
+# kernel_shape: re-exported for call sites that read geometry off a node
+from ..ops.quant import dequant_matmul, dequantize_kernel, kernel_shape  # noqa: F401
+
 Params = Dict[str, Any]
 
 
@@ -61,44 +66,40 @@ def norm_init(dim: int, scale: bool = True, bias: bool = True) -> Params:
 # ---------------------------------------------------------------------------
 
 def dense(p: Params, x: jax.Array, lora: Optional[Params] = None, lora_scale: float = 1.0) -> jax.Array:
-    """y = x @ W (+ b) (+ (alpha/r)(x@A)@B). Kernel may be 2D or per-layer-sliced,
-    float or int8-quantized (``kernel_q8``, see ops/quant.py).
+    """y = x @ W (+ b) (+ (alpha/r)(x@A)@B). Kernel may be 2D or per-layer-sliced.
 
-    LoRA factors may arrive as raw arrays (the materialized-perturbation
-    path — unchanged, byte-identical HLO) or as ``lora.FactoredDelta`` nodes
-    carrying the ES perturbation in factored form (the fused hot path); the
-    branch is resolved at trace time from the leaf types. When BOTH an int8
-    base and factored perturbations are present, the whole expression
-    resolves through ``ops/fused_qlora.fused_qlora_dense`` — ONE kernel
-    dequantizes the s8 base tile in VMEM and applies the member's LoRA chain
-    against it (the unified hot path; off the TPU it lowers the byte-identical
-    pre-round-15 XLA composition). Attention's QKV/out projections (sana.py
-    attn1/attn2, clip.py q/k/v/out) are ordinary dense sites and get the
-    same treatment through here.
+    How an adapter meets its base is read off the node's keys and the leaf's
+    type at trace time — this table is the whole decision (``conv2d`` follows
+    its first three rows; attention's QKV/out projections are ordinary dense
+    sites):
+
+    ============== ============================== ================================
+    base node      adapter leaf                   lowering
+    ============== ============================== ================================
+    ``kernel``     none, or raw arrays (serving,  ``x @ W`` (+ ``(x@a)@b·s``)
+                   evaluation, strips)
+    ``kernel``     ``lora.FactoredDelta`` (a      ``x @ W`` +
+                   training member)               ``lora.factored_lora_delta``
+    ``kernel_q8``  none / raw                     ``ops/quant.dequant_matmul``
+                                                  (+ the raw delta)
+    ``kernel_q8``  ``FactoredDelta``              ``ops/fused_qlora
+                                                  .fused_qlora_dense``: the Pallas
+                                                  kernel where platform and shape
+                                                  admit it, else the sum of the
+                                                  two rows above
+    ============== ============================== ================================
     """
     if "kernel" in p:
         y = x @ p["kernel"].astype(x.dtype)
+    elif lora is not None and fused_qlora_applies(lora):
+        # base and delta in one resolution: the delta is consumed here
+        y = fused_qlora_dense(x, p["kernel_q8"], lora, lora_scale)
+        lora = None
     else:
-        from ..ops.fused_qlora import fused_qlora_applies, fused_qlora_dense
-        from ..ops.quant_mm import dequant_matmul
-
-        qk = p["kernel_q8"]
-        if lora is not None and fused_qlora_applies(lora):
-            # unified int8-dequant + member-LoRA resolution (one kernel on
-            # TPU; the round-14 XLA composition elsewhere) — the LoRA
-            # delta is consumed here, not re-applied below
-            y = fused_qlora_dense(x, qk, lora, lora_scale)
-            lora = None
-        else:
-            # the shared dequant-matmul contract: the opt-in in-VMEM Pallas
-            # dequant kernel (HSES_BASE_QUANT_PALLAS=1 on TPU, 2D nodes) or
-            # XLA's operand-fused dequant everywhere else
-            y = dequant_matmul(x, qk)
+        y = dequant_matmul(x, p["kernel_q8"])
     if lora is not None:
-        from ..lora import FactoredDelta, fused_lora_delta
-
         if isinstance(lora["a"], FactoredDelta) or isinstance(lora["b"], FactoredDelta):
-            y = y + fused_lora_delta(x, lora, lora_scale)
+            y = y + factored_lora_delta(x, lora, lora_scale)
         else:
             a = lora["a"].astype(x.dtype)
             b = lora["b"].astype(x.dtype)
@@ -108,17 +109,8 @@ def dense(p: Params, x: jax.Array, lora: Optional[Params] = None, lora_scale: fl
     return y
 
 
-def kernel_shape(p: Params):
-    """Static shape of a node's kernel whether stored float or int8 — for
-    call sites that read geometry off the kernel (depthwise conv groups).
-    One definition, owned by the node format (ops/quant.py)."""
-    from ..ops.quant import kernel_shape as _kernel_shape
-
-    return _kernel_shape(p)
-
-
 def slice_stacked(p: Params, i) -> Params:
-    """Select layer ``i`` of a stacked-dense node (float or int8) inside scan."""
+    """Select layer ``i`` of a stacked node, dense or conv, float or int8."""
     out: Params = {}
     for k, v in p.items():
         if k == "kernel_q8":
@@ -181,10 +173,10 @@ def conv2d(
 ) -> jax.Array:
     """NHWC conv, kernel HWIO. Kernel may be float or int8-quantized
     (``kernel_q8``, see ops/quant.py). Matmul-equivalent int8 convs (1×1
-    stride-1 projections, non-overlapping p×p stride-p patch embeds) route
-    through the SAME dequant contract as ``dense``
-    (ops/fused_qlora.conv_kernel_q8_matmul → quant_mm.dequant_matmul);
-    everything else dequantizes at the use site as before. Optional
+    stride-1 projections, non-overlapping p×p stride-p patch embeds) lower as
+    ``dense``'s third row does (ops/fused_qlora.conv_kernel_q8_matmul →
+    ops/quant.dequant_matmul); everything else dequantizes at the use site
+    and keeps the conv. Optional
     PEFT-style conv LoRA: an r-channel conv (A) followed by a 1×1
     projection (B) — the Z-Image VAE-decoder adapter path (reference
     es_backend.py:599-629)."""
@@ -192,9 +184,6 @@ def conv2d(
         y = None
         w = p["kernel"].astype(x.dtype)
     else:
-        from ..ops.fused_qlora import conv_kernel_q8_matmul
-        from ..ops.quant import dequantize_kernel
-
         y = conv_kernel_q8_matmul(x, p["kernel_q8"], stride, padding, groups)
         if y is None:
             w = dequantize_kernel(p["kernel_q8"], x.dtype)
@@ -208,11 +197,9 @@ def conv2d(
             feature_group_count=groups,
         )
     if lora is not None and groups == 1:
-        from ..lora import FactoredDelta, matmul_factored
-
-        # conv-4D ``a`` factors carry dense ES noise (no factored form, so
-        # the fused path hands them over already materialized); the 2D
-        # ``b`` projection may be a FactoredDelta in the fused path.
+        # conv-4D ``a`` factors carry dense ES noise (no factored form, so a
+        # training member hands them over already materialized); its 2D
+        # ``b`` projection is a FactoredDelta.
         if isinstance(lora["b"], FactoredDelta):
             h = jax.lax.conv_general_dilated(
                 x, lora["a"].astype(x.dtype), window_strides=(stride, stride),

@@ -18,8 +18,8 @@ engine (and a days-long pod run) is operated from *live* endpoints instead:
 Stdlib-only (``http.server`` on a daemon thread), like the rest of the obs
 package: bench.py's jax-free parent and the serve engine both import it.
 The exporter is PULL-only and never touches the compiled graph — telemetry
-stays off the hot path (the all-knobs-off StableHLO golden is unaffected),
-and a scrape reads registry snapshots under their own locks.
+stays off the hot path, and a scrape reads registry snapshots under their
+own locks.
 
 Port discipline in pod mode: every host exports its own slice —
 ``obs.multihost.exporter_port`` offsets the base port by the process index,

@@ -69,26 +69,18 @@ Promotion discipline (this kernel is the *default* on TPU, not an opt-in):
   tiles that fit VMEM; ``HSES_FUSED_QLORA_PALLAS=0`` opts out. Nothing is
   probed and nothing is caught: a layer the gate selected and Mosaic refuses
   fails the enclosing compile.
-- elsewhere: :func:`xla_fused_qlora` is the EXACT pre-round-15 composition
-  (the separate dequant-matmul contract + the one-fused-operand LoRA
-  delta), so on every non-kernel platform the unified resolution lowers
-  the byte-identical program the round-14 ledger proved — CI diffs the
-  preflight ledgers and fails if that form ever moves more bytes.
+- elsewhere: :func:`xla_fused_qlora`, the sum of the two lowerings a site
+  takes when it has only an int8 base or only a member's factored adapter
+  (``ops/quant.dequant_matmul`` + ``lora.factored_lora_delta``): the program
+  of every platform without Mosaic, and of the layers ``_fit_blocks``
+  declines on a TPU.
 - parity: interpret-mode tests in tier-1 (tests/test_fused_qlora.py) on the
   CPU; on the chip ``tools/kernel_check.py`` compiles, runs and compares the
   kernel at the flagship call shapes (``chip_smoke.py`` runs that check).
 
-Routing (``HSES_FUSED_QLORA``): the *trace-time* knob that decides whether
-``kernel_q8`` consumers resolve through the unified contract at all.
-Default on; ``HSES_FUSED_QLORA=off`` restores the round-14 lowering
-(separate dequant + delta, conv sites dequant-then-conv) — the reference
-program the CI ledger gate diffs against. Distinct from the kernel flag
-above: routing shapes the XLA program, the kernel flag picks Mosaic vs XLA
-for a program already routed.
-
 Conv/patch-embed coverage: :func:`conv_kernel_q8_matmul` routes the
-matmul-equivalent ``kernel_q8`` convs through the same dequant contract as
-``dense`` (ops/quant_mm.dequant_matmul): 1×1 stride-1 convs (glumb_conv's
+matmul-equivalent ``kernel_q8`` convs through the same dequant-matmul as
+``dense`` (ops/quant.dequant_matmul): 1×1 stride-1 convs (glumb_conv's
 inverted/point projections) contract the channel axis directly, and
 non-overlapping p×p stride-p patch convs (CLIP/Sana patch_embed) go through
 an exact reshape-only im2col to a per-channel-flattened ``[p·p·cin, dout]``
@@ -104,9 +96,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..lora import FactoredDelta, factored_lora_delta
 from .pallas_gate import backend_is_tpu, env_requested
+from .quant import dequant_matmul
 
-ROUTING_ENV = "HSES_FUSED_QLORA"
 KERNEL_ENV = "HSES_FUSED_QLORA_PALLAS"
 
 # Per-layer VMEM working-set ceiling for electing the Pallas path. The grid
@@ -205,52 +198,28 @@ def _fit_blocks(q8, a, b, block_t: int, block_n: int, x_dtype) -> Optional[tuple
     return block_t, block_n
 
 
-def unified_routing_enabled() -> bool:
-    """Trace-time routing knob: ``HSES_FUSED_QLORA=off`` (or ``0``) restores
-    the round-14 composition — separate dequant matmul + LoRA delta, conv
-    sites dequant-then-conv — which is the CI ledger gate's reference
-    program. Anything else (the default) routes ``kernel_q8`` consumers
-    through the unified contract."""
-    return env_requested(ROUTING_ENV) is not False
-
-
 def use_fused_qlora_pallas() -> bool:
-    """The unified kernel's gate — ON BY DEFAULT on a TPU backend (this is
-    the promoted kernel; the separate opt-in kernels it unifies stay behind
-    their own flags for A/B). ``HSES_FUSED_QLORA_PALLAS=0`` opts out."""
+    """The kernel's gate — ON BY DEFAULT on a TPU backend;
+    ``HSES_FUSED_QLORA_PALLAS=0`` opts out."""
     return env_requested(KERNEL_ENV) is not False and backend_is_tpu()
 
 
 def fused_qlora_applies(leaf: Dict[str, Any]) -> bool:
-    """True when the lora leaf at an int8 dense site should resolve through
-    :func:`fused_qlora_dense`: routing on, and the leaf carries the fused
-    hot path's factored perturbations (both factors ``lora.FactoredDelta``).
-    Base-node shape details (stacked nodes are sliced to 2D before
-    ``dense``; GGUF block scales; the VMEM budget) are the resolver's own
-    business — its XLA composition handles every layout the old one
-    handled."""
-    from ..lora import FactoredDelta
-
-    return (
-        unified_routing_enabled()
-        and isinstance(leaf.get("a"), FactoredDelta)
-        and isinstance(leaf.get("b"), FactoredDelta)
-    )
+    """True when the lora leaf at an int8 dense site resolves through
+    :func:`fused_qlora_dense`: it is a training member's, both factors
+    ``lora.FactoredDelta``. Base-node shape details (stacked nodes are sliced
+    to 2D before ``dense``; GGUF block scales; the VMEM budget) are the
+    resolver's own business — its XLA composition handles every layout."""
+    return isinstance(leaf.get("a"), FactoredDelta) and isinstance(leaf.get("b"), FactoredDelta)
 
 
 def xla_fused_qlora(
     x: jax.Array, qk: Dict[str, jax.Array], leaf: Dict[str, Any], lora_scale
 ) -> jax.Array:
-    """The XLA composition — EXACTLY what ``nn.dense`` lowered before the
-    unified kernel existed: the shared dequant-matmul contract (which itself
-    resolves the opt-in int8 Pallas kernel or the XLA operand fusion) plus
-    the one-fused-operand LoRA delta. Byte-for-byte the round-14 program, so
-    promoting the unified resolution can never regress a non-kernel
-    platform (the CI ledger gate holds this line)."""
-    from ..lora import fused_lora_delta
-    from .quant_mm import dequant_matmul
-
-    return dequant_matmul(x, qk) + fused_lora_delta(x, leaf, lora_scale)
+    """The XLA composition: the dequantized base dot (XLA's operand fusion
+    keeps the dequant in the dot's read) plus the member's two-dot LoRA
+    delta — the kernel's reference in tests and ``tools/kernel_check``."""
+    return dequant_matmul(x, qk) + factored_lora_delta(x, leaf, lora_scale)
 
 
 def _dot(p, q, precision=jax.lax.Precision.HIGHEST):
@@ -264,7 +233,7 @@ def _base_plus_thin(x_ref, q_ref, s_ref, b_ref, z_ref, o_ref, lora_scale: float)
     """What every (token block, dout tile) step does: the base dot plus
     ``z @ [b.w ; b.vᵀ]`` (= xa@b_k), the one thin dot a tile.
 
-    The base term is the ops/quant_mm contract: the tile is dequantized in
+    The base term is ``ops/quant.dequant_matmul``'s: the tile is dequantized in
     VMEM (convert + per-channel scale in f32) and handed to the MXU in x's
     dtype. For bf16 activations that is bit for bit what an f32 dot at
     default precision did with the same tile — the MXU rounds f32 operands
@@ -299,8 +268,8 @@ def _qlora_kernel(
 
     Every dout step then adds ``z @ [b.w ; b.vᵀ]`` (= xa@b_k, K = r_l + r_e)
     to the base dot (:func:`_base_plus_thin`). The chain is f32 at
-    ``Precision.HIGHEST`` throughout (the parity pin is against the
-    materialized path's full-precision ε). The base dot takes ``x`` as it
+    ``Precision.HIGHEST`` throughout (the parity pin is against
+    ``es.perturb_member``'s full-precision ε). The base dot takes ``x`` as it
     arrives and the s8 tile dequantized to ``x``'s dtype; f32 accumulation."""
     from jax.experimental import pallas as pl
 
@@ -516,8 +485,6 @@ def _members_into_rows(lora_scale, block_t: int, block_n: int, interpret: bool):
     whose token blocks hold :func:`_members_per_block` whole members. Where
     that is 1, or the base or a ``w`` is per member, it is the default
     batching of the one-member call: the parent's program."""
-    from ..lora import FactoredDelta
-
     def one_member(x2, q8, scale, a, b):
         return _pallas_fused_qlora(x2, q8, scale, a, b, lora_scale, block_t, block_n, interpret)
 
@@ -573,8 +540,8 @@ def fused_qlora_dense(
     ``x`` may have any leading shape (``[..., din]``). The Pallas kernel
     handles 2D per-output-channel nodes with both factors factored whose
     tiles fit VMEM (:func:`_fit_blocks`); every other layout (GGUF block
-    scales, mixed leaf types) and every non-kernel platform takes
-    :func:`xla_fused_qlora` — the byte-identical round-14 composition.
+    scales, stacked nodes, mixed leaf types) and every non-kernel platform takes
+    :func:`xla_fused_qlora`.
     ``use_pallas=None`` auto-selects via :func:`use_fused_qlora_pallas`.
     The selection is final: a selected kernel that fails to trace or
     compile raises. ``interpret`` is for tests only. Under ``vmap`` the
@@ -586,10 +553,7 @@ def fused_qlora_dense(
     ~0.5% rel): the XLA form rounds the perturbed operands ``a_k``/``b_k``
     to the serving dtype before its dots (``lora.effective_factor``'s
     contract), while the kernel keeps the whole chain in f32 — the kernel
-    is the more precise side, the same boundary the round-12
-    fused-vs-materialized θ parity documents for bf16 configs."""
-    from ..lora import FactoredDelta
-
+    is the more precise side."""
     if use_pallas is None:
         use_pallas = use_fused_qlora_pallas()
     a, b = leaf["a"], leaf["b"]
@@ -616,9 +580,9 @@ def conv_kernel_q8_matmul(
     padding: str,
     groups: int,
 ) -> Optional[jax.Array]:
-    """Route a matmul-equivalent ``kernel_q8`` conv through the SAME dequant
-    contract as ``dense`` (ops/quant_mm.dequant_matmul) — None when the conv
-    is not matmul-equivalent (the caller keeps dequant-then-conv).
+    """Route a matmul-equivalent ``kernel_q8`` conv through the same
+    dequant-matmul as ``dense`` (ops/quant.dequant_matmul) — None when the
+    conv is not matmul-equivalent (the caller keeps dequant-then-conv).
 
     Two exact rewrites, both value-identical to the conv up to float
     summation order:
@@ -634,10 +598,8 @@ def conv_kernel_q8_matmul(
       flattening the reduction axes.
 
     Grouped/depthwise convs, overlapping windows, explicit padding configs,
-    and GGUF-style block scales all return None. Routing off
-    (``HSES_FUSED_QLORA=off``) returns None everywhere — the round-14
-    lowering."""
-    if not unified_routing_enabled() or groups != 1:
+    and GGUF-style block scales all return None."""
+    if groups != 1:
         return None
     if not isinstance(padding, str) or padding.upper() not in ("SAME", "VALID"):
         return None
@@ -646,8 +608,6 @@ def conv_kernel_q8_matmul(
         return None
     kh, kw, cin, cout = q8.shape
     flat_scale = scale.reshape(1, cout)
-    from .quant_mm import dequant_matmul
-
     if kh == 1 and kw == 1 and stride == 1:
         return dequant_matmul(x, {"q8": q8.reshape(cin, cout), "scale": flat_scale})
     B, H, W, C = x.shape

@@ -3,9 +3,9 @@
 A kernel is selected from what the code can observe — the backend
 (:func:`backend_is_tpu`) and the call's shapes (each kernel's own fit check)
 — plus one tri-state environment flag per kernel (:func:`env_requested`):
-``"0"``/``"off"`` opts a default-ON kernel out, ``"1"`` opts an opt-in kernel
-in. Nothing is probed and nothing falls back: a kernel its gate selected and
-Mosaic refuses raises at the enclosing compile, on every path. Whether each
+``"0"``/``"off"`` opts a default-ON kernel out. Nothing is probed and nothing
+falls back: a kernel its gate selected and Mosaic refuses raises at the
+enclosing compile, on every path. Whether each
 kernel compiles, runs and agrees with its XLA composition on a chip is
 established by ``tools/kernel_check.py`` (run by ``chip_smoke.py`` for the
 default-ON gates), not guessed at trace time.
@@ -18,9 +18,8 @@ default-ON gates), not guessed at trace time.
   into bench/dispatch_tax artifacts and ledger geometry so a measurement
   always says which kernels were requested when it was taken.
 
-The per-kernel gate *policies* stay in their own modules (opt-in vs
-on-by-default-on-TPU differs per kernel and is part of each kernel's
-documented contract); only the env/backend mechanics live here. Stdlib-only
+The per-kernel gates stay in their own modules (both kernels are on by
+default on a TPU); only the env/backend mechanics live here. Stdlib-only
 at import (jax-free processes render the flag marks).
 """
 
@@ -33,8 +32,6 @@ from typing import Dict, Optional
 # (tools/bench_report.py trend knob markers, tools/dispatch_tax.py stamp).
 PALLAS_ENV_FLAGS = {
     "HSES_USE_PALLAS": "flash",
-    "HSES_POP_FUSE_PALLAS": "lora",
-    "HSES_BASE_QUANT_PALLAS": "q8mm",
     "HSES_FUSED_QLORA_PALLAS": "qlora",
 }
 
@@ -62,15 +59,11 @@ def selected_kernels() -> Dict[str, bool]:
     """Every kernel gate's verdict on this backend under this environment,
     keyed by the kernel's ``pallas_call`` name."""
     from .attention import should_use_pallas
-    from .fused_lora import use_fused_pallas
     from .fused_qlora import use_fused_qlora_pallas
-    from .quant_mm import use_base_quant_pallas
 
     return {
         "fused_qlora": use_fused_qlora_pallas(),
         "decode_attention": should_use_pallas(),
-        "member_lora_delta": use_fused_pallas(),
-        "int8_matmul": use_base_quant_pallas(),
     }
 
 

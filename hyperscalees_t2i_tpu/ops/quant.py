@@ -90,6 +90,14 @@ def dequantize_kernel(qk: Dict[str, jax.Array], dtype=jnp.bfloat16) -> jax.Array
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
+def dequant_matmul(x: jax.Array, qk: Dict[str, jax.Array]) -> jax.Array:
+    """``x @ dequant(qk)`` — what every 2D ``kernel_q8`` consumer without a
+    member's factored adapter lowers (``nn.dense``, the matmul-equivalent
+    convs, the base half of ``ops/fused_qlora``'s composition): the dequant is
+    left to XLA's operand fusion, so only the s8 bytes move through HBM."""
+    return x @ dequantize_kernel(qk, x.dtype)
+
+
 def kernel_shape(p: Params) -> Tuple[int, ...]:
     """Static shape of a node's kernel, float or int8-quantized — for call
     sites that read geometry off the kernel (e.g. depthwise conv groups)."""
@@ -140,9 +148,8 @@ def maybe_quantize_tree(
 ) -> Params:
     """Apply the ``--base_quant`` knob to one frozen param tree.
 
-    ``off`` returns the tree UNTOUCHED (same object — the all-knobs-off
-    program stays bit-identical); ``int8`` rewrites every kernel node at or
-    above the min-size floor. The single entry point bench/preflight/trainer
+    ``off`` returns the tree UNTOUCHED (same object); ``int8`` rewrites every
+    kernel node at or above the min-size floor. The single entry point bench/preflight/trainer
     share, so "quantized base" means the same thing at every site."""
     if base_quant in (None, "", "off", False):
         return tree

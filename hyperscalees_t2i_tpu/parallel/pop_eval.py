@@ -42,7 +42,6 @@ from ..es import (
     factored_member_theta,
     lane_slice,
     member_maps,
-    perturb_member,
     stacked_adapter_theta,
 )
 from ..obs import get_registry, note_program_geometry, span as obs_span
@@ -76,16 +75,6 @@ def _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, ge
     return rewards
 
 
-def _fused_qlora_routing() -> bool:
-    """Trace-time resolution of the unified int8+LoRA routing knob
-    (ops/fused_qlora.py), stamped into every program's ledger geometry so a
-    ledger row always says which ``kernel_q8`` composition produced it —
-    the round-15 diff column is keyed on this."""
-    from ..ops.fused_qlora import unified_routing_enabled
-
-    return unified_routing_enabled()
-
-
 def effective_reward_tile(batch: int, reward_tile: int) -> int:
     """Largest divisor of ``batch`` that is ≤ ``reward_tile`` (0 = untiled).
 
@@ -117,6 +106,24 @@ def _note_effective_tile(batch: int, reward_tile: int) -> int:
             file=sys.stderr, flush=True,
         )
     return eff
+
+
+def _eval_member(generate_p, reward_apply, reward_tile, frozen, theta_k, flat_ids, item_index, gen_key):
+    """One member's rows for its whole image batch — in one piece, or through
+    ``lax.map`` over sub-batches of ``reward_tile`` images (rounded down to a
+    divisor of the batch, :func:`effective_reward_tile`)."""
+    B = flat_ids.shape[0]
+    tile = effective_reward_tile(B, reward_tile)
+    if tile == 0:
+        return _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index)
+    n_tiles = B // tile
+    tiled = jax.lax.map(
+        lambda args: _generate_and_reward(
+            generate_p, reward_apply, frozen, theta_k, args[0], gen_key, args[1]
+        ),
+        (flat_ids.reshape(n_tiles, tile), item_index.reshape(n_tiles, tile)),
+    )  # dict of [n_tiles, tile]
+    return jax.tree_util.tree_map(lambda a: a.reshape(B, *a.shape[2:]), tiled)
 
 
 def make_adapter_batch_generator(
@@ -159,7 +166,6 @@ def make_adapter_batch_generator(
         note_program_geometry(
             adapter_batch=A, images_per_request=B,
             member_batch=member_batch,
-            fused_qlora=_fused_qlora_routing(),
         )
         with obs_span("trace/serve_batch", adapter_batch=A, images=B):
             item_index = jnp.arange(B)
@@ -184,7 +190,6 @@ def make_fleet_evaluator(
     es_cfg: EggRollConfig,
     member_batch: int,
     reward_tile: int = 0,
-    pop_fuse: bool = False,
 ) -> Callable[..., Dict[str, jax.Array]]:
     """Build the *fleet* evaluator: ``eval_fleet(frozen, stacked_theta,
     stacked_noise, flat_ids [W, B], gen_keys [W, ...], sigmas [W],
@@ -225,30 +230,12 @@ def make_fleet_evaluator(
         )
     n_lanes = W * pop_size
 
-    def run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key):
-        return _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index)
-
-    def eval_theta(frozen, theta_k, flat_ids, item_index, gen_key):
-        B = flat_ids.shape[0]
-        tile = effective_reward_tile(B, reward_tile)
-        if tile == 0:
-            return run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key)
-        n_tiles = B // tile
-        tiled = jax.lax.map(
-            lambda args: run_image_batch(frozen, theta_k, args[0], args[1], gen_key),
-            (flat_ids.reshape(n_tiles, tile), item_index.reshape(n_tiles, tile)),
-        )
-        return jax.tree_util.tree_map(
-            lambda a: a.reshape(B, *a.shape[2:]), tiled
-        )
-
     def eval_fleet(frozen, stacked_theta, stacked_noise, flat_ids, gen_keys,
                    sigmas, c_scales):
         get_registry().inc("fleet_traces")
         note_program_geometry(
             fleet_width=W, pop=pop_size, member_batch=member_batch,
-            n_pop=1, n_data=1, reward_tile=reward_tile, pop_fuse=pop_fuse,
-            fused_qlora=_fused_qlora_routing(),
+            n_pop=1, n_data=1, reward_tile=reward_tile,
             reward_tile_effective=_note_effective_tile(
                 flat_ids.shape[1], reward_tile
             ),
@@ -259,23 +246,21 @@ def make_fleet_evaluator(
         ):
             B = flat_ids.shape[1]
             item_index = jnp.arange(B)
-            maps = member_maps(pop_size, es_cfg.antithetic) if pop_fuse else None
+            maps = member_maps(pop_size, es_cfg.antithetic)
 
             def eval_lane(i):
                 j = i // pop_size
                 k = i % pop_size
                 theta_j = lane_slice(stacked_theta, j, what="job-stacked adapter")
                 noise_j = lane_slice(stacked_noise, j, what="job-stacked noise")
-                if pop_fuse:
-                    theta_k = factored_member_theta(
-                        theta_j, noise_j, k, pop_size, es_cfg, maps,
-                        sigma=sigmas[j], c_scale=c_scales[j],
-                    )
-                else:
-                    theta_k = perturb_member(
-                        theta_j, noise_j, k, pop_size, es_cfg, sigma=sigmas[j]
-                    )
-                return eval_theta(frozen, theta_k, flat_ids[j], item_index, gen_keys[j])
+                theta_k = factored_member_theta(
+                    theta_j, noise_j, k, pop_size, es_cfg, maps,
+                    sigma=sigmas[j], c_scale=c_scales[j],
+                )
+                return _eval_member(
+                    generate_p, reward_apply, reward_tile,
+                    frozen, theta_k, flat_ids[j], item_index, gen_keys[j],
+                )
 
             flat = jax.lax.map(
                 eval_lane, jnp.arange(n_lanes),
@@ -297,7 +282,6 @@ def make_population_evaluator(
     mesh: Optional[Mesh] = None,
     reward_tile: int = 0,
     host_slice: Optional[Tuple[int, int]] = None,
-    pop_fuse: bool = False,
 ) -> Callable[[Pytree, Pytree, Pytree, jax.Array, jax.Array], Dict[str, jax.Array]]:
     """Build ``eval_pop(frozen, theta, noise, flat_ids, gen_key) → rewards``
     where ``frozen = {"gen": ..., "reward": ...}`` and each reward leaf is
@@ -325,51 +309,30 @@ def make_population_evaluator(
     untiled program: per-image generation keys fold the *global* item_index
     (the chunk-invariance contract) and every reward row is per-image.
 
-    ``pop_fuse`` switches member perturbation to the *fused factored* path
-    (PERF.md round 12): member ``k``'s adapter is handed to the forward as
-    ``lora.FactoredDelta`` leaves — the dense ``σ·s·U_bV_bᵀ/√r`` products are
-    never materialized; every adapted dense applies the delta as chained
-    thin contractions (f32 accumulation over the bf16 noise store), and the
-    sign/base lookup tables are built once per trace and threaded through
-    the member loop instead of rebuilt per member. Same member-batched
-    ``lax.map`` dispatch structure, strictly fewer bytes through HBM; θ
-    parity with the materialized path is float-rounding-tight, not bitwise
-    (contraction order changes — tests/test_fused.py pins the tolerance).
-    ``pop_fuse=False`` lowers the byte-identical pre-round-12 program.
+    Member ``k``'s adapter reaches the forward as ``lora.FactoredDelta``
+    leaves (``es.factored_member_theta``): the dense ``σ·s·U_bV_bᵀ/√r``
+    products are never materialized, every adapted dense builds its perturbed
+    factor at the point of use (f32 accumulation over the bf16 noise store;
+    ``models/nn.dense`` has the table of lowerings), and the sign/base lookup
+    tables are built once per trace and threaded through the member loop.
+    ``es.perturb_member`` is the plain reference this is held to, within
+    float rounding (tests/test_fused.py).
     """
 
-    def run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key):
-        return _generate_and_reward(generate_p, reward_apply, frozen, theta_k, flat_ids, gen_key, item_index)
-
-    def eval_theta(frozen, theta_k, flat_ids, item_index, gen_key):
-        B = flat_ids.shape[0]
-        tile = effective_reward_tile(B, reward_tile)
-        if tile == 0:
-            return run_image_batch(frozen, theta_k, flat_ids, item_index, gen_key)
-        n_tiles = B // tile
-        tiled = jax.lax.map(
-            lambda args: run_image_batch(frozen, theta_k, args[0], args[1], gen_key),
-            (flat_ids.reshape(n_tiles, tile), item_index.reshape(n_tiles, tile)),
-        )  # dict of [n_tiles, tile]
-        return jax.tree_util.tree_map(
-            lambda a: a.reshape(B, *a.shape[2:]), tiled
-        )
-
-    def eval_one(frozen, theta, noise, flat_ids, item_index, gen_key, k, maps=None):
+    def eval_one(frozen, theta, noise, flat_ids, item_index, gen_key, k, maps):
         # device-time scope (obs/xla_cost.INNER_SCOPES): a name only
         with jax.named_scope("es_noise"), jax.named_scope("perturb"):
-            if pop_fuse:
-                theta_k = factored_member_theta(theta, noise, k, pop_size, es_cfg, maps)
-            else:
-                theta_k = perturb_member(theta, noise, k, pop_size, es_cfg)
-        return eval_theta(frozen, theta_k, flat_ids, item_index, gen_key)
+            theta_k = factored_member_theta(theta, noise, k, pop_size, es_cfg, maps)
+        return _eval_member(
+            generate_p, reward_apply, reward_tile,
+            frozen, theta_k, flat_ids, item_index, gen_key,
+        )
 
     def make_maps():
-        # fused path only: device-side (signs, bases) built ONCE per trace
-        # and threaded into every member lane (the materialized path keeps
-        # its in-body construction so its HLO stays byte-identical)
+        # device-side (signs, bases) built ONCE per trace and threaded into
+        # every member lane
         with jax.named_scope("es_noise"), jax.named_scope("perturb"):
-            return member_maps(pop_size, es_cfg.antithetic) if pop_fuse else None
+            return member_maps(pop_size, es_cfg.antithetic)
 
     # iteration domain: the whole population, or this host's member slice
     slice_lo, slice_n = host_slice if host_slice is not None else (0, pop_size)
@@ -407,9 +370,7 @@ def make_population_evaluator(
             note_program_geometry(
                 pop=pop_size, member_batch=member_batch, n_pop=1, n_data=1,
                 reward_tile=reward_tile, host_slice=host_slice,
-                pop_fuse=pop_fuse,
-                fused_qlora=_fused_qlora_routing(),
-                reward_tile_effective=_note_effective_tile(
+                    reward_tile_effective=_note_effective_tile(
                     flat_ids.shape[0], reward_tile
                 ),
             )
@@ -460,8 +421,6 @@ def make_population_evaluator(
         note_program_geometry(
             pop=pop_size, member_batch=member_batch, n_pop=n_pop, n_data=n_data,
             reward_tile=reward_tile, host_slice=host_slice,
-            pop_fuse=pop_fuse,
-            fused_qlora=_fused_qlora_routing(),
             reward_tile_effective=_note_effective_tile(
                 _ceil_to(flat_ids.shape[0], n_data) // n_data, reward_tile
             ),

@@ -20,7 +20,7 @@ already cross ICI (``pop_eval.py``).
 Parity is rounding-tight, not bitwise: the psum changes f32 summation order
 (tests/test_pop_shard.py pins the tolerance). The replicated path stays the
 bit-for-bit parity anchor (``--pop_shard_update off`` and every mesh-less
-program lower the pre-PR text — the all-knobs-off StableHLO golden).
+program lower it).
 """
 
 from __future__ import annotations

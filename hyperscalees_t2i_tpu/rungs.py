@@ -144,36 +144,26 @@ PROMPT_TOKEN_LEN = 8  # Ltok
 # member-interior reward tiling (decode→CLIP through lax.map over image
 # sub-batches), the factored-noise store dtype, and the reward towers'
 # serving compute dtype. The small rungs keep everything off — they fit
-# trivially and stay byte-identical parity anchors; the big-decode rungs
-# ship with the layer ON (that default is what the CI preflight gate
-# verifies fits a v5e; the all-off override reproduces the pre-layer
-# program, f32 towers included). bench and preflight read THIS one table so
-# the analyzed geometry is the timed geometry; the trainer takes the same
+# trivially; the big-decode rungs ship with the layer ON (that default is
+# what the CI preflight gate verifies fits a v5e). bench and preflight read
+# THIS one table so the analyzed geometry is the timed geometry; the trainer takes the same
 # knobs as CLI flags (all-off defaults for bit-compat with older runs) — a
 # flagship training launch on a 16 GB chip must pass the RUNG_OPT values
 # explicitly (README "Memory & bandwidth knobs").
 DEFAULT_OPT = {
     "remat": "none", "reward_tile": 0,
     "noise_dtype": "float32", "tower_dtype": "float32",
-    "pop_fuse": False, "base_quant": "off",
-    # bench/preflight/pin programs measure the PURE ES step: the in-graph
+    "base_quant": "off",
+    # bench/preflight programs measure the PURE ES step: the in-graph
     # quality attribution (obs/quality.py, trainer default ON) is excluded
-    # here so the all-off StableHLO golden and every cost ledger stay
-    # byte-comparable across rounds — its own cost is priced separately
-    # (PERF.md round 22: +0.0033% FLOPs).
+    # here so every cost ledger stays comparable across rounds — its own
+    # cost is priced separately (PERF.md round 22: +0.0033% FLOPs).
     "quality": False,
 }
 _BIG_OPT = {
     "remat": "blocks", "noise_dtype": "bfloat16", "tower_dtype": "bfloat16",
     "base_quant": "int8",
 }
-# pop_fuse (PERF.md round 12): the fused factored member path ships ON for
-# the population-heavy and big-decode rungs — ledger-verified bytes-moved
-# reduction at identical FLOPs (popscale 6.63→6.62, flagship 73.99→73.91
-# GB/step: the per-member θ_k staging + f32→bf16 re-cast buffers are gone),
-# never a regression. tiny/small stay off: they are the byte-identical
-# parity anchors (the all-off override must reproduce the pre-round-12
-# program bit-for-bit).
 # base_quant (PERF.md round 14): the frozen base (DiT + DC-AE decoder +
 # CLIP reward towers) stored per-output-channel int8 in HBM, dequantized at
 # each use site (ops/quant.py) — the base is re-read per member, so the
@@ -184,13 +174,13 @@ _BIG_OPT = {
 RUNG_OPT = {
     "tiny": dict(DEFAULT_OPT),
     "small": dict(DEFAULT_OPT),
-    "popscale": {**DEFAULT_OPT, "pop_fuse": True, "base_quant": "int8"},
+    "popscale": {**DEFAULT_OPT, "base_quant": "int8"},
     "ar": dict(DEFAULT_OPT),
-    "mid": {**_BIG_OPT, "reward_tile": 2, "pop_fuse": True},
-    "midpop": {**_BIG_OPT, "reward_tile": 2, "pop_fuse": True},
-    "flagship": {**_BIG_OPT, "reward_tile": 1, "pop_fuse": True},
-    "flagpop": {**_BIG_OPT, "reward_tile": 1, "pop_fuse": True},
-    "flaggen": {**_BIG_OPT, "reward_tile": 0, "pop_fuse": True},
+    "mid": {**_BIG_OPT, "reward_tile": 2},
+    "midpop": {**_BIG_OPT, "reward_tile": 2},
+    "flagship": {**_BIG_OPT, "reward_tile": 1},
+    "flagpop": {**_BIG_OPT, "reward_tile": 1},
+    "flaggen": {**_BIG_OPT, "reward_tile": 0},
 }
 
 
@@ -201,22 +191,16 @@ def rung_opt(rung: str) -> Dict[str, Any]:
 
 def kernel_marks(d: Dict[str, Any]) -> list:
     """Comparability markers of a geometry / rung-record dict — the fields
-    that decide whether two measurements compare at all: the fused member
-    path (``fuse``), the int8 base (``q8``), unified int8+LoRA routing
-    explicitly OFF (``uq-`` — the ledger-diff reference programs; the
-    on-default is unmarked so r14-era rows read unchanged), and the Pallas
-    kernel env flags active at measurement time (``P:...``, short names per
-    ops/pallas_gate.PALLAS_ENV_FLAGS). THE one derivation —
+    that decide whether two measurements compare at all: the int8 base
+    (``q8``) and the Pallas kernel env flags active at measurement time
+    (``P:...``, short names per ops/pallas_gate.PALLAS_ENV_FLAGS). THE one
+    derivation —
     :func:`knobs_str` (preflight/ledger rows) and ``bench_report``'s trend
     cells both render from it, so a knob added here shows up everywhere.
     Schema-additive: absent keys render nothing."""
     marks = []
-    if d.get("pop_fuse"):
-        marks.append("fuse")
     if d.get("base_quant") == "int8":
         marks.append("q8")
-    if d.get("fused_qlora") is False:
-        marks.append("uq-")
     if d.get("pallas_env"):
         from .ops.pallas_gate import pallas_flag_marks
 
@@ -229,7 +213,7 @@ def kernel_marks(d: Dict[str, Any]) -> list:
 def knobs_str(d: Dict[str, Any]) -> str:
     """Compact one-token summary of the optimization knobs in a geometry /
     rung-record dict — ``remat/tN/n-dt/w-dt`` plus the
-    :func:`kernel_marks` suffix (``[/fuse][/q8][/uq-][/P:...]``). The ONE
+    :func:`kernel_marks` suffix (``[/q8][/P:...]``). The ONE
     definition both the preflight report and ``bench_report`` render, so
     ledger rows and bench rows always read the same (stdlib-only, like the
     rest of this module)."""

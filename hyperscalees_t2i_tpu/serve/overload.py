@@ -5,8 +5,7 @@ PR 16's capacity harness *measured* what serving does past the knee
 refusals come from a hard FIFO bound, and ~240 queued requests reach
 dispatch after their adapter was already evicted (the "admit-then-thrash"
 hazard). This module is the control layer that turns that cliff into a
-slope — four host-side mechanisms, none of which touch a compiled program
-(the all-knobs-off StableHLO golden is untouched by design):
+slope — four host-side mechanisms, none of which touch a compiled program:
 
 - **Request deadlines + doomed-work shedding.** Every request may carry an
   absolute deadline (``ServeRequest.t_deadline``). A request whose deadline

@@ -84,7 +84,7 @@ def _knobs(r: Dict) -> str:
 
 def _trend_marks(rec: Dict) -> str:
     """Kernel/knob markers for a rung's trend cell — the shared
-    ``rungs.kernel_marks`` derivation (fuse/q8/uq-/P:...), the fields that
+    ``rungs.kernel_marks`` derivation (q8/P:...), the fields that
     decide whether two artifacts' throughputs are comparable at all.
     Before round 15 only ``(q8)`` was marked, so a kernel-on and a
     kernel-off artifact rendered identically. Schema-additive: absent
@@ -389,7 +389,7 @@ def render_trend(paths: List[str]) -> str:
                 _fmt(doc.get("platform")),
                 _fmt(doc.get("value")),
             ] + [
-                # schema-additive comparability markers (fuse/q8/uq-/P:...):
+                # schema-additive comparability markers (q8/P:...):
                 # a kernel-on or int8-base rung's throughput only compares
                 # to rows with the same marks (_trend_marks)
                 _fmt(rungs.get(r, {}).get("imgs_per_sec"))

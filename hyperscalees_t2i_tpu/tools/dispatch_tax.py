@@ -1,9 +1,9 @@
-"""Dispatch-tax microbench: single-dispatch vs chained vs fused-member.
+"""Dispatch-tax microbench: single-dispatch vs chained vs fleet.
 
-Times three variants of ONE rung's ES epoch step and emits a single JSON
-row, so the per-step *dispatch overhead* (host→device round-trip + program
-launch) and the fused-member path's effect on it are measured numbers in
-the bench trend, not inferences from two different artifacts::
+Times variants of ONE rung's ES epoch step and emits a single JSON row, so
+the per-step *dispatch overhead* (host→device round-trip + program launch)
+is a measured number in the bench trend, not an inference from two
+different artifacts::
 
     python -m hyperscalees_t2i_tpu.tools.dispatch_tax                 # tiny
     python -m hyperscalees_t2i_tpu.tools.dispatch_tax --rung small \\
@@ -16,23 +16,17 @@ Variants (same geometry, same weights, same keys):
   program; per-step time isolates everything that is NOT per-dispatch
   overhead. ``dispatch_tax_s = single − chained`` (per step) is the number
   bench r05 showed is worth 7–12% at small geometry.
-- ``fused``   — one dispatch per step with ``pop_fuse=True`` (the factored
-  member path, PERF.md round 12): measures what the contraction-structure
-  change does to the same dispatch cadence.
-- ``fused_qlora`` — one dispatch per step with ``pop_fuse=True`` AND an
-  int8 base (min-size floor dropped so small rungs quantize), resolved
-  through the unified int8-dequant+LoRA contract (ops/fused_qlora.py,
-  round 15) — on CPU this times the kernel's XLA-fallback form, the
-  composition the ledger gate holds byte-equal to the round-14 program.
+- ``fused_qlora`` — one dispatch per step over an int8 base (min-size
+  floor dropped so small rungs quantize): every adapted dense resolves
+  through ops/fused_qlora.py — on CPU this times its XLA composition.
 - ``fleet2`` — J=2 jobs advanced by ONE dispatched (job, member)-batched
   fleet step (``make_fleet_step``, ISSUE 20) vs the same two jobs stepped
-  sequentially through the fused solo program: one launch + one sync for
+  sequentially through the solo program: one launch + one sync for
   J jobs is the dispatch-side half of fleet amortization
   (``fleet2_amortization`` = sequential/fused per-round time).
 
-Each row also stamps the active Pallas kernel env flags (``pallas_env``)
-and the unified-routing state (``fused_qlora``), so kernel-on and
-kernel-off rows are distinguishable in the trend.
+Each row also stamps the active Pallas kernel env flags (``pallas_env``),
+so kernel-on and kernel-off rows are distinguishable in the trend.
 
 Timing honesty follows bench.py: every timed window ends in a
 ``jax.device_get`` of a scalar that data-depends on all timed steps (θ is
@@ -118,18 +112,16 @@ def run(rung: str, steps: int, chain: int) -> dict:
 
     theta = fresh_theta()
 
-    def make(pop_fuse: bool):
-        tc = TrainConfig(
-            pop_size=pop, sigma=0.01, egg_rank=4, prompts_per_gen=num_unique,
-            batches_per_gen=1, member_batch=member_batch, promptnorm=True,
-            remat=opt["remat"], reward_tile=opt["reward_tile"],
-            noise_dtype=opt["noise_dtype"], pop_fuse=pop_fuse,
-            base_quant=opt.get("base_quant", "off"),
-            quality=opt.get("quality", False),
-        )
-        step = make_es_step(backend, reward_fn, tc, num_unique, 1, None)
-        lowered = step.lower(frozen, theta, flat_ids, jax.random.PRNGKey(2))
-        return step, lowered.compile()
+    tc = TrainConfig(
+        pop_size=pop, sigma=0.01, egg_rank=4, prompts_per_gen=num_unique,
+        batches_per_gen=1, member_batch=member_batch, promptnorm=True,
+        remat=opt["remat"], reward_tile=opt["reward_tile"],
+        noise_dtype=opt["noise_dtype"],
+        base_quant=opt.get("base_quant", "off"),
+        quality=opt.get("quality", False),
+    )
+    step = make_es_step(backend, reward_fn, tc, num_unique, 1, None)
+    compiled = step.lower(frozen, theta, flat_ids, jax.random.PRNGKey(2)).compile()
 
     rec: dict = {
         "metric": "dispatch_tax", "rung": rung, "pop": pop,
@@ -141,12 +133,11 @@ def run(rung: str, steps: int, chain: int) -> dict:
         "sync": "device_get",
     }
 
-    # -- single dispatch per step (materialized member path) ---------------
-    step_m, compiled_m = make(pop_fuse=False)
-    th, metrics, _ = compiled_m(frozen, fresh_theta(), flat_ids, jax.random.PRNGKey(2))
+    # -- single dispatch per step ------------------------------------------
+    th, metrics, _ = compiled(frozen, fresh_theta(), flat_ids, jax.random.PRNGKey(2))
     float(jax.device_get(metrics["opt_score_mean"]))  # warmup, exec-synced
     rec["step_time_single_s"] = round(
-        _timed_steps(compiled_m, frozen, th, flat_ids, steps), 6
+        _timed_steps(compiled, frozen, th, flat_ids, steps), 6
     )
 
     # -- chained: `chain` steps per dispatched program ---------------------
@@ -156,7 +147,7 @@ def run(rung: str, steps: int, chain: int) -> dict:
         def multi(fz, th_, ids, k):
             def body(e, carry):
                 th2, _ = carry
-                th3, mm, _ = step_m(fz, th2, ids, jax.random.fold_in(k, e))
+                th3, mm, _ = step(fz, th2, ids, jax.random.fold_in(k, e))
                 return (th3, mm)
 
             return jax.lax.fori_loop(0, chain, body, (th_, m0))
@@ -172,19 +163,8 @@ def run(rung: str, steps: int, chain: int) -> dict:
             rec["step_time_single_s"] - rec["step_time_chained_s"], 6
         )
 
-    # -- fused-member: one dispatch per step, factored perturbations -------
-    _, compiled_f = make(pop_fuse=True)
-    thf, mf, _ = compiled_f(frozen, fresh_theta(), flat_ids, jax.random.PRNGKey(2))
-    float(jax.device_get(mf["opt_score_mean"]))  # warmup
-    rec["step_time_fused_s"] = round(
-        _timed_steps(compiled_f, frozen, thf, flat_ids, steps), 6
-    )
-    rec["fused_speedup_s"] = round(
-        rec["step_time_single_s"] - rec["step_time_fused_s"], 6
-    )
-
     # -- fleet: TWO jobs per dispatch (ISSUE 20) vs the same two jobs
-    # stepped sequentially through the fused solo program. This row isolates
+    # stepped sequentially through the solo program. This row isolates
     # the *dispatch-side* half of fleet amortization (one launch + one sync
     # for J jobs); the byte-side half is preflight --fleet's claim. Both
     # jobs share the cohort geometry (admission contract), so the sequential
@@ -194,17 +174,9 @@ def run(rung: str, steps: int, chain: int) -> dict:
     from ..lora import stack_adapters
     from ..train.trainer import fleet_scalar_args, make_fleet_step
 
-    tc_f = TrainConfig(
-        pop_size=pop, sigma=0.01, egg_rank=4, prompts_per_gen=num_unique,
-        batches_per_gen=1, member_batch=member_batch, promptnorm=True,
-        remat=opt["remat"], reward_tile=opt["reward_tile"],
-        noise_dtype=opt["noise_dtype"], pop_fuse=True,
-        base_quant=opt.get("base_quant", "off"),
-        quality=opt.get("quality", False),
-    )
     # donate=False: microbench re-executes one program many times in-process
     # (XLA:CPU donation clobbers reused inputs under that pattern)
-    fleet2 = make_fleet_step(backend, reward_fn, tc_f, num_unique, 1, 2,
+    fleet2 = make_fleet_step(backend, reward_fn, tc, num_unique, 1, 2,
                              donate=False)
     stacked = jax.tree_util.tree_map(
         jnp.asarray, stack_adapters([theta_host, theta_host])
@@ -214,7 +186,7 @@ def run(rung: str, steps: int, chain: int) -> dict:
     )
     ids2 = jnp.stack([flat_ids, flat_ids])
     keys2 = jnp.stack([jax.random.PRNGKey(2), jax.random.PRNGKey(4)])
-    sig, csc, lrs = fleet_scalar_args([tc_f, tc_f])
+    sig, csc, lrs = fleet_scalar_args([tc, tc])
     fargs = (frozen, stacked, szeros, ids2, keys2,
              jnp.asarray(sig), jnp.asarray(csc), jnp.asarray(lrs))
     cfleet = fleet2.lower(*fargs).compile()
@@ -227,19 +199,19 @@ def run(rung: str, steps: int, chain: int) -> dict:
     rec["step_time_fleet2_fused_s"] = round(
         (time.perf_counter() - t0) / steps, 6
     )
-    # sequential baseline: two chained solo fused steps per round (θ chains
+    # sequential baseline: two chained solo steps per round (θ chains
     # per job, so the final fetch data-depends on every timed step)
     th_a, th_b = fresh_theta(), fresh_theta()
-    th_a, ma, _ = compiled_f(frozen, th_a, flat_ids, jax.random.PRNGKey(2))
-    th_b, mb, _ = compiled_f(frozen, th_b, flat_ids, jax.random.PRNGKey(4))
+    th_a, ma, _ = compiled(frozen, th_a, flat_ids, jax.random.PRNGKey(2))
+    th_b, mb, _ = compiled(frozen, th_b, flat_ids, jax.random.PRNGKey(4))
     float(jax.device_get(ma["opt_score_mean"]))
     float(jax.device_get(mb["opt_score_mean"]))  # warmup
     t0 = time.perf_counter()
     for e in range(steps):
-        th_a, ma, _ = compiled_f(
+        th_a, ma, _ = compiled(
             frozen, th_a, flat_ids, jax.random.fold_in(jax.random.PRNGKey(2), e)
         )
-        th_b, mb, _ = compiled_f(
+        th_b, mb, _ = compiled(
             frozen, th_b, flat_ids, jax.random.fold_in(jax.random.PRNGKey(4), e)
         )
     float(jax.device_get(ma["opt_score_mean"]))
@@ -253,12 +225,11 @@ def run(rung: str, steps: int, chain: int) -> dict:
             / rec["step_time_fleet2_fused_s"], 4
         )
 
-    # -- fused_qlora: int8 base + factored members through the unified
-    # resolution (ops/fused_qlora.py — its XLA-fallback form on CPU). The
-    # base is quantized with the min-size floor dropped so small-geometry
-    # rungs exercise the PATH (the byte win is the ledger's claim, not this
-    # microbench's); the row measures what the unified dequant+delta
-    # composition does to the same dispatch cadence.
+    # -- fused_qlora: int8 base + factored members (ops/fused_qlora.py — its
+    # XLA composition on CPU). The base is quantized with the min-size floor
+    # dropped so small-geometry rungs exercise the PATH (the byte win is the
+    # ledger's claim, not this microbench's); the row measures what the
+    # dequant+delta composition does to the same dispatch cadence.
     import os
 
     from ..ops.quant import MIN_SIZE_ENV
@@ -273,7 +244,7 @@ def run(rung: str, steps: int, chain: int) -> dict:
             pop_size=pop, sigma=0.01, egg_rank=4, prompts_per_gen=num_unique,
             batches_per_gen=1, member_batch=member_batch, promptnorm=True,
             remat=opt["remat"], reward_tile=opt["reward_tile"],
-            noise_dtype=opt["noise_dtype"], pop_fuse=True, base_quant="int8",
+            noise_dtype=opt["noise_dtype"], base_quant="int8",
             quality=opt.get("quality", False),
         )
         step_q = make_es_step(backend_q, reward_q, tc_q, num_unique, 1, None)
@@ -295,14 +266,11 @@ def run(rung: str, steps: int, chain: int) -> dict:
         else:
             os.environ[MIN_SIZE_ENV] = old_floor
 
-    # kernel provenance: which Pallas env flags were set when this row was
-    # measured, and whether the unified routing shaped the qlora program
-    from ..ops.fused_qlora import unified_routing_enabled
+    # kernel provenance: the Pallas env flags set when this row was measured
     from ..ops.pallas_gate import active_pallas_flags, selected_kernels
 
     rec["pallas_env"] = active_pallas_flags()
     rec["pallas_selected"] = selected_kernels()
-    rec["fused_qlora"] = unified_routing_enabled()
     return rec
 
 

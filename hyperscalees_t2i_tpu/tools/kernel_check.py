@@ -4,16 +4,15 @@ the shapes its real caller passes it.
     python -m hyperscalees_t2i_tpu.tools.kernel_check [--kernels a,b]
         [--compile_only] [--out FILE]
 
-The CPU tier can only *interpret* the four kernels in ``ops/``; whether
+The CPU tier can only *interpret* the two kernels in ``ops/``; whether
 Mosaic accepts them, and whether what it builds agrees with the XLA path, is
 a fact about a chip. This is the one place that establishes it: every case
 below is a call a model really makes (the dense sites of Sana-Sprint 1.6B and
-VAR-d16 under ``--base_quant int8 --pop_fuse true`` with the member axis the
+VAR-d16 under ``--base_quant int8`` with the member axis the
 benchmark's cells put in front, the VAR ten-scale KV cache, Infinity's masked
 cross-attention), run with ``interpret=False`` and compared with the
 XLA form the gate would otherwise choose. ``chip_smoke.py`` runs the first
-case of every kernel the default TPU gates select and fails on a
-disagreement; the opt-in kernels are checked only by this tool.
+case of every kernel the TPU gates select and fails on a disagreement.
 
 ``--compile_only`` lowers and compiles each case for a TPU v5e *without a
 chip* (libtpu's compile-only topology, ``jax.experimental.topologies``):
@@ -154,45 +153,6 @@ def _attention_case(label: str, B: int, nq: int, L: int, kv_len: Optional[int],
     )
 
 
-def _lora_case(label: str, T: int, din: int, dout: int) -> Case:
-    from ..ops.fused_lora import member_lora_delta, xla_member_lora_delta
-
-    def make(key):
-        kx, ka, kb = jax.random.split(key, 3)
-        x = jax.random.normal(kx, (T, din), jnp.float32).astype(jnp.bfloat16)
-        return x, _factored(ka, din, 8, 4, jnp.bfloat16), _factored(kb, 8, dout, 4, jnp.bfloat16)
-
-    return Case(
-        "member_lora_delta", label, make,
-        lambda x, a, b: member_lora_delta(x, a, b, 2.0, use_pallas=True),
-        lambda x, a, b: xla_member_lora_delta(x, a, b, 2.0),
-        tol=4 * _BF16_EPS,
-        tol_reason="the XLA form rounds a_k, b_k and x@a_k to bf16; the kernel "
-                   "keeps the chain in f32: up to four bf16 roundings apart",
-    )
-
-
-def _int8mm_case(label: str, T: int, din: int, dout: int) -> Case:
-    from ..ops.quant import quantize_kernel
-    from ..ops.quant_mm import int8_matmul, xla_int8_matmul
-
-    def make(key):
-        kx, kq = jax.random.split(key)
-        x = jax.random.normal(kx, (T, din), jnp.float32).astype(jnp.bfloat16)
-        qk = quantize_kernel(jax.random.normal(kq, (din, dout), jnp.float32) / jnp.sqrt(din))
-        return x, qk["q8"], qk["scale"]
-
-    return Case(
-        "int8_matmul", label, make,
-        lambda x, q8, s: int8_matmul(x, q8, s, use_pallas=True),
-        xla_int8_matmul,
-        tol=2 * _BF16_EPS,
-        tol_reason="the XLA form rounds the dequantized weights to bf16 "
-                   "before the dot, the kernel multiplies in f32: one operand "
-                   "rounding plus the output rounding",
-    )
-
-
 # VAR default geometry (models/var.VARConfig): 16 heads × 64, ten scales
 # 1,2,3,4,5,6,8,10,13,16 → queries pn² against the cache prefix written so
 # far, batch = 2 × prompts (CFG) — 4 prompts here, as the `ar` rung has.
@@ -261,10 +221,6 @@ def cases() -> List[Case]:
                         8, 256, 16, None, masked=True),
         _attention_case("infinity cross-attn: q[8,256,16,64] vs text[8,512,16,64] + mask",
                         8, 256, 512, None, masked=True),
-        _lora_case("sana attn site, float base: x[1024,2240], a[2240,8], b[8,2240]",
-                   1024, 2240, 2240),
-        # (the wider FFN 1×1 convs are not this kernel's: quant_mm._kernel_handles)
-        _int8mm_case("sana attn: x[1024,2240] @ s8[2240,2240]", 1024, 2240, 2240),
     ]
     return out
 
@@ -331,7 +287,7 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--kernels", default="",
-                    help="comma list of pallas_call names (default: all four)")
+                    help="comma list of pallas_call names (default: both)")
     ap.add_argument("--compile_only", action="store_true",
                     help="compile for a TPU v5e topology without a chip")
     ap.add_argument("--out", default=None, help="also write the records here (JSONL)")

@@ -166,7 +166,7 @@ def abstract_step_inputs(
         pop_size=pop, sigma=0.01, egg_rank=4, prompts_per_gen=m,
         batches_per_gen=1, member_batch=member_batch, promptnorm=True,
         remat=opt["remat"], reward_tile=opt["reward_tile"],
-        noise_dtype=opt["noise_dtype"], pop_fuse=opt.get("pop_fuse", False),
+        noise_dtype=opt["noise_dtype"],
         pop_shard_update=opt.get("pop_shard_update", "auto"),
         base_quant=base_quant,
         quality=opt.get("quality", False),
@@ -874,21 +874,10 @@ def main(argv=None) -> int:
                     choices=["float32", "bfloat16", "bf16"],
                     help="override the rung's reward-tower serving compute "
                          "dtype")
-    ap.add_argument("--pop_fuse", default=None, choices=["on", "off"],
-                    help="override the rung's fused-factored-member setting "
-                         "(on = FactoredDelta thin-contraction path, off = "
-                         "materialized per-member perturbations)")
     ap.add_argument("--base_quant", default=None, choices=["off", "int8"],
                     help="override the rung's frozen-base storage "
                          "quantization (int8 = per-output-channel int8 base "
                          "kernels dequantized at use, ops/quant.py)")
-    ap.add_argument("--fused_qlora", default=None, choices=["on", "off"],
-                    help="override the unified int8-dequant+LoRA routing "
-                         "(ops/fused_qlora.py, HSES_FUSED_QLORA): off "
-                         "analyzes the round-14 composition — separate "
-                         "dequant + LoRA delta, conv sites dequant-then-"
-                         "conv — the reference program the CI ledger gate "
-                         "diffs the shipped (on, default) form against")
     ap.add_argument("--pop_shard_update", default=None,
                     choices=["auto", "on", "off"],
                     help="override the pop-sharded-update mode the sharded "
@@ -971,7 +960,6 @@ def main(argv=None) -> int:
             "reward_tile": args.reward_tile,
             "noise_dtype": args.noise_dtype,
             "tower_dtype": args.tower_dtype,
-            "pop_fuse": None if args.pop_fuse is None else args.pop_fuse == "on",
             "base_quant": args.base_quant,
         }
         pairs = []
@@ -1023,19 +1011,12 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = forced_host_devices_flags(
             os.environ.get("XLA_FLAGS", ""), args.devices
         )
-    if args.fused_qlora is not None:
-        # trace-time routing knob (ops/fused_qlora.py): set explicitly so an
-        # inherited HSES_FUSED_QLORA can't contradict the CLI request
-        from ..ops.fused_qlora import ROUTING_ENV
-
-        os.environ[ROUTING_ENV] = "off" if args.fused_qlora == "off" else "1"
     ledger = ProgramLedger(Path(args.out) / "programs.jsonl") if args.out else None
     opt_override = {
         "remat": args.remat,
         "reward_tile": args.reward_tile,
         "noise_dtype": args.noise_dtype,
         "tower_dtype": args.tower_dtype,
-        "pop_fuse": None if args.pop_fuse is None else args.pop_fuse == "on",
         "pop_shard_update": args.pop_shard_update,
         "base_quant": args.base_quant,
     }

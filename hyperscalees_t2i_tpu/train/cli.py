@@ -42,6 +42,18 @@ def parse_resume(v: str) -> bool:
     return str2bool(v)
 
 
+def removed_pop_fuse(v: str) -> bool:
+    """``--pop_fuse`` chose between two member paths until the factored one
+    became the only one: ``true`` is what the program does and is accepted,
+    ``false`` asks for a path that no longer exists and is refused."""
+    if not str2bool(v):
+        raise argparse.ArgumentTypeError(
+            "the materialized member path was removed: every member's adapter "
+            "reaches the forward factored (es.factored_member_theta); drop the flag"
+        )
+    return True
+
+
 def parse_float_list(s: Optional[str]) -> Optional[Tuple[float, ...]]:
     if not s:
         return None
@@ -139,13 +151,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "halves CLIP activation/resize bytes; layernorm/"
                         "softmax internals stay f32). The v5e flagship fit "
                         "recipe uses bfloat16 (rungs.RUNG_OPT)")
-    p.add_argument("--pop_fuse", type=str2bool, default=False,
-                   help="fused factored member evaluation: apply each "
-                        "member's ES perturbation as chained thin "
-                        "contractions inside every adapted dense instead of "
-                        "materializing the dense perturbation per member "
-                        "(fewer bytes moved; theta parity rounding-tight, "
-                        "not bitwise — PERF.md round 12)")
+    # parse-only: the benchmark's configuration files still pass it
+    p.add_argument("--pop_fuse", type=removed_pop_fuse, help=argparse.SUPPRESS)
     p.add_argument("--base_quant", default="off", choices=["off", "int8"],
                    help="frozen-base storage quantization: int8 stores the "
                         "base kernel trees (DiT, DC-AE decoder, CLIP reward "
@@ -700,6 +707,52 @@ def build_reward_fn(args, backend):
     )
 
 
+def train_config(args):
+    """The parsed flags as the trainer's ``TrainConfig``."""
+    from .config import TrainConfig
+
+    return TrainConfig(
+        num_epochs=args.num_epochs, pop_size=args.pop_size, sigma=args.sigma,
+        lr_scale=args.lr_scale, egg_rank=args.egg_rank, antithetic=args.antithetic,
+        promptnorm=args.promptnorm, prompts_per_gen=args.prompts_per_gen,
+        batches_per_gen=args.batches_per_gen, member_batch=args.member_batch,
+        steps_per_dispatch=args.steps_per_dispatch,
+        reward_tile=args.reward_tile, remat=args.remat,
+        pop_shard_update=args.pop_shard_update, base_quant=args.base_quant,
+        noise_dtype="bfloat16" if args.noise_dtype == "bf16" else args.noise_dtype,
+        tower_dtype="bfloat16" if args.tower_dtype == "bf16" else args.tower_dtype,
+        theta_max_norm=args.theta_max_norm, max_step_norm=args.max_step_norm,
+        reward_weights=(args.w_aesthetic, args.w_text, args.w_noart, args.w_pick),
+        seed=args.seed, save_every=args.save_every,
+        log_images_every=args.log_images_every,
+        log_hist_every=args.log_hist_every,
+        profile_epochs=args.profile_epochs,
+        trace=args.trace, metrics_port=args.metrics_port,
+        metrics_host=args.metrics_host,
+        metrics_linger_s=args.metrics_linger_s, slo=args.slo,
+        heartbeat_interval_s=args.heartbeat_interval_s,
+        stall_cap_s=args.stall_cap_s, stall_action=args.stall_action,
+        es_degenerate_warn_epochs=args.es_degenerate_warn_epochs,
+        anomaly_detect=args.anomaly_detect,
+        anomaly_window=args.anomaly_window,
+        anomaly_min_epochs=args.anomaly_min_epochs,
+        anomaly_z=args.anomaly_z,
+        quality=args.quality,
+        quality_hack_window=args.quality_hack_window,
+        snapshot_every=args.snapshot_every,
+        run_dir=args.run_dir, run_name=args.run_name, resume=args.resume,
+        ckpt_keep=args.ckpt_keep, ckpt_legacy_mirror=args.ckpt_legacy_mirror,
+        rollback_policy=args.rollback_policy, max_rollbacks=args.max_rollbacks,
+        rollback_sigma_shrink=args.rollback_sigma_shrink,
+        theta_explode_norm=args.theta_explode_norm, faults=args.faults,
+        pop_host_shard=args.pop_host_shard,
+        desync_check_every=args.desync_check_every,
+        desync_action=args.desync_action,
+        on_topology_mismatch=args.on_topology_mismatch,
+        elastic_action=args.elastic_action,
+    )
+
+
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
 
@@ -736,7 +789,6 @@ def _run(args) -> None:
     """``main`` after the tracer is installed: build, mesh, train."""
     from ..obs import block_if_tracing, span as obs_span
     from ..parallel import POP_AXIS, initialize_multihost, make_mesh
-    from .config import TrainConfig
     from .trainer import run_training
 
     initialize_multihost()
@@ -811,46 +863,7 @@ def _run(args) -> None:
         print(f"[cli] mesh: {dict(mesh.shape)} over {n_dev} {scope} devices",
               flush=True)
 
-    tc = TrainConfig(
-        num_epochs=args.num_epochs, pop_size=args.pop_size, sigma=args.sigma,
-        lr_scale=args.lr_scale, egg_rank=args.egg_rank, antithetic=args.antithetic,
-        promptnorm=args.promptnorm, prompts_per_gen=args.prompts_per_gen,
-        batches_per_gen=args.batches_per_gen, member_batch=args.member_batch,
-        steps_per_dispatch=args.steps_per_dispatch,
-        reward_tile=args.reward_tile, remat=args.remat, pop_fuse=args.pop_fuse,
-        pop_shard_update=args.pop_shard_update, base_quant=args.base_quant,
-        noise_dtype="bfloat16" if args.noise_dtype == "bf16" else args.noise_dtype,
-        tower_dtype="bfloat16" if args.tower_dtype == "bf16" else args.tower_dtype,
-        theta_max_norm=args.theta_max_norm, max_step_norm=args.max_step_norm,
-        reward_weights=(args.w_aesthetic, args.w_text, args.w_noart, args.w_pick),
-        seed=args.seed, save_every=args.save_every,
-        log_images_every=args.log_images_every,
-        log_hist_every=args.log_hist_every,
-        profile_epochs=args.profile_epochs,
-        trace=args.trace, metrics_port=args.metrics_port,
-        metrics_host=args.metrics_host,
-        metrics_linger_s=args.metrics_linger_s, slo=args.slo,
-        heartbeat_interval_s=args.heartbeat_interval_s,
-        stall_cap_s=args.stall_cap_s, stall_action=args.stall_action,
-        es_degenerate_warn_epochs=args.es_degenerate_warn_epochs,
-        anomaly_detect=args.anomaly_detect,
-        anomaly_window=args.anomaly_window,
-        anomaly_min_epochs=args.anomaly_min_epochs,
-        anomaly_z=args.anomaly_z,
-        quality=args.quality,
-        quality_hack_window=args.quality_hack_window,
-        snapshot_every=args.snapshot_every,
-        run_dir=args.run_dir, run_name=args.run_name, resume=args.resume,
-        ckpt_keep=args.ckpt_keep, ckpt_legacy_mirror=args.ckpt_legacy_mirror,
-        rollback_policy=args.rollback_policy, max_rollbacks=args.max_rollbacks,
-        rollback_sigma_shrink=args.rollback_sigma_shrink,
-        theta_explode_norm=args.theta_explode_norm, faults=args.faults,
-        pop_host_shard=args.pop_host_shard,
-        desync_check_every=args.desync_check_every,
-        desync_action=args.desync_action,
-        on_topology_mismatch=args.on_topology_mismatch,
-        elastic_action=args.elastic_action,
-    )
+    tc = train_config(args)
 
     # best/median/worst member strips + histograms + profiler traces are
     # handled inside run_training (reference unifed_es.py:243-264,807-821)

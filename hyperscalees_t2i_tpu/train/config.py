@@ -51,14 +51,6 @@ class TrainConfig:
     # remat, recorded here for the ledger — the applied value lives in the
     # tower configs (train/cli.py build_reward_fn / rungs.sana_rung_model).
     tower_dtype: str = "float32"
-    # fused factored member evaluation (PERF.md round 12): apply each
-    # member's ES perturbation as chained thin contractions inside every
-    # adapted dense (lora.FactoredDelta) instead of materializing
-    # θ+σ·s·U_bV_bᵀ/√r per member before the forward. Fewer bytes moved at
-    # every population scale (ledger-verified); θ parity with the
-    # materialized path is rounding-tight, not bitwise. False lowers the
-    # byte-identical pre-round-12 program.
-    pop_fuse: bool = False
 
     # frozen-base storage quantization ("off" | "int8"): the base kernel
     # trees (DiT, DC-AE decoder, CLIP reward towers) stored per-output-

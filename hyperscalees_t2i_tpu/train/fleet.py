@@ -59,7 +59,7 @@ Pytree = Any
 COHORT_FIELDS: Tuple[str, ...] = (
     "pop_size", "egg_rank", "antithetic", "member_batch", "promptnorm",
     "prompts_per_gen", "batches_per_gen", "reward_tile", "noise_dtype",
-    "pop_fuse", "base_quant", "remat", "max_step_norm", "theta_max_norm",
+    "base_quant", "remat", "max_step_norm", "theta_max_norm",
     "quality",
 )
 
@@ -153,7 +153,7 @@ def make_solo_reward_rows(backend, reward_fn, tc) -> Callable:
     rew_p, _ = reward_parts(reward_fn)
     eval_pop = make_population_evaluator(
         gen_p, rew_p, pop, es_cfg, tc.member_batch,
-        reward_tile=tc.reward_tile, pop_fuse=tc.pop_fuse,
+        reward_tile=tc.reward_tile,
     )
 
     def rows(frozen, theta, flat_ids, key):

@@ -93,8 +93,8 @@ def _combine_and_update(
     ``es_update`` as a traced scalar — the fleet step passes each job's
     host-precomputed ``f32(lr_scale_j·σ_j)`` so one compiled program serves
     any per-job hyperparameter mix. ``None`` (every solo caller) resolves to
-    ``es_cfg.lr`` inside ``es_update`` exactly as before — byte-identical
-    trace, golden program untouched.
+    ``es_cfg.lr`` inside ``es_update`` — the solo trace is the same with or
+    without the fleet path.
 
     ``gen_metrics_fn`` (a backend's ``step_metrics``) reduces the generator's
     own rows (``gen/<name>``, ``[pop, B, ...]``, parallel/pop_eval.py) to
@@ -282,7 +282,6 @@ def make_host_sharded_programs(
     eval_slice_pop = make_population_evaluator(
         gen_p, rew_p, pop, es_cfg, tc.member_batch, mesh,
         reward_tile=tc.reward_tile, host_slice=host_slice,
-        pop_fuse=tc.pop_fuse,
     )
 
     def eval_slice(frozen: Pytree, theta: Pytree, flat_ids: jax.Array, key: jax.Array):
@@ -354,7 +353,7 @@ def make_es_step(
     rew_p, _ = reward_parts(reward_fn)
     eval_pop = make_population_evaluator(
         gen_p, rew_p, pop, es_cfg, tc.member_batch, mesh,
-        reward_tile=tc.reward_tile, pop_fuse=tc.pop_fuse,
+        reward_tile=tc.reward_tile,
     )
     update_fn, shard_update_on, n_update_shards = _resolve_update_fn(tc, es_cfg, mesh)
 
@@ -474,8 +473,7 @@ def make_fleet_step(
       (train/fleet.py enforces); per-job σ/lr are free.
 
     The fleet path is opt-in (J>1 callers only) — nothing here is reachable
-    from the solo ``make_es_step`` trace, so the all-knobs-off golden
-    program is untouched by construction.
+    from the solo ``make_es_step`` trace.
     """
     from ..backends.base import generate_parts, reward_parts
     from ..parallel.pop_eval import make_fleet_evaluator
@@ -489,7 +487,7 @@ def make_fleet_step(
     rew_p, _ = reward_parts(reward_fn)
     eval_fleet = make_fleet_evaluator(
         gen_p, rew_p, W, pop, es_cfg, tc.member_batch,
-        reward_tile=tc.reward_tile, pop_fuse=tc.pop_fuse,
+        reward_tile=tc.reward_tile,
     )
 
     def fleet_core(
@@ -1431,7 +1429,6 @@ def run_training(
                             "remat": tc_live.remat,
                             "noise_dtype": tc_live.noise_dtype,
                             "tower_dtype": tc_live.tower_dtype,
-                            "pop_fuse": tc_live.pop_fuse,
                             "base_quant": tc_live.base_quant,
                             # topology (every compile site records it, so ledger
                             # collective bytes are always attributable to a mesh)
@@ -1611,7 +1608,6 @@ def run_training(
                                           "remat": tc_live.remat,
                                           "noise_dtype": tc_live.noise_dtype,
                                           "tower_dtype": tc_live.tower_dtype,
-                                          "pop_fuse": tc_live.pop_fuse,
                                           "base_quant": tc_live.base_quant,
                                           "mesh_shape": (dict(mesh.shape)
                                                          if mesh is not None else None),

@@ -214,7 +214,7 @@ def test_bench_report_trend_mode(tmp_path, capsys):
     # no artifacts at all is an error, not a crash
     assert br.main(["--trend"]) == 1
 
-    # knob/kernel markers (schema-additive, ISSUE 10 + 11): a fused/int8
+    # knob/kernel markers (schema-additive, ISSUE 10 + 11): an int8
     # rung is marked in its trend cell — its throughput only compares to
     # rows with the same marks — the Pallas env flags active at measurement
     # time render as P:<short names> (kernel-on vs kernel-off artifacts
@@ -226,14 +226,14 @@ def test_bench_report_trend_mode(tmp_path, capsys):
         "rungs": {"mid": {"rung": "mid", "imgs_per_sec": 9.0,
                           "remat": "blocks", "reward_tile": 2,
                           "noise_dtype": "bfloat16", "tower_dtype": "bfloat16",
-                          "pop_fuse": True, "base_quant": "int8"}},
+                          "base_quant": "int8"}},
     }))
     assert br.main(["--trend", str(new), str(q8)]) == 0
     out = capsys.readouterr().out
-    assert "9.0 (fuse,q8)" in out
+    assert "9.0 (q8)" in out
     assert "| 7.5 |" in out  # unmarked cell stays unmarked
     assert br.main([str(q8)]) == 0
-    assert "blocks/t2/n-bf16/w-bf16/fuse/q8" in capsys.readouterr().out
+    assert "blocks/t2/n-bf16/w-bf16/q8" in capsys.readouterr().out
 
     kern = tmp_path / "BENCH_r08.json"
     kern.write_text(json.dumps({
@@ -241,18 +241,17 @@ def test_bench_report_trend_mode(tmp_path, capsys):
         "rungs": {"mid": {"rung": "mid", "imgs_per_sec": 9.5,
                           "remat": "blocks", "reward_tile": 2,
                           "noise_dtype": "bfloat16", "tower_dtype": "bfloat16",
-                          "pop_fuse": True, "base_quant": "int8",
-                          "fused_qlora": False,
+                          "base_quant": "int8",
                           "pallas_env": {"HSES_FUSED_QLORA_PALLAS": "1",
                                          "HSES_USE_PALLAS": "0"}}},
     }))
     assert br.main(["--trend", str(q8), str(kern)]) == 0
     out = capsys.readouterr().out
-    assert "9.5 (fuse,q8,uq-,P:flash-,qlora)" in out
-    assert "9.0 (fuse,q8)" in out  # flag-free row unchanged beside it
+    assert "9.5 (q8,P:flash-,qlora)" in out
+    assert "9.0 (q8)" in out  # flag-free row unchanged beside it
     # the per-rung knobs column renders the same provenance
     assert br.main([str(kern)]) == 0
-    assert "blocks/t2/n-bf16/w-bf16/fuse/q8/uq-/P:flash-,qlora" in capsys.readouterr().out
+    assert "blocks/t2/n-bf16/w-bf16/q8/P:flash-,qlora" in capsys.readouterr().out
 
 
 def _scaling_doc():

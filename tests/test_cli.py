@@ -1,11 +1,20 @@
 """CLI surface tests: parser, backend construction for every family, tiny
 reward tower build (the unifed_es.py-equivalent layer, SURVEY.md L4)."""
 
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from hyperscalees_t2i_tpu.train.cli import build_backend, build_parser, build_reward_fn, str2bool
+from hyperscalees_t2i_tpu.train.cli import (
+    build_backend,
+    build_parser,
+    build_reward_fn,
+    str2bool,
+    train_config,
+)
 
 
 def parse(extra):
@@ -57,3 +66,56 @@ def test_reward_fn_tiny(tmp_path):
     imgs = jnp.zeros((2, 8, 8, 3))
     out = rf(imgs, jnp.asarray([0, 0], jnp.int32))
     assert "combined" in out and out["combined"].shape == (2,)
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's frozen files meet the program at this parser
+# ---------------------------------------------------------------------------
+
+ROOT = Path(__file__).resolve().parents[1]
+CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_flags_parse(cell, tmp_path):
+    """Every flag a cell of ``BENCHMARK.json`` passes — its configuration's,
+    its traffic's, its generated inputs' (made at the rehearsal's size), the
+    harness's own — goes through the parser into a ``TrainConfig``. No PR but
+    a ``benchmark`` one may edit those files, so a flag the program drops has
+    to go on parsing (``--pop_fuse true`` is the first)."""
+    import importlib
+
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    work = next(w for w in manifest["workloads"] if w["name"] == cell)
+    entry = next(c for c in manifest["configs"] if c["name"] == work["config"])
+    config = json.loads((ROOT / entry["file"]).read_text())
+    traffic = json.loads((ROOT / "benchmarks" / "traffic" / f"{work['traffic']}.json").read_text())
+    flags = {**config["flags"], **traffic["flags"]}
+    spec = {**config["inputs"], **config["rehearse"].get("inputs", {})}
+    gen = importlib.import_module(f"benchmarks.inputs.{spec['kind']}")
+    made = gen.make(spec, config["model"], 0, tmp_path, ROOT / "benchmarks")
+    flags.update(zip(made[::2], made[1::2]))
+    flags.update({"--seed": "0", "--resume": "false", "--save_every": "0",
+                  "--run_dir": str(tmp_path), "--run_name": "run",
+                  "--num_epochs": "1000000", "--trace": "true"})
+    args = parse([x for kv in flags.items() for x in kv])
+    tc = train_config(args)
+    assert args.backend == flags["--backend"] and args.model_scale == "full"
+    assert (tc.pop_size, tc.prompts_per_gen, tc.member_batch) == tuple(
+        int(traffic["flags"][f]) for f in ("--pop_size", "--prompts_per_gen", "--member_batch"))
+    assert (tc.base_quant, tc.noise_dtype, tc.tower_dtype) == ("int8", "bfloat16", "bfloat16")
+    assert (tc.remat, tc.reward_tile) == (flags["--remat"], int(flags["--reward_tile"]))
+    assert tc.trace and not tc.resume and tc.seed == 0
+    assert flags["--pop_fuse"] == "true" and not hasattr(tc, "pop_fuse")
+
+
+def test_pop_fuse_false_is_refused_by_name(capsys):
+    """The flag is parse-only: ``true`` is what the program does; ``false``
+    asks for the removed member path and must not silently run the other."""
+    assert parse(["--backend", "var", "--pop_fuse", "true"]).pop_fuse is True
+    with pytest.raises(SystemExit) as e:
+        parse(["--backend", "var", "--pop_fuse", "false"])
+    assert e.value.code == 2
+    err = capsys.readouterr().err
+    assert "--pop_fuse" in err and "removed" in err
+    assert "--pop_fuse" not in build_parser().format_help()

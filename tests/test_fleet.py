@@ -130,7 +130,6 @@ def _fleet_tc(sigma, lr_scale, seed, run_dir):
         num_epochs=1, pop_size=4, sigma=sigma, lr_scale=lr_scale, egg_rank=2,
         antithetic=True, promptnorm=True, prompts_per_gen=2, batches_per_gen=1,
         member_batch=4, run_dir=str(run_dir), save_every=0, seed=seed,
-        pop_fuse=True,
     )
 
 
@@ -247,7 +246,7 @@ def test_fleet_scheduler_end_to_end(tmp_path):
             num_epochs=2, pop_size=4, sigma=sigma, lr_scale=lr_scale,
             egg_rank=2, antithetic=True, promptnorm=True, prompts_per_gen=2,
             batches_per_gen=1, member_batch=4, run_dir=str(tmp_path / "runs"),
-            save_every=1, seed=seed, pop_fuse=True,
+            save_every=1, seed=seed,
         )
 
     tc_a, tc_b = make_tc(0.05, 2.0, 3), make_tc(0.08, 1.5, 9)

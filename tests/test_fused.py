@@ -1,20 +1,15 @@
-"""Fused factored LoRA/ES hot-path parity (PERF.md round 12).
+"""The member path against its plain reference.
 
-The contract under test: ``pop_fuse=True`` never materializes a member's
-dense perturbation — adapters reach the forward as ``lora.FactoredDelta``
-leaves applied via one fused operand build per use — and the resulting θ
-trajectory matches the materialized path within float-rounding tolerance
-across noise dtypes, antithetic pairs, every LoRA leaf geometry (2D,
-stacked-3D, conv-4D), and the ``reward_tile`` interaction. ``pop_fuse=False``
-must keep lowering the *byte-identical* pre-round-12 program (the StableHLO
-golden below). The Pallas member-batched kernel is proven against the XLA
-fallback in interpret mode (CPU executes the same kernel logic the Mosaic
-compiler would get — the ops/attention.py precedent).
+The contract under test: a step program never materializes a member's dense
+perturbation — adapters reach the forward as ``lora.FactoredDelta`` leaves
+applied via one fused operand build per use — and what it computes matches
+the reference that does materialize it (``es.perturb_member``, looped over
+members in Python here, in the test) within float-rounding tolerance across
+noise dtypes, antithetic pairs, every LoRA leaf geometry (2D, stacked-3D,
+conv-4D), the ``reward_tile`` interaction, and every backend the CLI can
+build. There is no other member path to fall back to, so a backend on which
+the factored leaves were wrong would have nothing else to run.
 """
-
-import hashlib
-import json
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,13 +26,10 @@ from hyperscalees_t2i_tpu.es import (
 from hyperscalees_t2i_tpu.lora import (
     FactoredDelta,
     effective_factor,
-    fused_lora_delta,
     matmul_factored,
     slice_layer,
 )
 from hyperscalees_t2i_tpu.models import nn
-
-GOLDEN = Path(__file__).parent / "golden"
 
 
 def make_theta():
@@ -158,8 +150,30 @@ def test_matmul_factored_raw_passthrough():
 
 
 # ---------------------------------------------------------------------------
-# end-to-end: θ trajectory fused vs materialized through make_es_step
+# end to end: the evaluator and the step against a test-local reference that
+# loops the members in Python over es.perturb_member
 # ---------------------------------------------------------------------------
+
+def make_reference_evaluator(generate_p, reward_apply, pop_size, es_cfg, member_batch=0,
+                             mesh=None, reward_tile=0, host_slice=None):
+    """``make_population_evaluator``'s signature and result, written the plain
+    way: one member after another, each perturbation materialized
+    (``perturb_member``), raw leaves through the same generate → reward, no
+    member batching, no tiling, no mesh."""
+    assert mesh is None and host_slice is None
+
+    def eval_pop(frozen, theta, noise, flat_ids, gen_key):
+        item_index = jnp.arange(flat_ids.shape[0])
+        rows = []
+        for k in range(pop_size):
+            theta_k = perturb_member(theta, noise, k, pop_size, es_cfg)
+            out = generate_p(frozen["gen"], theta_k, flat_ids, gen_key, item_index)
+            images = out[0] if isinstance(out, tuple) else out
+            rows.append(dict(reward_apply(frozen["reward"], images, flat_ids)))
+        return {name: jnp.stack([r[name] for r in rows]) for name in rows[0]}
+
+    return eval_pop
+
 
 _TINY_CACHE = {}
 
@@ -221,30 +235,33 @@ def _run_epochs(backend, reward_fn, frozen, tc, epochs=2):
 @pytest.mark.parametrize(
     "noise_dtype,reward_tile", [("float32", 0), ("bfloat16", 2)],
 )
-def test_theta_trajectory_parity(noise_dtype, reward_tile):
+def test_theta_trajectory_parity(noise_dtype, reward_tile, monkeypatch):
+    """θ after two epochs of ``make_es_step`` against the same step built on
+    the reference evaluator: fitness shaping and the update are shared code,
+    so what differs is how a member's adapter met its base."""
+    from hyperscalees_t2i_tpu.parallel import pop_eval
     from hyperscalees_t2i_tpu.train.config import TrainConfig
 
     backend, reward_fn, frozen = _tiny_setup()
-    out = {}
-    for fuse in (False, True):
-        tc = TrainConfig(
-            pop_size=4, sigma=0.02, egg_rank=2, prompts_per_gen=1,
-            batches_per_gen=4, member_batch=2, promptnorm=True,
-            noise_dtype=noise_dtype, reward_tile=reward_tile, pop_fuse=fuse,
-        )
-        out[fuse] = _run_epochs(backend, reward_fn, frozen, tc)
-    norm = np.linalg.norm(out[False]) or 1.0
-    rel = np.linalg.norm(out[False] - out[True]) / norm
-    # rounding-tight, not bitwise: the fused path changes contraction order
+    tc = TrainConfig(
+        pop_size=4, sigma=0.02, egg_rank=2, prompts_per_gen=1,
+        batches_per_gen=4, member_batch=2, promptnorm=True,
+        noise_dtype=noise_dtype, reward_tile=reward_tile,
+    )
+    got = _run_epochs(backend, reward_fn, frozen, tc)
+    monkeypatch.setattr(pop_eval, "make_population_evaluator", make_reference_evaluator)
+    want = _run_epochs(backend, reward_fn, frozen, tc)
+    rel = np.linalg.norm(want - got) / (np.linalg.norm(want) or 1.0)
+    # rounding-tight, not bitwise: the factored leaves change contraction order
     # (measured ≤4e-6 rel over 3 epochs at this geometry — pinned with slack)
     assert rel < 1e-4, rel
-    assert np.max(np.abs(out[False] - out[True])) < 1e-4
+    assert np.max(np.abs(want - got)) < 1e-4
 
 
-def test_fused_evaluator_rewards_match_materialized():
-    """Per-member reward rows agree between the two evaluator modes — the
-    member axis batching (lax.map over factored adapters) changes no member's
-    identity, sign, or noise slice."""
+def test_evaluator_rewards_match_reference():
+    """Per-member reward rows agree with the reference's — the member axis
+    batching (lax.map over factored adapters) changes no member's identity,
+    sign, or noise slice. Pop 5: two antithetic pairs and the unpaired member."""
     from hyperscalees_t2i_tpu.backends.base import generate_parts, reward_parts
     from hyperscalees_t2i_tpu.parallel.pop_eval import make_population_evaluator
 
@@ -256,154 +273,66 @@ def test_fused_evaluator_rewards_match_materialized():
     noise = sample_noise(jax.random.PRNGKey(22), theta, 5, cfg)
     ids = jnp.zeros((4,), jnp.int32)
     key = jax.random.PRNGKey(23)
-    fz = {"gen": frozen["gen"], "reward": frozen["reward"]}
-    out = {}
-    for fuse in (False, True):
-        ev = make_population_evaluator(
-            gen_p, rew_p, 5, cfg, member_batch=2, pop_fuse=fuse
-        )
-        out[fuse] = jax.device_get(jax.jit(ev)(fz, theta, noise, ids, key))
-    for k in out[False]:
-        np.testing.assert_allclose(out[False][k], out[True][k], rtol=2e-4, atol=2e-4)
-
-
-# ---------------------------------------------------------------------------
-# Pallas member-batched kernel: interpret-mode parity + clean fallback
-# ---------------------------------------------------------------------------
-
-def _factored_pair(key, din=16, rl=4, re=2, dout=24):
-    ks = jax.random.split(key, 8)
-    a = FactoredDelta(
-        jax.random.normal(ks[0], (din, rl)), jax.random.normal(ks[1], (din, re)),
-        jax.random.normal(ks[2], (rl, re)), jnp.float32(0.03),
+    got, want = (
+        jax.device_get(jax.jit(make(gen_p, rew_p, 5, cfg, member_batch=2))(
+            frozen, theta, noise, ids, key))
+        for make in (make_population_evaluator, make_reference_evaluator)
     )
-    b = FactoredDelta(
-        jax.random.normal(ks[3], (rl, dout)), jax.random.normal(ks[4], (rl, re)),
-        jax.random.normal(ks[5], (dout, re)), jnp.float32(-0.04),
+    for k in want:
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-4, atol=2e-4)
+
+
+# every backend train.cli can build, at its tiny width, over an int8 base (the
+# cells' option; the floor lowered so toy kernels quantize): float-base sites
+# are the tests above
+BACKENDS = ["sana_one_step", "sana_pipeline", "var", "zimage", "infinity", "lm_ar"]
+
+
+@pytest.mark.parametrize("name", BACKENDS)
+def test_backend_member_rows_match_reference(name, tmp_path, monkeypatch):
+    import json
+
+    from hyperscalees_t2i_tpu.backends.base import generate_parts
+    from hyperscalees_t2i_tpu.ops.quant import MIN_SIZE_ENV, quantize_frozen
+    from hyperscalees_t2i_tpu.parallel.pop_eval import make_population_evaluator
+    from hyperscalees_t2i_tpu.train.cli import build_backend, build_parser
+
+    monkeypatch.setenv(MIN_SIZE_ENV, "1")
+    prompts = tmp_path / "p.txt"
+    prompts.write_text("a red square on a table\na blue circle\nthree green triangles\n")
+    flags = ["--backend", name, "--model_scale", "tiny", "--prompts_txt", str(prompts),
+             "--lora_r", "2", "--lora_alpha", "4"]
+    if name == "lm_ar":
+        from test_lm import TOY
+
+        (tmp_path / "config.json").write_text(json.dumps({**TOY, "num_nextn_predict_layers": 0}))
+        flags += ["--lm_config", str(tmp_path / "config.json")]
+    backend = build_backend(build_parser().parse_args(flags))
+    backend.setup()
+    backend.params = quantize_frozen(backend.params, "int8")
+    if getattr(backend, "vae_params", None) is not None:
+        backend.vae_params = quantize_frozen(backend.vae_params, "int8")
+    gen_p, frozen_gen = generate_parts(backend)
+    assert any(
+        leaf.dtype == jnp.int8 for leaf in jax.tree_util.tree_leaves(frozen_gen)
+    ), "no kernel went int8: the test would not reach the kernel_q8 rows"
+
+    def reward(fz, images, ids):  # per-image rows that see every pixel
+        x = images.astype(jnp.float32)
+        return {"mean": x.mean(axis=(1, 2, 3)), "contrast": x.std(axis=(1, 2, 3))}
+
+    # σ large enough that a token-sampling backend samples other tokens (at toy
+    # widths σ = 0.01 changes none, and every member would score the same)
+    pop, cfg = 4, EggRollConfig(sigma=0.5, rank=2, antithetic=True)
+    theta = backend.init_theta(jax.random.PRNGKey(31))
+    noise = sample_noise(jax.random.PRNGKey(32), theta, pop, cfg)
+    ids = jnp.asarray(np.asarray(backend.step_info(0, 2, 1).flat_ids, np.int32))
+    frozen = {"gen": frozen_gen, "reward": {}}
+    got, want = (
+        jax.device_get(jax.jit(make(gen_p, reward, pop, cfg, member_batch=2))(
+            frozen, theta, noise, ids, jax.random.PRNGKey(33)))
+        for make in (make_population_evaluator, make_reference_evaluator)
     )
-    x = jax.random.normal(ks[6], (3, 7, din))
-    return x, a, b
-
-
-def test_pallas_kernel_interpret_parity():
-    from hyperscalees_t2i_tpu.ops.fused_lora import member_lora_delta, xla_member_lora_delta
-
-    x, a, b = _factored_pair(jax.random.PRNGKey(30))
-    ref = xla_member_lora_delta(x, a, b, 2.0)
-    out = member_lora_delta(x, a, b, 2.0, interpret=True)
-    assert out.shape == ref.shape
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
-
-
-def test_pallas_kernel_interpret_parity_vmapped():
-    """The member axis arrives via vmap in pop_eval — the kernel must batch."""
-    from hyperscalees_t2i_tpu.ops.fused_lora import member_lora_delta, xla_member_lora_delta
-
-    x, a, b = _factored_pair(jax.random.PRNGKey(31))
-    cs = jnp.array([0.01, -0.02, 0.05])
-    am = jax.vmap(lambda c: FactoredDelta(a.w, a.u, a.v, c))(cs)
-    bm = jax.vmap(lambda c: FactoredDelta(b.w, b.u, b.v, -c))(cs)
-    ref = jax.vmap(lambda aa, bb: xla_member_lora_delta(x, aa, bb, 1.5))(am, bm)
-    out = jax.vmap(
-        lambda aa, bb: member_lora_delta(x, aa, bb, 1.5, interpret=True)
-    )(am, bm)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
-
-
-def test_pallas_kernel_tile_padding():
-    """Token counts that don't divide the tile run correctly (padded rows
-    are computed then sliced away)."""
-    from hyperscalees_t2i_tpu.ops.fused_lora import member_lora_delta, xla_member_lora_delta
-
-    x, a, b = _factored_pair(jax.random.PRNGKey(32))
-    x = x.reshape(-1, x.shape[-1])[:5]  # 5 rows vs block_t=4 → one padded tile
-    ref = xla_member_lora_delta(x, a, b, 1.0)
-    out = member_lora_delta(x, a, b, 1.0, interpret=True, block_t=4)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
-
-
-def test_pallas_flag_falls_back_cleanly_off_tpu():
-    """Default auto-select on the CPU test platform must take the XLA path
-    (no kernel, no error) — the shipped behavior everywhere the env flag or
-    a TPU is absent."""
-    from hyperscalees_t2i_tpu.ops.fused_lora import member_lora_delta, use_fused_pallas, xla_member_lora_delta
-
-    assert not use_fused_pallas()
-    x, a, b = _factored_pair(jax.random.PRNGKey(33))
-    np.testing.assert_array_equal(
-        np.asarray(member_lora_delta(x, a, b, 1.0)),
-        np.asarray(xla_member_lora_delta(x, a, b, 1.0)),
-    )
-    # fused_lora_delta (the dense() entry point) also takes the XLA path here
-    leaf = {"a": a, "b": b}
-    np.testing.assert_allclose(
-        np.asarray(fused_lora_delta(x, leaf, 1.0)),
-        np.asarray(xla_member_lora_delta(x, a, b, 1.0)), rtol=1e-5, atol=1e-5,
-    )
-
-
-# ---------------------------------------------------------------------------
-# the all-knobs-off program is pinned bit-for-bit (StableHLO golden)
-# ---------------------------------------------------------------------------
-
-def _tiny_alloff_stablehlo() -> str:
-    if "hlo" in _TINY_CACHE:  # one abstract lowering serves both pin tests
-        return _TINY_CACHE["hlo"]
-    from hyperscalees_t2i_tpu.rungs import DEFAULT_OPT, RUNG_PLAN
-    from hyperscalees_t2i_tpu.tools.preflight import abstract_step_inputs
-    from hyperscalees_t2i_tpu.train.trainer import make_es_step
-
-    scale, pop, m, mb = RUNG_PLAN["tiny"]
-    (backend, reward_fn, tc, frozen, theta, ids, key_s, nu) = abstract_step_inputs(
-        scale, pop, m, mb, dict(DEFAULT_OPT)
-    )
-    step = make_es_step(backend, reward_fn, tc, nu, 1, None)
-    _TINY_CACHE["hlo"] = step.lower(frozen, theta, ids, key_s).as_text()
-    return _TINY_CACHE["hlo"]
-
-
-def test_alloff_program_stablehlo_pinned():
-    """pop_fuse=False (and every other knob off) must keep lowering the
-    byte-identical program — the golden stores its sha256, stamped with the
-    generating jax version (the test_golden skip convention: XLA lowering
-    drifts across jax releases, which is not a regression of this repo)."""
-    golden_path = GOLDEN / "stablehlo_alloff_tiny.json"
-    txt = _tiny_alloff_stablehlo()
-    sha = hashlib.sha256(txt.encode()).hexdigest()
-    if not golden_path.exists():
-        golden_path.write_text(json.dumps({
-            "sha256": sha, "lines": len(txt.splitlines()),
-            "gen_jax": jax.__version__,
-            "what": "tiny-rung ES step, all optimization knobs off "
-                    "(rungs.DEFAULT_OPT) — the materialized-path parity anchor",
-        }, indent=1))
-        pytest.skip("golden generated on this run; rerun to compare")
-    fixture = json.loads(golden_path.read_text())
-    if fixture.get("gen_jax") != jax.__version__:
-        pytest.skip(
-            f"stablehlo golden was generated under jax {fixture.get('gen_jax')}, "
-            f"running {jax.__version__} — lowering text is version-pinned"
-        )
-    assert fixture["sha256"] == sha, (
-        "the all-knobs-off program changed — pop_fuse=False (and friends) "
-        "must lower the byte-identical materialized-path program; if the "
-        "change is intentional, regenerate the golden and say so in PERF.md"
-    )
-
-
-def test_fused_program_differs_from_materialized():
-    """Sanity complement to the pin: pop_fuse=True lowers a DIFFERENT
-    program (the knob is not a no-op)."""
-    from hyperscalees_t2i_tpu.rungs import DEFAULT_OPT, RUNG_PLAN
-    from hyperscalees_t2i_tpu.tools.preflight import abstract_step_inputs
-    from hyperscalees_t2i_tpu.train.trainer import make_es_step
-
-    scale, pop, m, mb = RUNG_PLAN["tiny"]
-    (backend, reward_fn, tc, frozen, theta, ids, key_s, nu) = abstract_step_inputs(
-        scale, pop, m, mb, {**DEFAULT_OPT, "pop_fuse": True}
-    )
-    assert tc.pop_fuse
-    step = make_es_step(backend, reward_fn, tc, nu, 1, None)
-    txt = step.lower(frozen, theta, ids, key_s).as_text()
-    base = _tiny_alloff_stablehlo()
-    assert hashlib.sha256(txt.encode()).hexdigest() != hashlib.sha256(base.encode()).hexdigest()
+    assert np.ptp(want["mean"], axis=0).max() > 0, "the perturbation never reached an image"
+    for k in ("mean", "contrast"):
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-4, atol=2e-4)

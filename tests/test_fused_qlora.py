@@ -1,27 +1,24 @@
-"""Unified int8-dequant + member-LoRA kernel (ops/fused_qlora.py, round 15).
+"""Int8-dequant + member-LoRA kernel (ops/fused_qlora.py) and ``nn.dense``'s table.
 
 The contract under test, layer by layer:
 
 - **kernel parity** — the Pallas kernel (interpret mode on CPU — the
   ops/attention.py precedent: the CPU tier lowers and *interprets* the
   kernel, only real TPU executes it) matches :func:`xla_fused_qlora`, the
-  byte-identical round-14 composition, across {2D, stacked-3D} × {f32,
+  XLA composition, across {2D, stacked-3D} × {f32,
   bf16 noise factors} × antithetic signs, with tile padding and the
   member-vmap batching pop_eval applies.
-- **dense resolution** — ``nn.dense`` with an int8 node AND FactoredDelta
-  factors resolves through the unified path, bitwise-equal to the old
-  composition on CPU (off the TPU it IS that composition) and within float
-  tolerance of an explicit dequantize-then-materialize reference.
+- **dense resolution** — ``nn.dense``'s table, base node × adapter leaf:
+  every case against the float32 formula, and an int8 node with
+  FactoredDelta factors bitwise-equal to the composition on CPU (off the TPU
+  it IS that composition).
 - **conv contract** — matmul-equivalent ``kernel_q8`` convs (1×1 stride-1,
   non-overlapping p×p stride-p patch embeds) route through the same
-  dequant contract as ``dense``; everything else (overlapping windows,
-  depthwise groups) keeps the dequant-then-conv lowering, and
-  ``HSES_FUSED_QLORA=off`` restores the round-14 program everywhere.
+  dequant-matmul as ``dense``; everything else (overlapping windows,
+  depthwise groups) keeps the dequant-then-conv lowering.
 - **gate mechanics** — the shared ops/pallas_gate env/backend reads every
   kernel gate is built on; no gate probes, none falls back after an error.
 """
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +29,9 @@ from hyperscalees_t2i_tpu.lora import FactoredDelta, slice_layer
 from hyperscalees_t2i_tpu.models import nn
 from hyperscalees_t2i_tpu.ops import pallas_gate
 from hyperscalees_t2i_tpu.ops.fused_qlora import (
-    ROUTING_ENV,
     conv_kernel_q8_matmul,
     fused_qlora_applies,
     fused_qlora_dense,
-    unified_routing_enabled,
     use_fused_qlora_pallas,
     xla_fused_qlora,
 )
@@ -157,7 +152,7 @@ def _formula(x, qk, leaf, lora_scale, ca=None, cb=None):
     """(base, delta) of ``y = x @ (q8·scale) + lora_scale·(x @ a_k) @ b_k`` in
     plain f32 — the formula itself, no code of the system beyond the pytree.
     The dequantized weights are rounded to the activations' dtype first, as
-    the dequant contract says (ops/quant_mm; a no-op for f32 activations)."""
+    ops/quant.dequant_matmul does (a no-op for f32 activations)."""
     f32 = jnp.float32
     w = (qk["q8"].astype(f32) * qk["scale"]).astype(x.dtype).astype(f32)
     x = x.astype(f32)
@@ -708,50 +703,43 @@ def test_gate_default_off_the_tpu_backend(monkeypatch):
 # dense resolution
 # ---------------------------------------------------------------------------
 
-def test_dense_unified_matches_legacy_bitwise_and_materialized():
-    """``nn.dense`` with kernel_q8 + FactoredDelta resolves through the
-    unified path: bitwise-equal to the round-14 composition on CPU (the
-    fallback IS that composition — the ledger gate's premise) and within
-    float tolerance of dequantize-then-materialize."""
-    x, qk, leaf = _factored_pair(jax.random.PRNGKey(45))
-    node = {"kernel_q8": qk, "bias": jnp.linspace(0, 1, 24)}
-    assert fused_qlora_applies(leaf)
-    y = nn.dense(node, x, lora=leaf, lora_scale=2.0)
-    np.testing.assert_array_equal(
-        np.asarray(y),
-        np.asarray(xla_fused_qlora(x, qk, leaf, 2.0) + node["bias"]),
-    )
-
-    base, delta = _formula(x, qk, leaf, 2.0)
-    ref = base + delta + node["bias"]
-    _assert_close(y, ref, tol=1e-4)
-
-
-def test_dense_raw_lora_keeps_legacy_branch():
-    """Raw-array LoRA factors (the materialized path) must NOT take the
-    unified resolution — its HLO is pinned by the all-knobs-off golden."""
-    x, qk, _ = _factored_pair(jax.random.PRNGKey(46))
+@pytest.mark.parametrize("leaf_kind", ["none", "raw", "factored"])
+@pytest.mark.parametrize("base", ["kernel", "kernel_q8"])
+def test_dense_table(base, leaf_kind):
+    """``nn.dense``'s table (its docstring), base node × adapter leaf: each
+    lowering against the float32 formula; off the TPU an int8 base under a
+    member's factored leaf is bit for bit the sum of the two lowerings a site
+    takes with only one of them (:func:`xla_fused_qlora`), and raw factors
+    never reach ``fused_qlora_dense``."""
+    x, qk, factored = _factored_pair(jax.random.PRNGKey(45))
     raw = {"a": jax.random.normal(jax.random.PRNGKey(1), (16, 4)),
            "b": jax.random.normal(jax.random.PRNGKey(2), (4, 24))}
-    assert not fused_qlora_applies(raw)
-    node = {"kernel_q8": qk}
-    y = nn.dense(node, x, lora=raw, lora_scale=2.0)
-    ref = x @ dequantize_kernel(qk, x.dtype) + ((x @ raw["a"]) @ raw["b"]) * 2.0
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(ref))
+    leaf = {"none": None, "raw": raw, "factored": factored}[leaf_kind]
+    bias = jnp.linspace(0, 1, 24)
+    w = dequantize_kernel(qk, jnp.float32)
+    node = {"kernel": w, "bias": bias} if base == "kernel" else {"kernel_q8": qk, "bias": bias}
+    y = nn.dense(node, x, lora=leaf, lora_scale=2.0)
 
+    want = x @ w
+    if leaf_kind == "raw":
+        want = want + ((x @ raw["a"]) @ raw["b"]) * 2.0
+    elif leaf_kind == "factored":
+        want = want + _formula(x, qk, factored, 2.0)[1]
+    want = want + bias
+    _assert_close(y, want, tol=1e-4)
 
-def test_routing_env_off_disables_applies(monkeypatch):
-    monkeypatch.setenv(ROUTING_ENV, "off")
-    assert not unified_routing_enabled()
-    _, qk, leaf = _factored_pair(jax.random.PRNGKey(47))
-    assert not fused_qlora_applies(leaf)
-    monkeypatch.setenv(ROUTING_ENV, "1")
-    assert unified_routing_enabled()
-    assert fused_qlora_applies(leaf)
+    assert fused_qlora_applies(factored) and not fused_qlora_applies(raw)
+    if base == "kernel_q8" and leaf_kind == "factored":
+        np.testing.assert_array_equal(
+            np.asarray(y), np.asarray(xla_fused_qlora(x, qk, factored, 2.0) + bias)
+        )
+    elif leaf_kind != "factored":
+        # no member's leaf: the program is the plain one, bit for bit
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
-# conv/patch-embed: the same dequant contract as dense
+# conv/patch-embed: the same dequant-matmul as dense
 # ---------------------------------------------------------------------------
 
 def _conv_ref(x, qk, stride=1, padding="SAME"):
@@ -786,9 +774,9 @@ def test_conv_patch_embed_routes_im2col(padding):
     assert "convolution" not in routed
 
 
-def test_conv_nonequivalent_keeps_conv_lowering(monkeypatch):
+def test_conv_nonequivalent_keeps_conv_lowering():
     """Overlapping windows, depthwise groups, and a non-divisible grid keep
-    the dequant-then-conv path — bitwise the HSES_FUSED_QLORA=off program."""
+    the dequant-then-conv path, bit for bit."""
     x = jax.random.normal(jax.random.PRNGKey(54), (2, 8, 8, 16))
     q3 = quantize_kernel(jax.random.normal(jax.random.PRNGKey(55), (3, 3, 16, 12)) * 0.1)
     assert conv_kernel_q8_matmul(x, q3, 1, "SAME", 1) is None
@@ -800,56 +788,27 @@ def test_conv_nonequivalent_keeps_conv_lowering(monkeypatch):
     # 5×5 stride 5 on an 8-grid: patches would straddle the edge → conv path
     q5 = quantize_kernel(jax.random.normal(jax.random.PRNGKey(57), (5, 5, 16, 12)) * 0.1)
     assert conv_kernel_q8_matmul(x, q5, 5, "SAME", 1) is None
-    # routing off restores the conv lowering for the matmul-equivalent case
-    q1 = quantize_kernel(jax.random.normal(jax.random.PRNGKey(58), (1, 1, 16, 12)) * 0.1)
-    monkeypatch.setenv(ROUTING_ENV, "off")
-    assert conv_kernel_q8_matmul(x, q1, 1, "SAME", 1) is None
-    off_text = jax.jit(lambda v: nn.conv2d({"kernel_q8": q1}, v)).lower(x).as_text()
-    assert "convolution" in off_text
-
-
-def test_routing_shapes_the_q8_step_program():
-    """The unified routing is not a no-op on an int8+fused ES-step program
-    (the ledger-diff columns compare real alternatives), while the all-off
-    tiny program — no kernel_q8 anywhere — is untouched by the knob (the
-    StableHLO golden in test_fused.py stays the authority)."""
-    import os
-
-    from hyperscalees_t2i_tpu.ops.quant import MIN_SIZE_ENV
-    from hyperscalees_t2i_tpu.rungs import DEFAULT_OPT, RUNG_PLAN
-    from hyperscalees_t2i_tpu.tools.preflight import abstract_step_inputs
-    from hyperscalees_t2i_tpu.train.trainer import make_es_step
-
-    scale, pop, m, mb = RUNG_PLAN["tiny"]
-
-    def lower_text(routing: str) -> str:
-        old_route = os.environ.get(ROUTING_ENV)
-        old_floor = os.environ.get(MIN_SIZE_ENV)
-        os.environ[ROUTING_ENV] = routing
-        os.environ[MIN_SIZE_ENV] = "1"  # tiny layers quantize for the probe
-        try:
-            (backend, reward_fn, tc, frozen, theta, ids, key_s, nu) = (
-                abstract_step_inputs(
-                    scale, pop, m, mb,
-                    {**DEFAULT_OPT, "pop_fuse": True, "base_quant": "int8"},
-                )
-            )
-            step = make_es_step(backend, reward_fn, tc, nu, 1, None)
-            return step.lower(frozen, theta, ids, key_s).as_text()
-        finally:
-            for k, v in ((ROUTING_ENV, old_route), (MIN_SIZE_ENV, old_floor)):
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-
-    sha = lambda t: hashlib.sha256(t.encode()).hexdigest()
-    assert sha(lower_text("1")) != sha(lower_text("off"))
 
 
 # ---------------------------------------------------------------------------
 # shared gate mechanics (ops/pallas_gate.py)
 # ---------------------------------------------------------------------------
+
+def test_the_environment_switches_the_source_names():
+    """Two kernel opt-outs and one size override: every ``HSES_*`` name in the
+    package and the chip smoke. A fourth is a new option: it needs its reason
+    (PERF.md) and this list."""
+    import re
+    from pathlib import Path
+
+    import hyperscalees_t2i_tpu
+
+    pkg = Path(hyperscalees_t2i_tpu.__file__).parent
+    sources = [*pkg.rglob("*.py"), pkg.parent / "chip_smoke.py"]
+    names = {n for f in sources for n in re.findall(r"HSES_[A-Z0-9_]+", f.read_text())}
+    assert names == {"HSES_FUSED_QLORA_PALLAS", "HSES_USE_PALLAS", "HSES_BASE_QUANT_MIN_SIZE"}
+    assert set(pallas_gate.PALLAS_ENV_FLAGS) == names - {"HSES_BASE_QUANT_MIN_SIZE"}
+
 
 def test_env_requested_tristate(monkeypatch):
     monkeypatch.delenv("HSES_TEST_FLAG", raising=False)
@@ -873,27 +832,26 @@ def test_active_flags_and_marks(monkeypatch):
     assert pallas_gate.pallas_flag_marks({}) == ""
     from hyperscalees_t2i_tpu.rungs import kernel_marks
 
-    rec = {"pop_fuse": True, "pallas_env": {"HSES_FUSED_QLORA_PALLAS": "1"}}
-    assert kernel_marks(rec) == ["fuse", "P:qlora"]
+    rec = {"base_quant": "int8", "pallas_env": {"HSES_FUSED_QLORA_PALLAS": "1"}}
+    assert kernel_marks(rec) == ["q8", "P:qlora"]
 
 
 def _as_tpu(monkeypatch, on: bool):
     """Every gate module binds backend_is_tpu at import; flip them all."""
-    from hyperscalees_t2i_tpu.ops import fused_lora, fused_qlora, quant_mm
+    from hyperscalees_t2i_tpu.ops import fused_qlora
 
-    for mod in (pallas_gate, fused_qlora, fused_lora, quant_mm):
+    for mod in (pallas_gate, fused_qlora):
         monkeypatch.setattr(mod, "backend_is_tpu", lambda: on)
 
 
 def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     """Selection is by platform (and, per layer, shape) plus the one
-    tri-state flag: default-ON kernels are on exactly on a TPU backend unless
-    opted out, opt-in kernels exactly on a TPU backend when asked for, and a
-    request never forces a kernel onto a backend that cannot run Mosaic."""
+    tri-state flag: both kernels are on exactly on a TPU backend unless
+    opted out, and a request never forces a kernel onto a backend that cannot
+    run Mosaic."""
     for f in pallas_gate.PALLAS_ENV_FLAGS:
         monkeypatch.delenv(f, raising=False)
-    off = {"fused_qlora": False, "decode_attention": False,
-           "member_lora_delta": False, "int8_matmul": False}
+    off = {"fused_qlora": False, "decode_attention": False}
     assert pallas_gate.selected_kernels() == off
     for f in pallas_gate.PALLAS_ENV_FLAGS:  # =1 off the TPU selects nothing
         monkeypatch.setenv(f, "1")
@@ -903,10 +861,7 @@ def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     assert pallas_gate.selected_kernels() == {k: True for k in off}
     for f in pallas_gate.PALLAS_ENV_FLAGS:
         monkeypatch.delenv(f)
-    assert pallas_gate.selected_kernels() == {
-        "fused_qlora": True, "decode_attention": True,
-        "member_lora_delta": False, "int8_matmul": False,
-    }
+    assert pallas_gate.selected_kernels() == {k: True for k in off}
     # the opt-out wins where the kernel is the backend default — the
     # pallas_env stamp ("flash-") has to describe the path that ran
     monkeypatch.setenv("HSES_USE_PALLAS", "0")

@@ -300,7 +300,7 @@ def test_train_cli_lm_ar_two_epochs(tmp_path, monkeypatch):
     prompts.write_text("a red square on a table\na blue circle\nthree green triangles in a row\n")
     cli.main([
         "--backend", "lm_ar", "--lm_config", str(tmp_path / "config.json"), "--model_scale", "tiny",
-        "--prompts_txt", str(prompts), "--pop_fuse", "true", "--base_quant", "int8",
+        "--prompts_txt", str(prompts), "--base_quant", "int8",
         "--noise_dtype", "bfloat16", "--sigma", "0.5", "--lora_r", "2", "--lora_alpha", "4",
         "--pop_size", "4", "--prompts_per_gen", "2", "--member_batch", "2",
         "--num_epochs", "2", "--allow_random_rewards", "true",

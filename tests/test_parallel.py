@@ -14,6 +14,7 @@ from hyperscalees_t2i_tpu.es import (
     perturb_member,
     sample_noise,
 )
+from hyperscalees_t2i_tpu.lora import effective_factor
 from hyperscalees_t2i_tpu.parallel import (
     POP_AXIS,
     all_gather_ragged,
@@ -39,10 +40,12 @@ def _toy_generate(theta, flat_ids, key, item_index=None):
     # Deterministic "generation": tiny function of theta + per-item noise.
     # Per-item keys fold in the *global* position so outputs are invariant to
     # chunking/data-sharding (the framework-wide item_index contract).
+    # A member's matrix leaves arrive factored (lora.FactoredDelta); a
+    # generator that reads a leaf itself builds it as nn.dense's helpers do.
     idx = jnp.arange(flat_ids.shape[0]) if item_index is None else item_index
     keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(idx)
     noise = jax.vmap(lambda k: jax.random.normal(k, (4,)))(keys)
-    feat = jnp.tanh(noise @ theta["w1"][:4, :] + theta["b"])
+    feat = jnp.tanh(noise @ effective_factor(theta["w1"], jnp.float32)[:4, :] + theta["b"])
     return feat * (1.0 + flat_ids[:, None].astype(jnp.float32))
 
 

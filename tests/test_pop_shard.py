@@ -4,11 +4,9 @@ The contract under test: ``--pop_shard_update on`` computes each pop shard's
 fitness-weighted noise sum over its contiguous base slice only and one psum
 over the pop axis rebuilds the full Δθ — the θ trajectory matches the
 replicated update within tight f32 tolerance on a 2×2 pop×data mesh
-(composing with ``pop_fuse`` and ``noise_dtype=bfloat16``), ``auto`` falls
-back to replicated exactly when the base-sample count does not tile the pop
-axis, and ``off`` keeps lowering the replicated program (whose mesh-less
-form is pinned bit-for-bit by the all-knobs-off StableHLO golden in
-tests/test_fused.py).
+(under either ``noise_dtype``), ``auto`` falls back to replicated exactly when
+the base-sample count does not tile the pop axis, and ``off`` keeps lowering
+the replicated program.
 """
 
 import jax
@@ -50,9 +48,8 @@ def _toy_theta():
 
 
 def _mat(leaf):
-    """Under pop_fuse the member's adapter arrives as FactoredDelta leaves;
-    materialize like the real consumers (lora.effective_factor) do so one
-    toy generator serves both evaluator modes."""
+    """A member's adapter arrives as FactoredDelta leaves; materialize like
+    the real consumers (lora.effective_factor) do. Raw leaves pass through."""
     from hyperscalees_t2i_tpu.lora import FactoredDelta, effective_factor
 
     return (
@@ -174,19 +171,16 @@ def _run_steps(tc, mesh, epochs=3):
     return theta, np.asarray(scores)
 
 
-# the two cells compose the sharded update with the PR-7 fused member path
-# and the bf16 noise store — the knob interactions the ISSUE names
-@pytest.mark.parametrize(
-    "pop_fuse,noise_dtype", [(False, "float32"), (True, "bfloat16")],
-)
-def test_step_trajectory_parity_2x2(pop_fuse, noise_dtype):
+# the sharded update under both noise stores
+@pytest.mark.parametrize("noise_dtype", ["float32", "bfloat16"])
+def test_step_trajectory_parity_2x2(noise_dtype):
     mesh = make_mesh({"pop": 2, "data": 2})
     out = {}
     for mode in ("off", "on"):
         tc = TrainConfig(
             pop_size=8, sigma=0.05, egg_rank=2, prompts_per_gen=3,
             batches_per_gen=2, member_batch=4, promptnorm=True,
-            pop_fuse=pop_fuse, noise_dtype=noise_dtype, pop_shard_update=mode,
+            noise_dtype=noise_dtype, pop_shard_update=mode,
         )
         out[mode] = _run_steps(tc, mesh)
     t_off, s_off = out["off"]

@@ -145,14 +145,11 @@ def test_conv2d_consumes_quantized_node():
     np.testing.assert_allclose(np.asarray(yq), np.asarray(y), atol=0.08)
 
 
-def test_dense_and_kernel_shape_on_quantized():
+def test_kernel_shape_on_quantized():
+    """(``nn.dense`` over a quantized node: tests/test_fused_qlora.py's table.)"""
     node = {"kernel": jax.random.normal(jax.random.PRNGKey(4), (64, 32)) * 0.2,
             "bias": jnp.zeros(32)}
     qnode = quantize_tree({"d": node}, min_size=1)["d"]
-    x = jax.random.normal(jax.random.PRNGKey(5), (3, 64))
-    np.testing.assert_allclose(
-        np.asarray(nn.dense(qnode, x)), np.asarray(nn.dense(node, x)), atol=0.05
-    )
     assert kernel_shape(node) == (64, 32)
     assert kernel_shape(qnode) == (64, 32)
     assert nn.kernel_shape(qnode) == (64, 32)
@@ -171,6 +168,34 @@ def test_slice_stacked_int8():
     np.testing.assert_array_equal(
         np.asarray(sl["kernel_q8"]["q8"]), np.asarray(per_layer["q8"])
     )
+
+
+@pytest.mark.parametrize("family", ["msvq", "bsq"])
+def test_phi_apply_consumes_quantized_node(family):
+    """The residual-blend φ convs are a stacked-conv node like any other: with
+    the floor low enough to quantize them ``phi_apply`` raised ``KeyError:
+    'kernel'`` (it indexed the float kernel behind ops/quant.py's back), in
+    VAR's MSVQ and in Infinity's BSQ alike."""
+    from hyperscalees_t2i_tpu.models import bsq, msvq
+
+    if family == "msvq":
+        mod, cfg = msvq, msvq.MSVQConfig(
+            vocab_size=16, c_vae=8, patch_nums=(1, 2, 4), phi_partial=2, ch=8,
+            ch_mult=(1, 1), num_res_blocks=1, compute_dtype=jnp.float32)
+        params, C = msvq.init_msvq(jax.random.PRNGKey(0), cfg), cfg.c_vae
+    else:
+        mod, cfg = bsq, bsq.BSQConfig(
+            bits=4, patch_nums=(1, 2, 4), phi_partial=2, dec_ch=(8, 8), compute_dtype=jnp.float32)
+        params, C = bsq.init_bsq(jax.random.PRNGKey(0), cfg), cfg.bits
+    q = quantize_tree(params, min_size=1)
+    assert "kernel_q8" in q["phi"] and q["phi"]["kernel_q8"]["q8"].shape == (2, 3, 3, C, C)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 4, C))
+    for si in range(cfg.num_scales):
+        want = mod.phi_apply(params, cfg, h, si)
+        got = mod.phi_apply(q, cfg, h, si)
+        # 3·3·C MACs of ≤ scale/2 error each, halved by the blend
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0.05)
+        assert not np.array_equal(np.asarray(got), np.asarray(h))
 
 
 def test_glumb_conv_quantized_groups():
@@ -302,54 +327,3 @@ def test_reward_rows_and_theta_trajectory_int8_base(tmp_path, monkeypatch):
     # stay in the same basin (measured drift ~1e-2 of ‖θ‖ over 4 epochs)
     assert drift < 0.25, drift
     assert np.all(np.isfinite(th_q))
-
-
-# ---------------------------------------------------------------------------
-# Pallas int8-dequant matmul (HSES_BASE_QUANT_PALLAS) — interpret-mode parity
-# ---------------------------------------------------------------------------
-
-def test_pallas_int8_matmul_interpret_parity():
-    from hyperscalees_t2i_tpu.ops.quant_mm import int8_matmul, xla_int8_matmul
-
-    w = jax.random.normal(jax.random.PRNGKey(10), (48, 40)) * 0.1
-    qk = quantize_kernel(w)
-    x = jax.random.normal(jax.random.PRNGKey(11), (2, 5, 48))
-    ref = xla_int8_matmul(x, qk["q8"], qk["scale"])
-    out = int8_matmul(x, qk["q8"], qk["scale"], interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    # tile padding: token count not divisible by the block
-    x2 = x.reshape(-1, 48)[:7]
-    out2 = int8_matmul(x2, qk["q8"], qk["scale"], interpret=True, block_t=4)
-    np.testing.assert_allclose(
-        np.asarray(out2), np.asarray(xla_int8_matmul(x2, qk["q8"], qk["scale"])),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
-def test_pallas_int8_flag_falls_back_cleanly_off_tpu():
-    """Default auto-select on the CPU test platform must take the XLA path
-    (no kernel, no error) — and nn.dense consumes quantized nodes the same
-    way with the flag unset."""
-    from hyperscalees_t2i_tpu.ops.quant_mm import (
-        int8_matmul,
-        use_base_quant_pallas,
-        xla_int8_matmul,
-    )
-
-    assert not use_base_quant_pallas()
-    w = jax.random.normal(jax.random.PRNGKey(12), (32, 24)) * 0.1
-    qk = quantize_kernel(w)
-    x = jax.random.normal(jax.random.PRNGKey(13), (3, 32))
-    np.testing.assert_array_equal(
-        np.asarray(int8_matmul(x, qk["q8"], qk["scale"])),
-        np.asarray(xla_int8_matmul(x, qk["q8"], qk["scale"])),
-    )
-    # GGUF block-scale nodes always take the XLA path (kernel is
-    # per-channel-only) — exercised via int8_matmul's own guard
-    bs = {"q8": qk["q8"], "scale": jnp.tile(qk["scale"], (2, 1)) }
-    out = int8_matmul(x, bs["q8"], bs["scale"], use_pallas=True, interpret=False)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(xla_int8_matmul(x, bs["q8"], bs["scale"])),
-        rtol=1e-6, atol=1e-6,
-    )

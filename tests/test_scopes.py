@@ -255,7 +255,7 @@ def test_compiled_step_carries_every_scope(family, want, tmp_path):
     backend.setup()
     reward_fn = _tiny_clip_reward(backend)
     tc = TrainConfig(pop_size=4, sigma=0.05, egg_rank=2, prompts_per_gen=2, member_batch=2,
-                     pop_fuse=True, promptnorm=True, run_dir=str(tmp_path / "runs"))
+                     promptnorm=True, run_dir=str(tmp_path / "runs"))
     step = make_es_step(backend, reward_fn, tc, 2, 1, None, stateful_delta=True)
     theta = backend.init_theta(jax.random.PRNGKey(0))
     zeros = jax.tree_util.tree_map(jnp.zeros_like, theta)
@@ -287,7 +287,7 @@ def _cli_run(out, trace: bool, monkeypatch):
     monkeypatch.setenv("HSES_BASE_QUANT_MIN_SIZE", "1")  # toy kernels still go int8
     cli.main([
         "--backend", "sana_one_step", "--model_scale", "tiny",
-        "--pop_fuse", "true", "--base_quant", "int8",
+        "--base_quant", "int8",
         "--pop_size", "4", "--prompts_per_gen", "2", "--member_batch", "2",
         "--num_epochs", "2", "--allow_random_rewards", "true",
         "--run_dir", str(out), "--run_name", "run", "--resume", "false",
