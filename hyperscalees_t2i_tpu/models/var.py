@@ -12,10 +12,11 @@ TPU-first redesign (NOT a port):
   accumulation + decode compiles into ONE XLA program (the reference runs 10
   eager transformer passes with growing tensor shapes, var.py:160-187);
 - block params are stacked ``[depth, ...]`` and consumed by ``lax.scan`` —
-  one trace for any depth; the KV cache is a preallocated
-  ``[depth, B, L, H, dh]`` buffer written with static offsets (the standard
-  JAX decode idiom, replacing torch's dynamically-growing ``torch.cat`` cache,
-  basic_var.py:85-109);
+  one trace for any depth; the KV cache is the row blocks written so far,
+  ``[depth, B, n_s, H, dh]`` a scale, and grows by a block at a time with
+  static shapes (torch's growing ``torch.cat`` cache, basic_var.py:85-109,
+  without the copy; a preallocated ``[.., L, ..]`` buffer carried through
+  the scan cost a fill and a copy of the whole of it every scale);
 - CFG runs as a fused ``2B`` batch (cond rows then uncond rows) with the
   per-scale ramp ``t = cfg·si/(S-1)`` applied to the logit pair
   (var.py:172-173);
@@ -33,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lora import LoRASpec, lookup, slice_layer
+from ..obs import note_program_geometry
 from ..ops.attention import decode_attention
 from ..ops.quant import resolve_kernel
 from ..ops.sampling import sample_top_k_top_p
@@ -135,15 +137,25 @@ def _blocks_step(
     cfg: VARConfig,
     x: jax.Array,  # [B2, n, d] current scale's token activations
     cond6_all: jax.Array,  # [depth, B2, 6, d] precomputed AdaLN modulation
-    caches: Tuple[jax.Array, jax.Array],  # K,V: [depth, B2, L, H, dh]
+    caches: Tuple[Any, Any],  # K, V: the row blocks written so far, [depth, B2, n_s, H, dh] each
     pos: int,  # static prefix length
     lora: Optional[Params],
     lora_scale: float,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Run all transformer blocks on one scale's tokens, updating the cache.
+) -> Tuple[jax.Array, Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]]:
+    """Run all transformer blocks on one scale's tokens, growing the cache.
 
-    ``pos`` is static (Python int) per scale, so cache writes/reads lower to
-    static-slice ops. Layers run under ``lax.scan`` with stacked params.
+    ``pos`` is static (Python int) per scale, so cache reads lower to
+    static-slice ops. Layers run under ``lax.scan`` with stacked params. The
+    cache is append-only and kept as it was written: each of K and V is the
+    tuple of the earlier scales' row blocks (a single array stands for one
+    block, of which the first ``pos`` rows count). The scan reads them,
+    returns this scale's ``n`` rows a layer, and that block is appended — no
+    row is copied again after the scale that wrote it, except into the
+    operand a layer's attention reads. A cache preallocated at the whole
+    sequence's length and passed through the scan is filled and copied whole
+    once a scale, and each layer's whole cache once a layer: a scan's outputs
+    are a fresh zero-filled buffer and its inputs are not the loop's to
+    overwrite.
     """
     d, H, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     B2, n, _ = x.shape
@@ -152,7 +164,7 @@ def _blocks_step(
 
     def layer(carry, inp):
         x, = carry
-        li, kC, vC, cond6 = inp  # kC/vC: [B2, L, H, dh] this layer's cache
+        li, kP, vP, cond6 = inp  # kP/vP: this layer's row blocks so far, [B2, n_s, H, dh] each
         g1, s1, b1, g2, s2, b2 = (cond6[:, i][:, None, :] for i in range(6))
 
         h = nn.layer_norm(x) * (1.0 + s1.astype(dt)) + b1.astype(dt)
@@ -169,13 +181,15 @@ def _blocks_step(
             # reference uses 0.25/sqrt(dh) in the non-l2 branch
             # (VAR_models/basic_var.py:72), not the usual 1/sqrt(dh)
             sm_scale = 0.25 / math.sqrt(dh)
-        kC = jax.lax.dynamic_update_slice(kC, k.astype(kC.dtype), (0, pos, 0, 0))
-        vC = jax.lax.dynamic_update_slice(vC, v.astype(vC.dtype), (0, pos, 0, 0))
+        k, v = k.astype(dt), v.astype(dt)
         # visible context: all written positions [0, pos+n) (static kv_len).
         # Pallas flash path on TPU keeps the logit tile in VMEM instead of a
         # [B2, H, n, L] f32 HBM tensor per scale (ops/attention.py).
         out = (
-            decode_attention(q, kC, vC, kv_len=pos + n, sm_scale=sm_scale)
+            decode_attention(
+                q, jnp.concatenate([*kP, k], axis=1), jnp.concatenate([*vP, v], axis=1),
+                kv_len=pos + n, sm_scale=sm_scale,
+            )
             .astype(dt)
             .reshape(B2, n, d)
         )
@@ -191,15 +205,15 @@ def _blocks_step(
         h2 = nn.dense(fc2_p, h2, slice_layer(lookup(lora, "blocks/fc2"), li), lora_scale)
         x = x + g2.astype(dt) * h2.astype(dt)
 
-        return (x,), (kC, vC)
+        return (x,), (k, v)
 
-    kAll, vAll = caches
-    (x,), (kAll, vAll) = jax.lax.scan(
+    kPrev, vPrev = (c if isinstance(c, tuple) else (c[:, :, :pos],) for c in caches)
+    (x,), (kNew, vNew) = jax.lax.scan(
         layer,
         (x.astype(dt),),
-        (jnp.arange(cfg.depth), kAll, vAll, cond6_all),
+        (jnp.arange(cfg.depth), kPrev, vPrev, cond6_all),
     )
-    return x, (kAll, vAll)
+    return x, (kPrev + (kNew,), vPrev + (vNew,))
 
 
 def generate(
@@ -249,8 +263,11 @@ def generate(
         # head AdaLN (scale, shift) from the same cond (AdaLNBeforeHead).
         hs, hb = jnp.split(nn.dense(params["head_ada"], jax.nn.silu(cond)), 2, axis=-1)
 
-        kC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
-        vC = jnp.zeros((cfg.depth, 2 * B, L, H, dh), dt)
+        # the KV cache starts empty and grows a block of rows a scale (_blocks_step)
+        kC, vC = (), ()
+        # for the program record of the enclosing compile: the cache's shape
+        # when full, whose fills and copies obs/xla_cost.kv_cache_whole_ops counts
+        note_program_geometry(kv_cache_shape=(cfg.depth, 2 * B, L, H, dh))
         f_hat = jnp.zeros((B, vq_cfg.grid, vq_cfg.grid, vq_cfg.c_vae), jnp.float32)
 
         # first scale input: sos from class embedding + start/level/pos tables

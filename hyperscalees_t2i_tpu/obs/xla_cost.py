@@ -347,6 +347,14 @@ UNATTRIBUTED = "unattributed"
 INFERRED = "~"  # prefix of a table entry that scope_table inferred from the graph
 
 
+# How scope_table and kv_cache_whole_ops read ``compiled.as_text()``: the
+# computations fusions call, a computation's opening line, and an
+# instruction's opcode (the first `word(` after its result shape).
+_FUSED_CALLEE = r"\bfusion\(.*?\bcalls=%?([\w.\-]+)"
+_COMPUTATION_HEAD = r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$"
+_OPCODE = r"\s([a-z][\w\-]*)\("
+
+
 def scope_of(op_name: str) -> str:
     """Innermost vocabulary path in an instruction's ``op_name`` metadata:
     ``jit(f)/while/body/closed_call/vmap(generate)/dit_ffn/mul`` →
@@ -396,10 +404,10 @@ def scope_table(compiled: Any) -> Dict[str, str]:
     import os.path
     import re
 
-    fused = set(re.findall(r"\bfusion\(.*?\bcalls=%?([\w.\-]+)", text))
-    head = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+    fused = set(re.findall(_FUSED_CALLEE, text))
+    head = re.compile(_COMPUTATION_HEAD)
     inst = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
-    opcode = re.compile(r"\s([a-z][\w\-]*)\(")  # the first `word(` after the shape
+    opcode = re.compile(_OPCODE)
     op_name = re.compile(r'op_name="([^"]*)"')
     control = ("while", "call", "conditional")
     plumbing = control + ("tuple", "get-tuple-element", "parameter", "constant")
@@ -459,6 +467,72 @@ def scope_table(compiled: Any) -> Dict[str, str]:
                 inferred[name] = INFERRED + got
     table.update(inferred)
     return table
+
+
+def kv_cache_whole_ops(compiled: Any, cache_shape: Any) -> Dict[str, int]:
+    """Instructions of the optimized HLO module, by opcode, whose result is
+    as large as a generator's whole KV cache: the K or V stack as the model
+    keeps it (``cache_shape``, VAR: ``[depth, 2B, L, H, dh]``, noted at trace
+    time as ``kv_cache_shape``) or one layer of it (``cache_shape[1:]``),
+    with or without one further axis (the member axis of a ``vmap``ped
+    chunk). What a scale of generation needs is its own rows; each op counted
+    here fills, copies or re-lays the cache whole — or, as a
+    ``dynamic-update-slice``, writes rows into it in place, which is why the
+    count is by opcode. A fusion is named by its root,
+    ``fusion(dynamic-update-slice)``; a tuple result counts when an element
+    has the shape (a ``while`` that carries the cache). Instructions inside
+    fused computations never run as ops of their own and are left out, as are
+    those that move nothing (``parameter``, ``tuple``, ``get-tuple-element``,
+    ``bitcast``) and the ``-start`` half of an async pair. ``{}`` when the
+    backend has no ``as_text``."""
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return {}
+    import re
+
+    stack = tuple(int(d) for d in cache_shape)
+    wanted = (stack, stack[1:])
+
+    def whole(dims: tuple) -> bool:
+        return dims in wanted or any(
+            dims[:i] + dims[i + 1:] in wanted for i in range(len(dims))
+        )
+
+    fused = set(re.findall(_FUSED_CALLEE, text))
+    head = re.compile(_COMPUTATION_HEAD)
+    inst = re.compile(r"^\s+(ROOT\s+)?%?[\w.\-]+\s+=\s")
+    opcode = re.compile(_OPCODE)
+    calls = re.compile(r"\bcalls=%?([\w.\-]+)")
+    free = ("parameter", "tuple", "get-tuple-element", "bitcast")
+    roots: Dict[str, str] = {}
+    found = []  # (opcode, fused computation called or None)
+    current = None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = head.match(line)
+            current = m.group(1) if m else current
+            continue
+        m = inst.match(line)
+        op = opcode.search(line, m.end() - 1) if m else None
+        if op is None:
+            continue
+        if m.group(1):
+            roots[current] = op.group(1)
+        if current in fused or op.group(1) in free or op.group(1).endswith("-start"):
+            continue
+        result = line[m.end():op.start()]
+        if any(
+            whole(tuple(int(d) for d in dims.split(",") if d))
+            for dims in re.findall(r"[a-z][a-z0-9]*\[([0-9,]*)\]", result)
+        ):
+            callee = calls.search(line) if op.group(1) == "fusion" else None
+            found.append((op.group(1), callee.group(1) if callee else None))
+    counts: Dict[str, int] = {}
+    for op, callee in found:
+        name = f"fusion({roots.get(callee, '?')})" if op == "fusion" else op
+        counts[name] = counts.get(name, 0) + 1
+    return counts
 
 
 # ops through which the dequant dataflow cone propagates (elementwise /
@@ -831,8 +905,9 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
     the compiler's peak as an ``obs/`` gauge (→ next ``metrics.jsonl`` row).
     With the tracer enabled, also write the program's op → scope table
     (:func:`scope_table`) to ``scopes/<label>.json`` beside the ledger and
-    name it in the record. The one call every compile site makes. Never
-    raises."""
+    name it in the record, and count the ops as large as the KV cache a
+    generator noted (:func:`kv_cache_whole_ops`). The one call every compile
+    site makes. Never raises."""
     try:
         rec = program_record(**kwargs)
     except Exception:
@@ -850,6 +925,9 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
                 path.parent.mkdir(parents=True, exist_ok=True)
                 path.write_text(json.dumps(table, sort_keys=True))
                 rec["scope_table"] = str(rel)
+            cache_shape = rec["geometry"].get("kv_cache_shape")
+            if cache_shape:
+                rec["kv_cache_whole_ops"] = kv_cache_whole_ops(compiled, cache_shape)
     except Exception:
         pass
     ledger.write(rec)

@@ -390,3 +390,66 @@ def test_trainer_run_writes_programs_ledger(tmp_path):
     assert run_report.main([str(run_dir)]) == 0
     html_text = (run_dir / "run_report.html").read_text()
     assert "Roofline" in html_text and "es_step_" in html_text
+
+
+# ---------------------------------------------------------------------------
+# kv_cache_whole_ops: ops of the optimized module as large as a KV cache
+# ---------------------------------------------------------------------------
+
+_KV_HLO = """HloModule m
+
+%fused_dus (p0: bf16[4,16,8,680,16,64], p1: bf16[4,16,8,4,16,64]) -> bf16[4,16,8,680,16,64] {
+  %p0 = bf16[4,16,8,680,16,64]{5,4,3,2,1,0} parameter(0)
+  %p1 = bf16[4,16,8,4,16,64]{5,4,3,2,1,0} parameter(1)
+  %c = s32[] constant(0)
+  %inner = bf16[4,16,8,680,16,64]{5,4,3,2,1,0} copy(%p0)
+  ROOT %dus = bf16[4,16,8,680,16,64]{5,4,3,2,1,0} dynamic-update-slice(%inner, %p1, %c, %c, %c, %c, %c, %c)
+}
+
+%body (t: (s32[], bf16[16,4,8,680,16,64])) -> (s32[], bf16[16,4,8,680,16,64]) {
+  %t = (s32[], bf16[16,4,8,680,16,64]{5,4,3,2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[] get-tuple-element(%t), index=0
+  %k = bf16[16,4,8,680,16,64]{5,4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%t), index=1
+  %layer = bf16[4,8,680,16,64]{4,3,2,1,0:T(8,128)(2,1)} copy-done(%start)
+  %start = (bf16[4,8,680,16,64]{4,3,2,1,0}, bf16[4,8,680,16,64]{4,3,2,1,0}, u32[]) copy-start(%view)
+  %view = bf16[4,8,680,1024]{3,2,1,0} bitcast(%layer)
+  %prefix = bf16[4,8,424,16,64]{4,3,2,1,0} slice(%layer), slice={[0:4], [0:8], [0:424], [0:16], [0:64]}
+  ROOT %out = (s32[], bf16[16,4,8,680,16,64]{5,4,3,2,1,0}) tuple(%i, %k)
+}
+
+ENTRY %main (a: bf16[4,16,8,4,16,64]) -> bf16[8,680,16,64] {
+  %a = bf16[4,16,8,4,16,64]{5,4,3,2,1,0} parameter(0)
+  %zero = bf16[] constant(0)
+  %fill = bf16[4,16,8,680,16,64]{5,4,3,2,1,0:T(8,128)(2,1)} broadcast(%zero), dimensions={}
+  %fill2 = bf16[16,4,8,680,16,64]{5,4,3,2,1,0} broadcast(%zero), dimensions={}
+  %i0 = s32[] constant(0)
+  %init = (s32[], bf16[16,4,8,680,16,64]{5,4,3,2,1,0}) tuple(%i0, %fill2)
+  %loop = (s32[], bf16[16,4,8,680,16,64]{5,4,3,2,1,0}) while(%init), condition=%cond, body=%body
+  %write = bf16[4,16,8,680,16,64]{5,4,3,2,1,0} fusion(%fill, %a), kind=kLoop, calls=%fused_dus
+  ROOT %one = bf16[8,680,16,64]{3,2,1,0} copy(%somewhere)
+}
+"""
+
+
+@pytest.mark.parametrize("opcode, want, why", [
+    ("broadcast", 2, "the stack with the member axis in front of the depth or behind it"),
+    ("fusion(dynamic-update-slice)", 1, "a fusion goes by its root; what is inside it is no op of its own"),
+    ("while", 1, "a tuple result that carries the stack"),
+    ("copy-done", 1, "a layer of the stack under the member axis; its -start half is the same transfer"),
+    ("copy", 1, "a layer without a member axis; the copy inside the fusion is not counted"),
+    ("slice", 0, "a prefix is not the whole"),
+    ("bitcast", 0, "moves nothing"), ("get-tuple-element", 0, "moves nothing"),
+    ("parameter", 0, "moves nothing"), ("tuple", 0, "moves nothing"), ("copy-start", 0, "counted at its -done"),
+])
+def test_kv_cache_whole_ops_counts_by_opcode(opcode, want, why):
+    class Fake:
+        def as_text(self):
+            return _KV_HLO
+
+    counts = xla_cost.kv_cache_whole_ops(Fake(), (16, 8, 680, 16, 64))
+    assert counts.get(opcode, 0) == want, (why, counts)
+    assert sum(counts.values()) == 6
+
+
+def test_kv_cache_whole_ops_tolerates_backends_without_hlo_text():
+    assert xla_cost.kv_cache_whole_ops(object(), (16, 8, 680, 16, 64)) == {}
