@@ -1,5 +1,8 @@
 """``lm_ar``: a sparse-expert decoder language model as an autoregressive
-image-token generator under ES (models/lm.py).
+image-token generator under ES (models/lm.py; the family is the one the
+``model_type`` of ``--lm_config`` names: MLA over a latent cache, or
+``qwen3_next``'s Gated DeltaNet layers beside gated attention,
+models/lm_hybrid.py).
 
 A mechanism's backend, not a model's: sizes come from the ``config.json``-shaped
 file ``--lm_config`` names (the model's published keys plus the share of the
@@ -7,7 +10,8 @@ deployment this chip holds), prompts are token ids (``--prompt_token_ids``: a
 JSON file ``{"prompts": [text], "ids": [[int]]}`` from the model's own
 tokenizer; without it ids are synthesized from each prompt's text, which is
 enough for seeded weights), and the text is what the CLIP rewards score
-against. Per member: prefill the prompt ids into the latent cache, sample the
+against. Per member: prefill the prompt ids into what the family carries (a
+latent cache; or recurrent states and a KV cache side by side), sample the
 image ids one position a step, decode them through the VQ decoder.
 
 Besides the images, ``generate_p`` returns per-image rows (sampled ids, the
@@ -19,6 +23,11 @@ and :meth:`LMArBackend.step_metrics` reduces them to the step's metrics:
   the mean of that call;
 - ``moe/pair_route_flip``: share of (cache slot, layer) top-k sets that differ
   between the two halves of an antithetic pair;
+- ``lm/state_bytes``, ``lm/kv_cache_bytes`` (a family that says what a sequence
+  carries; ``qwen3_next`` does): bytes of recurrent state + conv window, and of
+  KV cache, that the step's sequences carry through their decode scans — what
+  grows with ``pop_size x prompts_per_gen`` whatever the prompt length, and of
+  which ``member_batch / pop_size`` is resident at a time;
 - ``probe/*``: what member 0 produced for its first sequences (ids, routing,
   logits at every 16th position, the prompt ids) — the trainer writes them to
   ``probe_epoch<k>.npz`` once and keeps them out of ``metrics.jsonl``.
@@ -35,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..lora import LoRASpec, init_lora
+from ..lora import init_lora
 from ..models import lm
 from ..obs import block_if_tracing, span as obs_span
 from ..utils.seeding import stable_text_seed
@@ -49,7 +58,7 @@ FIRST_TEXT_ID = 2  # 0 pads, 1 begins the image
 
 @dataclasses.dataclass
 class LMBackendConfig:
-    model: lm.LMConfig
+    model: lm.GeneratorUse  # either family's configuration (lm.config_from_json)
     prompts_txt_path: Optional[str] = None
     prompt_token_ids_path: Optional[str] = None
     base_quant: str = "off"
@@ -59,7 +68,7 @@ class LMBackendConfig:
     seed_params: int = 0
 
 
-def synthetic_token_ids(text: str, cfg: lm.LMConfig) -> List[int]:
+def synthetic_token_ids(text: str, cfg: lm.GeneratorUse) -> List[int]:
     """Stand-in for a tokenizer that is not on the machine: ``words x 1.3``
     ids (capped at ``max_prompt_len``) below the image-id range, drawn from a
     hash of the text so that they are stable across processes."""
@@ -73,7 +82,7 @@ class LMArBackend:
         self.cfg = cfg
         self.name = "lm_ar"
         self.params = params
-        self._spec = LoRASpec(rank=cfg.lora_r, alpha=cfg.lora_alpha, targets=lm.LM_LORA_TARGETS)
+        self._spec = cfg.model.lora_spec(rank=cfg.lora_r, alpha=cfg.lora_alpha)
         with obs_span("load_prompts"):
             self.prompts, ids = self._load_prompts()
         m = cfg.model
@@ -161,6 +170,9 @@ class LMArBackend:
             out["moe/pair_route_flip"] = differ.sum() / jnp.maximum(seen.sum(), 1)
         else:
             out["moe/pair_route_flip"] = jnp.float32(0.0)
+        for kind in ("state", "kv_cache"):
+            if f"carried/{kind}" in rows:
+                out[f"lm/{kind}_bytes"] = rows[f"carried/{kind}"].sum()
         n = min(PROBE_SEQUENCES, topk.shape[1])
         for k in ("ids", "topk", "logits", "prompt_ids", "prompt_len"):
             out[f"probe/{k}"] = rows[k][0, :n]

@@ -1,17 +1,30 @@
-"""A sparse-expert decoder language model as an autoregressive image-token
-generator: multi-head latent attention (MLA) over a latent cache, sandwich
-norm, a sigmoid top-k router over routed experts of which this chip holds a
-share, a shared expert, and a multi-token-prediction (MTP) module.
+"""Decoder language models as autoregressive image-token generators.
 
-Sizes come from a ``config.json``-shaped file (:meth:`LMConfig.from_json`):
-the published keys of a DeepSeek-V3-family ``config.json`` (``hidden_size``,
-``q_lora_rank``, ``kv_lora_rank``, ``n_routed_experts`` …) plus the share this
-chip holds of a stated deployment (``experts_held``, ``expert_offset``,
-``vocab_rows_held``) and the system's own use of the model (``image_tokens``).
-No preset table: a user with a checkpoint directory states sizes the same way.
+Two families stand behind one :func:`generate`, chosen by the ``model_type`` of
+a ``config.json``-shaped file (:func:`config_from_json`):
 
-The layer equations (the plain float32 form is ``reference/lm_reference.py``,
-written from the same description and sharing no code with this file):
+- the one this file writes down (no ``model_type``, ``deepseek_v3`` or
+  ``pangu_ultra_moe``): multi-head latent attention (MLA) over a latent cache,
+  sandwich norm, a sigmoid top-k router over routed experts of which this chip
+  holds a share, one ungated shared expert, a multi-token-prediction (MTP)
+  module. ``generate`` carries one latent cache a layer;
+- ``qwen3_next`` (``models/lm_hybrid.py``): Gated DeltaNet layers beside gated
+  softmax attention, a softmax router with a sigmoid-gated shared expert.
+  ``generate`` carries a recurrent state and conv window for some layers and a
+  KV cache for the others, side by side.
+
+What both share lives here: the use the system makes of either
+(:class:`GeneratorUse`: the share of a stated deployment this chip holds —
+``experts_held``, ``expert_offset``, ``vocab_rows_held`` — and
+``image_tokens``), ``routed_experts`` over ``ops/grouped.py`` with
+``expert_factors``, and :func:`generate` itself: prefill, the ``lax.scan`` of
+sampled positions, sampling, the image-id range, the VQ decode. A family is the
+four functions of :class:`Family`. No preset table: a user with a checkpoint
+directory states sizes the same way.
+
+The first family's layer equations (the plain float32 form is
+``reference/lm_reference.py``, written from the same description and sharing
+no code with this file):
 
 - block: ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(FFN(N3(h)))``;
 - MLA: ``cq = Nq(u Wdq)``, ``q = cq Wuq`` → heads × (nope | rope);
@@ -31,12 +44,12 @@ written from the same description and sharing no code with this file):
   head. In the model and the reference; not run by :func:`generate` (at plain
   sampling the family discards it).
 
-Generation: prefill the (padded, masked) prompt ids into the latent cache,
-then ``image_tokens.count`` steps of ``lax.scan`` — embed the last id (the
-begin-of-image id first), one pass of the blocks over the cache, final norm,
-head over the rows held, the image-id range of the logits, top-k/top-p
-sampling under a key shared by the members — and the VQ decoder of
-``models/msvq.py`` over the sampled grid.
+Generation, either family: prefill the (padded, masked) prompt ids into what
+the family carries (here the latent cache), then ``image_tokens.count`` steps
+of ``lax.scan`` — embed the last id (the begin-of-image id first), one pass of
+the blocks over the carried state, final norm, head over the rows held, the
+image-id range of the logits, top-k/top-p sampling under a key shared by the
+members — and the VQ decoder of ``models/msvq.py`` over the sampled grid.
 """
 
 from __future__ import annotations
@@ -45,7 +58,7 @@ import dataclasses
 import json
 import math
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,9 +89,88 @@ PUBLISHED_KEYS = (
 )
 
 
+MLA_MODEL_TYPES = (None, "deepseek_v3", "pangu_ultra_moe")  # the family this file writes down
+
+
+class Family(NamedTuple):
+    """What :func:`generate` and the backend ask of a model family."""
+    init: Callable            # (key, cfg, base_quant) -> params
+    prefill_state: Callable   # (params, cfg, ids, lens, lora, scale, factors) -> (carried state, MoE stats, bytes a sequence by kind)
+    decode_layers: Callable   # (params, cfg, x, state, i, prompt_len, lora, scale, factors) -> (x, state, MoE stats)
+    head: Callable            # (params, cfg, hidden) -> float32 logits over the rows held
+
+
 @dataclasses.dataclass(frozen=True)
-class LMConfig:
-    # --- the model's own config.json keys
+class GeneratorUse:
+    """What a configuration of either family states besides the model's own
+    keys: this chip's share of the deployment and the system's use of the
+    model as an image-token generator."""
+    # --- this chip's share of the deployment (model-configs §4)
+    experts_held: int = 256
+    expert_offset: int = 0
+    vocab_rows_held: int = 153600
+    # --- the system's use of it: image ids and how they are sampled
+    image_vocab: int = 4096
+    image_id_offset: int = 0
+    boi_id: int = 1
+    grid: int = 16
+    max_prompt_len: int = 64
+    top_k: int = 900
+    top_p: float = 0.96
+    decode_batch: int = 0  # images a member decodes at a time (0: all of its batch)
+    vq: msvq.MSVQConfig = dataclasses.field(default_factory=msvq.MSVQConfig)
+    compute_dtype: Any = jnp.bfloat16
+
+    def check_use(self, n_experts: int) -> None:
+        if not 0 <= self.expert_offset <= n_experts - self.experts_held:
+            raise ValueError(f"experts [{self.expert_offset}, +{self.experts_held}) of {n_experts}")
+        if self.image_id_offset + self.image_vocab > self.vocab_rows_held:
+            raise ValueError("the image-id range lies outside the vocabulary rows held")
+        if self.vq.vocab_size != self.image_vocab or self.vq.patch_nums[-1] != self.grid:
+            raise ValueError("the VQ codebook and grid must match image_vocab and grid")
+
+    @property
+    def image_tokens(self) -> int:
+        return self.grid * self.grid
+
+    @property
+    def cache_len(self) -> int:
+        return self.max_prompt_len + self.image_tokens
+
+    def lora_spec(self, rank: int = 8, alpha: float = 16.0) -> LoRASpec:
+        return LoRASpec(rank=rank, alpha=alpha, targets=self.lora_targets)
+
+
+def published_from_raw(raw: Dict[str, Any], keys: Tuple[str, ...], family: str) -> Dict[str, Any]:
+    """The model's own keys of a ``config.json``-shaped dict; a missing one is named."""
+    missing = [k for k in keys if k not in raw]
+    if missing:
+        raise ValueError(f"{family} config.json needs the keys {missing}")
+    return {k: raw[k] for k in keys}
+
+
+def use_from_raw(raw: Dict[str, Any], n_experts: int, vocab: int) -> Dict[str, Any]:
+    """:class:`GeneratorUse`'s fields from a ``config.json``-shaped dict: the
+    share keys, the ``image_tokens`` group, ``vq`` (``MSVQConfig`` keys) and
+    ``torch_dtype``."""
+    kw: Dict[str, Any] = {"experts_held": raw.get("experts_held", n_experts),
+                          "expert_offset": raw.get("expert_offset", 0),
+                          "vocab_rows_held": raw.get("vocab_rows_held", vocab)}
+    img = raw.get("image_tokens", {})
+    for k in ("image_vocab", "image_id_offset", "boi_id", "grid", "max_prompt_len", "top_k", "top_p",
+              "decode_batch"):
+        if k in img:
+            kw[k] = img[k]
+    dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[raw.get("torch_dtype", "bfloat16")]
+    vq = {k: tuple(v) if isinstance(v, list) else v for k, v in raw.get("vq", {}).items()}
+    vq.setdefault("vocab_size", kw.get("image_vocab", GeneratorUse.image_vocab))
+    vq["patch_nums"] = (kw.get("grid", GeneratorUse.grid),)  # one scale: the ids are the grid
+    return dict(kw, vq=msvq.MSVQConfig(compute_dtype=dt, **vq), compute_dtype=dt)
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig(GeneratorUse):
+    # --- the model's own config.json keys (openPangu-Ultra-MoE-718B's as defaults)
     hidden_size: int = 7680
     num_attention_heads: int = 128
     q_lora_rank: int = 1536
@@ -99,70 +191,60 @@ class LMConfig:
     first_k_dense_replace: int = 3
     num_nextn_predict_layers: int = 1
     vocab_size: int = 153600
-    # --- this chip's share of the deployment (model-configs §4)
-    experts_held: int = 256
-    expert_offset: int = 0
-    vocab_rows_held: int = 153600
-    # --- the system's use of it: image ids and how they are sampled
-    image_vocab: int = 4096
-    image_id_offset: int = 0
-    boi_id: int = 1
-    grid: int = 16
-    max_prompt_len: int = 64
-    top_k: int = 900
-    top_p: float = 0.96
-    decode_batch: int = 0  # images a member decodes at a time (0: all of its batch)
-    vq: msvq.MSVQConfig = dataclasses.field(default_factory=msvq.MSVQConfig)
-    compute_dtype: Any = jnp.bfloat16
 
     def __post_init__(self) -> None:
         if self.n_shared_experts != 1:
-            raise ValueError("one shared expert is what this model code writes down")
-        if not 0 <= self.expert_offset <= self.n_routed_experts - self.experts_held:
-            raise ValueError(f"experts [{self.expert_offset}, +{self.experts_held}) of {self.n_routed_experts}")
-        if self.image_id_offset + self.image_vocab > self.vocab_rows_held:
-            raise ValueError("the image-id range lies outside the vocabulary rows held")
-        if self.vq.vocab_size != self.image_vocab or self.vq.patch_nums[-1] != self.grid:
-            raise ValueError("the VQ codebook and grid must match image_vocab and grid")
+            raise ValueError(f"n_shared_experts {self.n_shared_experts}: the MLA family of models/lm.py writes "
+                             "down one ungated shared expert")
+        self.check_use(self.n_routed_experts)
+
+    @classmethod
+    def from_raw(cls, raw: Dict[str, Any]) -> "LMConfig":
+        kw = published_from_raw(raw, PUBLISHED_KEYS, "an MLA-family")
+        return cls(**kw, **use_from_raw(raw, kw["n_routed_experts"], kw["vocab_size"]))
 
     @classmethod
     def from_json(cls, path: str) -> "LMConfig":
-        """A ``config.json``-shaped file: published keys, the share keys, an
-        ``image_tokens`` group, ``vq`` (``MSVQConfig`` keys) and ``torch_dtype``."""
-        raw = json.loads(Path(path).read_text())
-        kw = {k: raw[k] for k in PUBLISHED_KEYS if k in raw}
-        kw["experts_held"] = raw.get("experts_held", kw.get("n_routed_experts", cls.n_routed_experts))
-        kw["expert_offset"] = raw.get("expert_offset", 0)
-        kw["vocab_rows_held"] = raw.get("vocab_rows_held", kw.get("vocab_size", cls.vocab_size))
-        img = raw.get("image_tokens", {})
-        for k in ("image_vocab", "image_id_offset", "boi_id", "grid", "max_prompt_len", "top_k", "top_p",
-                  "decode_batch"):
-            if k in img:
-                kw[k] = img[k]
-        dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[raw.get("torch_dtype", "bfloat16")]
-        vq = {k: tuple(v) if isinstance(v, list) else v for k, v in raw.get("vq", {}).items()}
-        grid = kw.get("grid", cls.grid)
-        vq.setdefault("vocab_size", kw.get("image_vocab", cls.image_vocab))
-        vq["patch_nums"] = (grid,)  # one scale: the ids are the grid
-        return cls(vq=msvq.MSVQConfig(compute_dtype=dt, **vq), compute_dtype=dt, **kw)
-
-    @property
-    def image_tokens(self) -> int:
-        return self.grid * self.grid
-
-    @property
-    def cache_len(self) -> int:
-        return self.max_prompt_len + self.image_tokens
+        """A ``config.json``-shaped file of this family (:func:`config_from_json`
+        reads either family's)."""
+        return cls.from_raw(json.loads(Path(path).read_text()))
 
     @property
     def cache_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
+    @property
+    def n_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def lora_targets(self) -> Tuple[str, ...]:
+        return LM_LORA_TARGETS
+
     def is_moe(self, layer: int) -> bool:
         return layer >= self.first_k_dense_replace
 
-    def lora_spec(self, rank: int = 8, alpha: float = 16.0) -> LoRASpec:
-        return LoRASpec(rank=rank, alpha=alpha, targets=LM_LORA_TARGETS)
+    def family(self) -> Family:
+        return MLA_FAMILY
+
+
+def config_from_json(path: str):
+    """A ``config.json``-shaped file → the configuration of the family its
+    ``model_type`` names: the model's published keys, the share keys
+    (``experts_held``, ``expert_offset``, ``vocab_rows_held``), an
+    ``image_tokens`` group, ``vq`` and ``torch_dtype``. A file without
+    ``model_type`` is read as the MLA family, as every file was before the key
+    was looked at."""
+    from . import lm_hybrid  # the second family builds on this module
+
+    raw = json.loads(Path(path).read_text())
+    model_type = raw.get("model_type")
+    if model_type in MLA_MODEL_TYPES:
+        return LMConfig.from_raw(raw)
+    if model_type == lm_hybrid.MODEL_TYPE:
+        return lm_hybrid.HybridLMConfig.from_raw(raw)
+    known = [t for t in MLA_MODEL_TYPES if t] + [lm_hybrid.MODEL_TYPE]
+    raise ValueError(f"{path}: model_type {model_type!r} is not a family this model code writes down ({known})")
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +294,12 @@ def _block_init(key, cfg: LMConfig, moe: bool) -> Params:
     return p
 
 
-def init_lm(key: jax.Array, cfg: LMConfig, base_quant: str = "off") -> Params:
+def init_lm(key: jax.Array, cfg: GeneratorUse, base_quant: str = "off") -> Params:
+    """Seeded parameters of ``cfg``'s family."""
+    return cfg.family().init(key, cfg, base_quant)
+
+
+def _init_mla(key: jax.Array, cfg: LMConfig, base_quant: str = "off") -> Params:
     """Seeded parameters. ``base_quant="int8"`` quantizes each kernel inside
     the same program (``ops/quant.maybe_quantize_tree``, what ``train.cli``'s
     later ``quantize_frozen`` pass would do and then finds done): at the
@@ -353,8 +440,12 @@ def route(p: Params, cfg: LMConfig, u: jax.Array) -> Tuple[jax.Array, jax.Array]
 # read either way: there the dense form reads each expert once with the
 # dequantization fused into the dot's operand, where the compiler's grouped
 # kernel measured 1.3 ms a [16, 7680, 2048] s8 kernel against a 0.31 ms read
-# (my chip run, PR 27). The member axis is not visible under ``vmap``, so the
-# choice is by a member's rows: a chunk of 8 members is 8 x 16 = 128 rows.
+# (my chip run, PR 27). Re-decided at 128 held experts of width 512 (the
+# ``qwen3_next`` cell: 8 members x 8 rows, 640 pairs, 5 rows an expert) from two traced steps: ``experts``
+# takes 2.340 s a step dense (0.759 ms a call) and 2.815 s grouped (0.913 ms; ``DENSE_ROWS = 0`` set by a scratch
+# script) against a 1.523 s floor (my chip runs, PR 31) - it holds there too, by a fifth. (Host-timed alone, which
+# is no device metric, one call read 0.753 / 0.954 ms against a 0.492 ms read of the three s8 kernels.) The member
+# axis is not visible under ``vmap``, so the choice is by a member's rows: a chunk of 8 members is 128 rows.
 DENSE_ROWS = 16
 
 
@@ -370,7 +461,7 @@ def _expert_einsum(spec: str, x: jax.Array, node: Params) -> jax.Array:
     return (y * qk["scale"][:, 0, :]).astype(x.dtype)
 
 
-def _routed_dense(p: Params, cfg: LMConfig, u: jax.Array, wt: jax.Array,
+def _routed_dense(p: Params, cfg, u: jax.Array, wt: jax.Array,
                   factors: Optional[Dict[str, Any]], scale: float) -> jax.Array:
     """Few rows: all ``E`` held experts over all rows ``u [R, d]``, weighted by
     ``wt [R, E]`` (0 where a row did not choose the expert)."""
@@ -391,7 +482,7 @@ def _routed_dense(p: Params, cfg: LMConfig, u: jax.Array, wt: jax.Array,
     return jnp.einsum("te,ted->td", wt.astype(u.dtype), y)
 
 
-def routed_experts(p: Params, cfg: LMConfig, u: jax.Array, top_i: jax.Array, top_w: jax.Array,
+def routed_experts(p: Params, cfg, u: jax.Array, top_i: jax.Array, top_w: jax.Array,
                    row_valid: jax.Array, factors: Optional[Dict[str, Any]], scale: float):
     """``Σ_{e ∈ top-k, e held} w_e E_e(u)`` for ``u [R, d]``: every pair whose
     expert is held here is computed, none dropped. ``factors``: this member's
@@ -424,7 +515,7 @@ def routed_experts(p: Params, cfg: LMConfig, u: jax.Array, top_i: jax.Array, top
     return jnp.einsum("rk,rkd->rd", w.astype(u.dtype), y.reshape(R, K, d)), e.reshape(R, K)
 
 
-def expert_factors(lora: Optional[Params], cfg: LMConfig, dtype) -> Optional[List[Optional[Dict[str, Any]]]]:
+def expert_factors(lora: Optional[Params], cfg, dtype) -> Optional[List[Optional[Dict[str, Any]]]]:
     """Per layer, one member's routed-expert LoRA factors laid side by side
     (``ops/grouped.expert_lora_factors``): built once a generation, outside
     the decode loop, from the adapter tree (raw or ``FactoredDelta`` leaves)."""
@@ -438,6 +529,17 @@ def expert_factors(lora: Optional[Params], cfg: LMConfig, dtype) -> Optional[Lis
     return out
 
 
+def routed_with_stats(p: Params, cfg, u: jax.Array, top_i: jax.Array, top_w: jax.Array, row_valid: jax.Array,
+                      factors: Optional[Dict[str, Any]], scale: float):
+    """:func:`routed_experts` and the counters of this call (either family)."""
+    routed, e = routed_experts(p, cfg, u, top_i, top_w, row_valid, factors, scale)
+    return routed, {
+        "assign": (e < cfg.experts_held).sum(-1).astype(jnp.int32),           # [R] pairs computed here
+        "load": grouped.expert_load_ratio(e.reshape(-1), cfg.experts_held),   # scalar, this call
+        "topk": jnp.sort(top_i, axis=-1),                                     # [R, k] as a set
+    }
+
+
 def moe(p: Params, cfg: LMConfig, u: jax.Array, row_valid: jax.Array, lora: Optional[Params],
         factors: Optional[Dict[str, Any]], path: str, scale: float):
     """``u [R, d]`` → (``[R, d]``, counters of this call). ``row_valid`` marks
@@ -447,12 +549,7 @@ def moe(p: Params, cfg: LMConfig, u: jax.Array, row_valid: jax.Array, lora: Opti
     with jax.named_scope("shared"):
         shared = _swiglu(p["shared"], u, lora, f"{path}/shared", scale)
     with jax.named_scope("experts"):
-        routed, e = routed_experts(p["experts"], cfg, u, top_i, top_w, row_valid, factors, scale)
-        stats = {
-            "assign": (e < cfg.experts_held).sum(-1).astype(jnp.int32),           # [R] pairs computed here
-            "load": grouped.expert_load_ratio(e.reshape(-1), cfg.experts_held),   # scalar, this call
-            "topk": jnp.sort(top_i, axis=-1),                                     # [R, k] as a set
-        }
+        routed, stats = routed_with_stats(p["experts"], cfg, u, top_i, top_w, row_valid, factors, scale)
     return shared + routed, stats
 
 
@@ -478,7 +575,7 @@ def block(p: Params, cfg: LMConfig, li: int, x: jax.Array, attn, row_valid: jax.
     return h + _rms(f, p["n4"], cfg), extra, stats
 
 
-def _embed(params: Params, cfg: LMConfig, ids: jax.Array) -> jax.Array:
+def _embed(params: Params, cfg, ids: jax.Array) -> jax.Array:
     return params["embed"][ids].astype(cfg.compute_dtype)
 
 
@@ -539,12 +636,55 @@ def mtp_logits(params: Params, cfg: LMConfig, hidden: jax.Array, next_ids: jax.A
     return _head(params, cfg, y)
 
 
+def _mla_prefill_state(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
+                       lora, lora_scale: float, factors):
+    """:class:`Family` hook: the prompt into one latent cache a layer."""
+    B, P = ids.shape
+    dt = cfg.compute_dtype
+    _, entries, stats = prefill(params, cfg, ids, lens, lora, lora_scale, factors, cache_only=True)
+    caches = tuple(jnp.zeros((B, cfg.cache_len, cfg.cache_width), dt).at[:, :P].set(e.astype(dt)) for e in entries)
+    return caches, stats, {}
+
+
+def decode_slot(cfg: GeneratorUse, i: jax.Array, prompt_len: jax.Array):
+    """Sampled position ``i`` of right-padded prompts ``prompt_len [B]`` in a
+    cache of ``cache_len`` slots: (the slot it is written to, each sequence's
+    true position ``[B]``, the slots a query sees ``[B, cache_len]``: its own
+    prompt's, then the sampled ones up to this)."""
+    P = cfg.max_prompt_len
+    slots = jnp.arange(cfg.cache_len)
+    slot = P + i
+    pos = prompt_len + i
+    valid = (slots[None, :] < prompt_len[:, None]) | ((slots[None, :] >= P) & (slots[None, :] <= slot))
+    return slot, pos, valid
+
+
+def _mla_decode_layers(params: Params, cfg: LMConfig, x: jax.Array, caches, i: jax.Array, prompt_len: jax.Array,
+                       lora, lora_scale: float, factors):
+    """:class:`Family` hook: sampled position ``i`` of every sequence, ``x [B,
+    d]``, through the blocks over the latent caches."""
+    B = x.shape[0]
+    slot, pos, valid = decode_slot(cfg, i, prompt_len)
+    new_caches, stats = [], []
+    for li, p in enumerate(params["layers"]):
+        attn = lambda u, p=p, li=li: mla_decode(
+            p["mla"], cfg, u, pos, caches[li], slot, valid, lora, f"layers/{li}/mla", lora_scale)
+        x, cache, st = block(p, cfg, li, x, attn, jnp.ones((B,), bool), lora,
+                             factors[li] if factors else None, lora_scale)
+        new_caches.append(cache)
+        if st is not None:
+            stats.append(st)
+    return x, tuple(new_caches), stats
+
+
+MLA_FAMILY = Family(init=_init_mla, prefill_state=_mla_prefill_state, decode_layers=_mla_decode_layers, head=_head)
+
 PROBE_EVERY = 16  # logits are kept at every 16th sampled position
 
 
 def generate(
     params: Params,
-    cfg: LMConfig,
+    cfg: GeneratorUse,
     prompt_ids: jax.Array,   # [B, max_prompt_len] right-padded
     prompt_len: jax.Array,   # [B]
     key: jax.Array,
@@ -553,58 +693,52 @@ def generate(
     decode: bool = True,
     item_index: Optional[jax.Array] = None,
 ):
-    """Prefill + ``image_tokens`` sampled steps + VQ decode. Returns (images
-    ``[B, H, W, 3]`` in [0, 1] — or the sampled ids when ``decode=False`` — and
-    per-image rows: ``ids`` ``[B, n]``, ``topk`` ``[B, Tmax, moe layers, k]``
-    (the router's choice at every cache slot, -1 where none), ``assign``
-    ``[B]`` (token–expert pairs computed here), ``load`` ``[B]`` (largest
-    expert load ratio of any call, the same for every image of a call) and
-    ``logits`` ``[B, n / PROBE_EVERY, image_vocab]``).
+    """Prefill + ``image_tokens`` sampled steps + VQ decode, for either family:
+    what the scan carries between positions is whatever ``cfg.family()``'s
+    ``prefill_state`` returns (one latent cache a layer; or recurrent states,
+    conv windows and KV caches side by side) and ``generate`` does not look
+    inside it. Returns (images ``[B, H, W, 3]`` in [0, 1] — or the sampled ids
+    when ``decode=False`` — and per-image rows: ``ids`` ``[B, n]``, ``topk``
+    ``[B, Tmax, moe layers, k]`` (the router's choice at every cache slot, -1
+    where none), ``assign`` ``[B]`` (token–expert pairs computed here), ``load``
+    ``[B]`` (largest expert load ratio of any call, the same for every image of
+    a call), ``logits`` ``[B, n / PROBE_EVERY, image_vocab]`` and, where the
+    family says what a sequence carries, ``carried/<kind>`` ``[B]`` in bytes).
 
     Sampling keys fold in the step and each image's *global* batch position
     (``item_index``), so outputs do not depend on how the batch is chunked."""
     B, P = prompt_ids.shape
-    n, Tmax, dt = cfg.image_tokens, cfg.cache_len, cfg.compute_dtype
+    n, dt = cfg.image_tokens, cfg.compute_dtype
+    fam = cfg.family()
     item_idx = jnp.arange(B) if item_index is None else item_index
     lo, hi = cfg.image_id_offset, cfg.image_id_offset + cfg.image_vocab
-    slots = jnp.arange(Tmax)
 
     with jax.named_scope("generate"):
         factors = expert_factors(lora, cfg, dt)
         with jax.named_scope("lm_prefill"):
-            _, entries, stats = prefill(params, cfg, prompt_ids, prompt_len, lora, lora_scale, factors,
-                                        cache_only=True)
-            caches = tuple(jnp.zeros((B, Tmax, cfg.cache_width), dt).at[:, :P].set(e.astype(dt)) for e in entries)
+            state, stats, carried_bytes = fam.prefill_state(params, cfg, prompt_ids, prompt_len, lora, lora_scale,
+                                                            factors)
             assign = sum(st["assign"].sum(-1) for st in stats) if stats else jnp.zeros((B,), jnp.int32)
             load = jnp.max(jnp.stack([st["load"] for st in stats])) if stats else jnp.float32(0.0)
             # the router's choice at each prompt slot, -1 at padding and at the
             # last layer (cache_only: it routes no prompt row)
             in_prompt = (jnp.arange(P) < prompt_len[:, None])[..., None]           # [B, P, 1]
-            n_moe = cfg.num_hidden_layers - cfg.first_k_dense_replace
-            topk_p = jnp.full((B, P, n_moe, cfg.num_experts_per_tok), -1, jnp.int32)
+            topk_p = jnp.full((B, P, cfg.n_moe_layers, cfg.num_experts_per_tok), -1, jnp.int32)
             for j, st in enumerate(stats):
                 topk_p = topk_p.at[:, :, j].set(jnp.where(in_prompt, st["topk"], -1))
 
         def step(carry, i):
-            last, caches, assign, load, probe = carry
+            last, state, assign, load, probe = carry
             with jax.named_scope("lm_decode_step"):
-                slot = P + i
-                pos = prompt_len + i
-                valid = (slots[None, :] < prompt_len[:, None]) | ((slots[None, :] >= P) & (slots[None, :] <= slot))
-                x = _embed(params, cfg, last)
-                new_caches, tk = [], []
-                for li, p in enumerate(params["layers"]):
-                    attn = lambda u, p=p, li=li: mla_decode(
-                        p["mla"], cfg, u, pos, caches[li], slot, valid, lora, f"layers/{li}/mla", lora_scale)
-                    x, cache, st = block(p, cfg, li, x, attn, jnp.ones((B,), bool), lora,
-                                         factors[li] if factors else None, lora_scale)
-                    new_caches.append(cache)
-                    if st is not None:
-                        assign = assign + st["assign"]
-                        load = jnp.maximum(load, st["load"])
-                        tk.append(st["topk"])
+                x, state, sts = fam.decode_layers(params, cfg, _embed(params, cfg, last), state, i, prompt_len,
+                                                  lora, lora_scale, factors)
+                tk = []
+                for st in sts:
+                    assign = assign + st["assign"]
+                    load = jnp.maximum(load, st["load"])
+                    tk.append(st["topk"])
                 with jax.named_scope("lm_head"):
-                    logits = _head(params, cfg, x)[:, lo:hi]  # sampling sees the image-id range only
+                    logits = fam.head(params, cfg, x)[:, lo:hi]  # sampling sees the image-id range only
                 with jax.named_scope("sample"):
                     k_i = jax.random.fold_in(key, i)
                     keys = jax.vmap(lambda j: jax.random.fold_in(k_i, j))(item_idx)
@@ -615,17 +749,18 @@ def generate(
                     probe = jax.lax.dynamic_update_slice_in_dim(
                         probe, jnp.where(i % PROBE_EVERY == 0, logits[:, None, :], old), j, axis=1)
             tk = jnp.stack(tk, axis=1) if tk else jnp.zeros((B, 0, cfg.num_experts_per_tok), jnp.int32)
-            return (ids + lo, tuple(new_caches), assign, load, probe), (ids, tk)
+            return (ids + lo, state, assign, load, probe), (ids, tk)
 
         probe0 = jnp.zeros((B, n // PROBE_EVERY, cfg.image_vocab), jnp.float32)
         boi = jnp.full((B,), cfg.boi_id, jnp.int32)
         (_, _, assign, load, probe), (ids, topk_d) = jax.lax.scan(
-            step, (boi, caches, assign, load, probe0), jnp.arange(n))
+            step, (boi, state, assign, load, probe0), jnp.arange(n))
         ids = ids.T                                                           # [B, n]
         topk = jnp.concatenate([topk_p, jnp.moveaxis(topk_d, 0, 1)], axis=1)  # [B, Tmax, layers, k]
 
     rows = {"ids": ids, "topk": topk, "assign": assign,
             "load": jnp.broadcast_to(load, (B,)), "logits": probe}
+    rows.update({f"carried/{k}": jnp.full((B,), v, jnp.float32) for k, v in carried_bytes.items()})
     if not decode:
         return ids, rows
     with jax.named_scope("decode"):

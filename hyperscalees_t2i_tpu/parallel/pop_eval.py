@@ -302,6 +302,13 @@ def make_population_evaluator(
     "SAME seed for all indiv", runES.py:103-107), so reward differences are
     attributable to the LoRA perturbation alone.
 
+    ``member_batch`` members run together (``lax.map`` ``batch_size``), and a
+    chunk holds whatever each of its members' generations carries: a KV or
+    latent cache, and for a generator with recurrent layers a state per
+    sequence that is full-sized from the first position (``lm_ar`` on a
+    ``qwen3_next`` file: 19 MB a sequence, ``lm/state_bytes`` over the step).
+    Nothing here sizes the chunk from that; ``member_batch`` is the knob.
+
     ``reward_tile`` (0 = off) bounds *member-interior* memory: each member's
     generate→decode→preprocess→reward pipeline runs through ``lax.map`` over
     image sub-batches of that size, so the 1024px decode + CLIP tower temps

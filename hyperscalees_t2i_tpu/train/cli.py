@@ -71,7 +71,8 @@ def add_backend_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
     p.add_argument("--lm_config", default=None,
                    help="lm_ar: a config.json-shaped file — the model's published keys "
                         "plus this chip's share (experts_held, expert_offset, "
-                        "vocab_rows_held) and image_tokens (models/lm.LMConfig.from_json)")
+                        "vocab_rows_held) and image_tokens; its model_type names the family "
+                        "(models/lm.config_from_json)")
     p.add_argument("--prompt_token_ids", default=None,
                    help='lm_ar: {"prompts": [text], "ids": [[int]]} from the model\'s own '
                         "tokenizer; without it ids are synthesized from --prompts_txt")
@@ -415,7 +416,7 @@ def build_backend(args):
             sys.exit("ERROR: --backend lm_ar needs --lm_config <config.json-shaped file> "
                      "(the model's published keys plus experts_held / vocab_rows_held)")
         return LMArBackend(LMBackendConfig(
-            model=lm.LMConfig.from_json(args.lm_config),
+            model=lm.config_from_json(args.lm_config),
             prompts_txt_path=args.prompts_txt, prompt_token_ids_path=args.prompt_token_ids,
             base_quant=getattr(args, "base_quant", "off"),
             lora_r=args.lora_r, lora_alpha=args.lora_alpha,

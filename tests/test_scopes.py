@@ -230,8 +230,16 @@ LM_SCOPES = (
 )
 
 
+LM_HYBRID_SCOPES = (
+    (LM_SCOPES - {s for s in LM_SCOPES if "lm_mla" in s or "lm_dense_ffn" in s})
+    | {f"generate/{phase}/{inner}" for phase in ("lm_prefill", "lm_decode_step")
+       for inner in ("lm_gdn", "lm_gdn/conv", "lm_gdn/delta_rule", "lm_gdn/gdn_out", "lm_attn")}
+    | {"generate/lm_decode_step/lm_attn/attend"}  # the toy is one period: its one attention layer's prefill stops at K and V
+)
+
+
 @pytest.mark.parametrize("family, want", [("sana_one_step", SANA_SCOPES), ("var", VAR_SCOPES),
-                                          ("lm_ar", LM_SCOPES)])
+                                          ("lm_ar", LM_SCOPES), ("lm_ar:qwen3_next", LM_HYBRID_SCOPES)])
 def test_compiled_step_carries_every_scope(family, want, tmp_path):
     """The guard against a refactor of the member loop silently dropping a
     scope: the tiny step of each family, compiled, names every top-level
@@ -243,10 +251,14 @@ def test_compiled_step_carries_every_scope(family, want, tmp_path):
 
     prompts = tmp_path / "p.txt"
     prompts.write_text("a red square\na blue circle\n")
+    family, _, model_type = family.partition(":")
     argv = ["--backend", family, "--model_scale", "tiny", "--prompts_txt", str(prompts),
             "--lora_r", "2", "--lora_alpha", "4"]
     if family == "lm_ar":
-        from tests.test_lm import TOY
+        if model_type:
+            from tests.test_lm_hybrid import TOY
+        else:
+            from tests.test_lm import TOY
 
         (tmp_path / "config.json").write_text(json.dumps(TOY))
         argv += ["--lm_config", str(tmp_path / "config.json")]
