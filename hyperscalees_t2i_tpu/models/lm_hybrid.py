@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lora import lookup
+from ..obs import note_program_geometry
 from ..ops import gated_delta
 from ..ops.quant import maybe_quantize_tree
 from . import lm, nn
@@ -337,7 +338,12 @@ def gdn_prefill(p: Params, cfg: HybridLMConfig, u: jax.Array, lens: jax.Array,
 def gdn_decode(p: Params, cfg: HybridLMConfig, u: jax.Array, carried,
                lora: Optional[Params], path: str, scale: float):
     """One position a sequence: ``u [S, d]``, ``carried = (state, window)`` →
-    (out ``[S, d]``, the new pair)."""
+    (out ``[S, d]``, the new pair). The rule's step is ``ops/gated_delta``'s
+    Pallas kernel on a TPU at the published 128 x 128 head (the state crosses
+    HBM once in and once out, in the carry's own buffer) and the
+    ``jax.numpy`` step everywhere else — a CPU, the toy heads of tier-1; a
+    state carried narrower (``STATE_DTYPE``, a control) is widened before
+    the step and narrowed after, whichever form runs."""
     state, window = carried
     mixed, z, beta, g = _gdn_project(p, cfg, u, lora, path, scale)
     with jax.named_scope("conv"):
@@ -457,6 +463,11 @@ def prefill_state(params: Params, cfg: HybridLMConfig, ids: jax.Array, lens: jax
             c = (c[0].astype(STATE_DTYPE), c[1].astype(dt))
             nbytes["state"] += sum(map(_nbytes, c))
         state.append(c)
+    recurrent = [c[0] for kind, c in zip(cfg.layer_types, state) if kind != "full_attention"]
+    if recurrent:
+        # a traced run counts the compiled step's ops as large as a layer's state (obs/xla_cost.record_compile):
+        # in the decode scan's body the update itself and nothing else, or a position pays for a copy
+        note_program_geometry(recurrent_state_shape=(len(recurrent),) + recurrent[0].shape)
     return tuple(state), stats, nbytes
 
 

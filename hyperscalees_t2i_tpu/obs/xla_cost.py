@@ -906,9 +906,11 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
     the compiler's peak as an ``obs/`` gauge (→ next ``metrics.jsonl`` row).
     With the tracer enabled, also write the program's op → scope table
     (:func:`scope_table`) to ``scopes/<label>.json`` beside the ledger and
-    name it in the record, and count the ops as large as the KV cache a
-    generator noted (:func:`kv_cache_whole_ops`). The one call every compile
-    site makes. Never raises."""
+    name it in the record, and count the ops as large as the KV cache or the
+    recurrent states a generator noted (:func:`kv_cache_whole_ops` on
+    ``kv_cache_shape`` and on ``recurrent_state_shape``: the stack of the
+    layers' states, one layer's, with or without the member axis). The one
+    call every compile site makes. Never raises."""
     try:
         rec = program_record(**kwargs)
     except Exception:
@@ -926,9 +928,10 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
                 path.parent.mkdir(parents=True, exist_ok=True)
                 path.write_text(json.dumps(table, sort_keys=True))
                 rec["scope_table"] = str(rel)
-            cache_shape = rec["geometry"].get("kv_cache_shape")
-            if cache_shape:
-                rec["kv_cache_whole_ops"] = kv_cache_whole_ops(compiled, cache_shape)
+            for carried in ("kv_cache", "recurrent_state"):
+                shape = rec["geometry"].get(f"{carried}_shape")
+                if shape:
+                    rec[f"{carried}_whole_ops"] = kv_cache_whole_ops(compiled, shape)
     except Exception:
         pass
     ledger.write(rec)

@@ -4,13 +4,14 @@ the shapes its real caller passes it.
     python -m hyperscalees_t2i_tpu.tools.kernel_check [--kernels a,b]
         [--compile_only] [--out FILE]
 
-The CPU tier can only *interpret* the two kernels in ``ops/``; whether
+The CPU tier can only *interpret* the three kernels in ``ops/``; whether
 Mosaic accepts them, and whether what it builds agrees with the XLA path, is
 a fact about a chip. This is the one place that establishes it: every case
 below is a call a model really makes (the dense sites of Sana-Sprint 1.6B and
 VAR-d16 under ``--base_quant int8`` with the member axis the
 benchmark's cells put in front, the VAR ten-scale KV cache, Infinity's masked
-cross-attention), run with ``interpret=False`` and compared with the
+cross-attention, the hybrid cell's 64 recurrent states a DeltaNet layer), run
+with ``interpret=False`` and compared with the
 XLA form the gate would otherwise choose. ``chip_smoke.py`` runs the first
 case of every kernel the TPU gates select and fails on a disagreement.
 
@@ -47,8 +48,8 @@ class Case:
     kernel: str  # the pallas_call name (ops/pallas_gate.selected_kernels key)
     label: str
     make: Callable[[jax.Array], Tuple[Any, ...]]  # key -> args
-    kernel_fn: Callable[..., jax.Array]
-    xla_fn: Callable[..., jax.Array]
+    kernel_fn: Callable[..., Any]  # an array, or a tuple of them: each is held to tol
+    xla_fn: Callable[..., Any]
     tol: float  # bound on max|kernel − xla| / max|xla|
     tol_reason: str
     # the same kernel called once a member (what vmap's default batching of a
@@ -153,6 +154,37 @@ def _attention_case(label: str, B: int, nq: int, L: int, kv_len: Optional[int],
     )
 
 
+def _gated_delta_case(label: str, lead: Tuple[int, ...], H: int = 32, dk: int = 128, dv: int = 128) -> Case:
+    """One decode position of a Gated DeltaNet layer (Qwen3-Next widths: 32
+    value heads of 128 x 128 float32 state): the kernel against the
+    ``jax.numpy`` step, the output and the new state each."""
+    from ..ops.gated_delta import gated_delta_step, xla_gated_delta_step
+
+    def make(key):
+        kq, kk, kv, kg, kb, ks = jax.random.split(key, 6)
+
+        def unit(k):
+            t = jax.random.normal(k, (*lead, H, dk), jnp.float32)
+            return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True))
+
+        return (unit(kq) / jnp.sqrt(dk), unit(kk), jax.random.normal(kv, (*lead, H, dv), jnp.float32),
+                -jax.random.uniform(kg, (*lead, H)), jax.random.uniform(kb, (*lead, H)),
+                jax.random.normal(ks, (*lead, H, dk, dv), jnp.float32))
+
+    kernel = lambda *args: gated_delta_step(*args, use_pallas=True)
+    return Case(
+        "gated_delta_step", label, make,
+        jax.vmap(kernel) if len(lead) > 1 else kernel, xla_gated_delta_step,
+        tol=1e-5,
+        tol_reason="both sides multiply and add in float32 and nothing is "
+                   "rounded narrower; the kernel is free to sum the 128 terms "
+                   "of k^T S and q^T S in another order than XLA's reduce (16 "
+                   "partial sums of 8 rows, then across sublanes): a few "
+                   "float32 spacings, 2^-23 each, of the largest term — 1e-5 "
+                   "leaves a factor ten (a v5e read 0.0, PR 32: the same order)",
+    )
+
+
 # VAR default geometry (models/var.VARConfig): 16 heads × 64, ten scales
 # 1,2,3,4,5,6,8,10,13,16 → queries pn² against the cache prefix written so
 # far, batch = 2 × prompts (CFG) — 4 prompts here, as the `ar` rung has.
@@ -214,6 +246,13 @@ def cases() -> List[Case]:
                         8, nq, 680, kv)
         for i, (nq, kv) in reversed(list(enumerate(_VAR_SCALES)))
     ]
+    # the hybrid cell's decode step (qwen3next80b-ep4-train-pop8x8): 64
+    # sequences' states a DeltaNet layer; in the step pop_eval's vmap puts the
+    # member axis in front, 8 members x 8 sequences
+    out += [
+        _gated_delta_case("qwen3-next decode, 64 sequences: state f32[64,32,128,128]", (64,)),
+        _gated_delta_case("qwen3-next decode, member axis 8: state f32[8,8,32,128,128]", (8, 8)),
+    ]
     out += [
         # Infinity cross-attention (models/infinity.py): bool text mask; 16
         # synthesized tokens (backends/infinity_backend) and T5's 512
@@ -263,11 +302,17 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
         jax.jit(case.kernel_fn).lower(*args).compile()
         return {**rec, "ok": True, "compiled_for": compile_only_device.device_kind}
     args = jax.jit(case.make)(key)
-    got = jax.jit(case.kernel_fn)(*args).astype(jnp.float32)
-    ref = jax.jit(case.xla_fn)(*args).astype(jnp.float32)
-    diff = float(jnp.max(jnp.abs(got - ref)))
-    scale = float(jnp.max(jnp.abs(ref)))
-    rel = diff / max(scale, 1e-30)
+    f32_leaves = lambda fn: [t.astype(jnp.float32) for t in jax.tree_util.tree_leaves(jax.jit(fn)(*args))]
+    gots, refs = f32_leaves(case.kernel_fn), f32_leaves(case.xla_fn)
+
+    def apart(got, ref):
+        diff, scale = float(jnp.max(jnp.abs(got - ref))), float(jnp.max(jnp.abs(ref)))
+        return diff / max(scale, 1e-30), diff, scale
+
+    # where a kernel returns several arrays, each is held to the tolerance at
+    # its own scale: the record is of the one furthest from its reference
+    rel, diff, scale = max(map(apart, gots, refs))
+    got = gots[0]
     if case.per_member_fn is not None:
         per = jax.jit(case.per_member_fn)(*args).astype(jnp.float32)
         rec.update(
@@ -278,7 +323,7 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
     return {
         **rec, "max_abs_diff": diff, "max_abs_ref": scale, "rel": rel,
         "tol": case.tol, "tol_reason": case.tol_reason,
-        "ok": bool(rel <= case.tol and jnp.all(jnp.isfinite(got))),
+        "ok": bool(rel <= case.tol and all(bool(jnp.all(jnp.isfinite(g))) for g in gots)),
         "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,
     }
@@ -287,7 +332,7 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--kernels", default="",
-                    help="comma list of pallas_call names (default: both)")
+                    help="comma list of pallas_call names (default: all)")
     ap.add_argument("--compile_only", action="store_true",
                     help="compile for a TPU v5e topology without a chip")
     ap.add_argument("--out", default=None, help="also write the records here (JSONL)")

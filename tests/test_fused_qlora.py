@@ -346,6 +346,34 @@ def test_mosaic_compiles_the_largest_cell_calls(call, v5e_chip):
     assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
 
 
+@pytest.mark.parametrize("members", [False, True], ids=["64-sequences", "member-axis-8x8"])
+def test_mosaic_compiles_the_gated_delta_step_of_the_hybrid_cell(members, v5e_chip):
+    """The third default-on kernel (``ops/gated_delta.py``, here because one
+    file of a test run may load libtpu) at the hybrid cell's call, a DeltaNet
+    layer's 64 states ``f32[64, 32, 128, 128]``: Mosaic takes the in-kernel
+    transpose of the ``q``/``k`` rows and the 8 MB of state blocks; the state
+    comes out in the buffer it went in by; and under ``vmap`` over the members
+    nothing as large as the state exists beside the call's own operand and
+    result — the batching is a grid axis, not a copy."""
+    from jax.sharding import SingleDeviceSharding
+    from hyperscalees_t2i_tpu.tools import kernel_check
+
+    case = next(c for c in kernel_check.cases()
+                if c.kernel == "gated_delta_step" and ("member axis" in c.label) == members)
+    rec = kernel_check.run_case(case, compile_only_device=v5e_chip)
+    assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
+    s = SingleDeviceSharding(v5e_chip)
+    args = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                                  jax.eval_shape(case.make, jax.random.PRNGKey(0)))
+    compiled = jax.jit(case.kernel_fn, donate_argnums=5).lower(*args).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    state_bytes = 64 * 32 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes == state_bytes and mem.temp_size_in_bytes < state_bytes // 16
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    from hyperscalees_t2i_tpu.obs.xla_cost import kv_cache_whole_ops
+    assert kv_cache_whole_ops(compiled, args[5].shape) == {"custom-call": 1}
+
+
 # ---------------------------------------------------------------------------
 # the member axis: a token block made of whole members (PR 28)
 # ---------------------------------------------------------------------------
@@ -838,20 +866,21 @@ def test_active_flags_and_marks(monkeypatch):
 
 def _as_tpu(monkeypatch, on: bool):
     """Every gate module binds backend_is_tpu at import; flip them all."""
-    from hyperscalees_t2i_tpu.ops import fused_qlora
+    from hyperscalees_t2i_tpu.ops import fused_qlora, gated_delta
 
-    for mod in (pallas_gate, fused_qlora):
+    for mod in (pallas_gate, fused_qlora, gated_delta):
         monkeypatch.setattr(mod, "backend_is_tpu", lambda: on)
 
 
 def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     """Selection is by platform (and, per layer, shape) plus the one
-    tri-state flag: both kernels are on exactly on a TPU backend unless
+    tri-state flag: the kernels are on exactly on a TPU backend unless
     opted out, and a request never forces a kernel onto a backend that cannot
-    run Mosaic."""
+    run Mosaic. ``gated_delta_step`` (PR 32) has no flag: the backend and the
+    call's shapes alone select it."""
     for f in pallas_gate.PALLAS_ENV_FLAGS:
         monkeypatch.delenv(f, raising=False)
-    off = {"fused_qlora": False, "decode_attention": False}
+    off = {"fused_qlora": False, "decode_attention": False, "gated_delta_step": False}
     assert pallas_gate.selected_kernels() == off
     for f in pallas_gate.PALLAS_ENV_FLAGS:  # =1 off the TPU selects nothing
         monkeypatch.setenv(f, "1")
@@ -866,7 +895,7 @@ def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     # pallas_env stamp ("flash-") has to describe the path that ran
     monkeypatch.setenv("HSES_USE_PALLAS", "0")
     monkeypatch.setenv("HSES_FUSED_QLORA_PALLAS", "off")
-    assert not any(pallas_gate.selected_kernels().values())
+    assert pallas_gate.selected_kernels() == {**off, "gated_delta_step": True}
 
 
 def test_gate_inside_jit_selects_and_never_falls_back(monkeypatch):

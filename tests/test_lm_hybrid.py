@@ -212,9 +212,37 @@ def decode_logits(params, cfg, prompt, lens, ids, lora, scale):
     return jnp.stack(out, axis=1)
 
 
-def test_prefill_then_cached_decode_against_full_forward(toy, form):
-    cfg, raw, params = toy
+def force_kernel(monkeypatch):
+    """What a TPU selects, made to run here: ``gdn_decode`` calls
+    ``gated_delta.gated_delta_step`` with no arguments of its own, so the test
+    steers the call through the interpreted Pallas kernel. Returns the list
+    the fit check's verdict of every such call is appended to."""
+    real, verdicts = gated_delta.gated_delta_step, []
+
+    def forced(q, k, v, g, beta, state):
+        verdicts.append(gated_delta.kernel_head_block(q, v, state))
+        return real(q, k, v, g, beta, state, interpret=True)
+
+    monkeypatch.setattr(gated_delta, "gated_delta_step", forced)
+    return verdicts
+
+
+@pytest.fixture(params=[(4, 8), (5, 8), (4, 128)],
+                ids=["ends-in-attention", "ends-in-deltanet", "published-head-through-the-kernel"])
+def toy_decoded(request, tmp_path, monkeypatch):
+    """The two toys, and one at the published 128 x 128 DeltaNet head whose
+    decode steps go through the kernel (:func:`force_kernel`)."""
+    layers, head = request.param
+    cfg, raw = toy_cfg(tmp_path, num_hidden_layers=layers, linear_key_head_dim=head, linear_value_head_dim=head)
+    verdicts = force_kernel(monkeypatch) if head == 128 else []
+    yield cfg, raw, randomized_norms(lm.init_lm(jax.random.PRNGKey(0), cfg), jax.random.PRNGKey(99))
+    assert all(h == cfg.linear_num_value_heads for h in verdicts) and bool(verdicts) == (head == 128)
+
+
+def test_prefill_then_cached_decode_against_full_forward(toy_decoded, form):
+    cfg, raw, params = toy_decoded
     n, L_all = cfg.image_tokens, cfg.num_hidden_layers
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     prompt = jax.random.randint(jax.random.PRNGKey(3), (2, cfg.max_prompt_len), 2, 48)
     lens = jnp.array([6, 3])
     lora = random_lora(jax.random.PRNGKey(4), params, cfg)
@@ -224,7 +252,7 @@ def test_prefill_then_cached_decode_against_full_forward(toy, form):
     assert rows["topk"].shape == (2, cfg.cache_len, L_all, cfg.num_experts_per_tok)
     # what a sequence carries: 3 (or 4) DeltaNet layers' float32 state + conv window, one attention layer's K and V
     n_g = sum(k == "linear_attention" for k in cfg.layer_types)
-    assert float(rows["carried/state"][0]) == n_g * (4 * 8 * 8 * 4 + 3 * cfg.conv_channels * 4)
+    assert float(rows["carried/state"][0]) == n_g * (4 * dk * dv * 4 + 3 * cfg.conv_channels * 4)
     assert float(rows["carried/kv_cache"][0]) == (L_all - n_g) * 2 * cfg.cache_len * 2 * 8 * 4
     got = decode_logits(params, cfg, prompt, lens, ids, lora, 2.0)
     for s in range(2):
@@ -393,6 +421,7 @@ def test_train_cli_lm_ar_two_epochs_on_a_qwen3_next_config(tmp_path, monkeypatch
         "--pop_size", "4", "--prompts_per_gen", "2", "--member_batch", "2",
         "--num_epochs", "2", "--allow_random_rewards", "true",
         "--run_dir", str(tmp_path / "runs"), "--run_name", "run", "--resume", "false", "--save_every", "0",
+        "--trace", "true",
     ])
     run = tmp_path / "runs" / "run"
     rows = [json.loads(l) for l in (run / "metrics.jsonl").read_text().splitlines()]
@@ -410,7 +439,12 @@ def test_train_cli_lm_ar_two_epochs_on_a_qwen3_next_config(tmp_path, monkeypatch
     assert len(set(rows[0]["es/member_reward"])) > 1   # the perturbation reaches the model: members score differently
     assert rows[-1]["obs/dispatches"] == 2
     steps = [json.loads(l) for l in (run / "programs.jsonl").read_text().splitlines()]
-    assert len([p for p in steps if p["label"].startswith("es_step_")]) == 1
+    (step,) = [p for p in steps if p["label"].startswith("es_step_")]
+    # a traced run counts the compiled step's ops as large as a DeltaNet layer's state (three layers; a member's
+    # two sequences lie on the mesh's two data shards, one each x 4 heads of 8 x 8): at least the decode scan
+    assert step["geometry"]["recurrent_state_shape"] == [3, 1, 4, 8, 8]
+    whole = step["recurrent_state_whole_ops"]
+    assert whole.get("while", 0) >= 1 and all(isinstance(v, int) and v > 0 for v in whole.values())
     probe = np.load(run / "probe_epoch0.npz")
     assert probe["ids"].shape == (2, 16) and probe["topk"].shape == (2, 22, 4, 4)
     q = lm.init_lm(jax.random.PRNGKey(0), lm.config_from_json(str(tmp_path / "config.json")), "int8")
