@@ -28,6 +28,15 @@ and :meth:`LMArBackend.step_metrics` reduces them to the step's metrics:
   KV cache, that the step's sequences carry through their decode scans — what
   grows with ``pop_size x prompts_per_gen`` whatever the prompt length, and of
   which ``member_batch / pop_size`` is resident at a time;
+- ``lm/hc_marginal_err``, ``lm/hc_row_err``, ``lm/hc_offdiag_mass`` (a model
+  with hyper-connection streams; ``xing4_0``): the largest distance of a row
+  or column sum of any ``H_res`` of the step from 1 (mostly the columns': what
+  the Sinkhorn iterations have not converged away at the step's sharpest
+  matrix); the same of the row sums alone (rows are normalized last, so this
+  is ``hc_eps`` and one rounding in float32 — a coefficient path in lower
+  precision shows here); and the mean off-diagonal mass of ``H_res`` over the
+  step's tokens and sub-layers — 0 is ``n`` plain residuals side by side,
+  ``1 − 1/n`` is uniform mixing of the streams;
 - ``probe/*``: what member 0 produced for its first sequences (ids, routing,
   logits at every 16th position, the prompt ids) — the trainer writes them to
   ``probe_epoch<k>.npz`` once and keeps them out of ``metrics.jsonl``.
@@ -173,6 +182,10 @@ class LMArBackend:
         for kind in ("state", "kv_cache"):
             if f"carried/{kind}" in rows:
                 out[f"lm/{kind}_bytes"] = rows[f"carried/{kind}"].sum()
+        if "hc_err" in rows:
+            out["lm/hc_marginal_err"] = rows["hc_err"].max()
+            out["lm/hc_row_err"] = rows["hc_row"].max()
+            out["lm/hc_offdiag_mass"] = rows["hc_off"].sum() / jnp.maximum(rows["hc_n"].sum(), 1.0)
         n = min(PROBE_SEQUENCES, topk.shape[1])
         for k in ("ids", "topk", "logits", "prompt_ids", "prompt_len"):
             out[f"probe/{k}"] = rows[k][0, :n]

@@ -3,11 +3,19 @@
 Two families stand behind one :func:`generate`, chosen by the ``model_type`` of
 a ``config.json``-shaped file (:func:`config_from_json`):
 
-- the one this file writes down (no ``model_type``, ``deepseek_v3`` or
-  ``pangu_ultra_moe``): multi-head latent attention (MLA) over a latent cache,
-  sandwich norm, a sigmoid top-k router over routed experts of which this chip
-  holds a share, one ungated shared expert, a multi-token-prediction (MTP)
-  module. ``generate`` carries one latent cache a layer;
+- the one this file writes down: multi-head latent attention (MLA) over a
+  latent cache, a sigmoid top-k router over routed experts of which this chip
+  holds a share (or all), one ungated shared expert, a multi-token-prediction
+  (MTP) module. ``generate`` carries one latent cache a layer. Its
+  ``model_type``s and the residual path each takes (one :func:`block`; what a
+  file runs follows from its keys, there is no knob):
+
+  - none, ``deepseek_v3``, ``pangu_ultra_moe``: **sandwich norm** around one
+    residual vector a token, plain RoPE, top-k by the score;
+  - ``xing4_0``: **pre-norm inside manifold-constrained hyper-connections**
+    (``hc_mult`` residual streams a token, mixed by a Sinkhorn-projected
+    matrix), yarn-scaled RoPE (``rope_scaling``), top-k by the score plus a
+    selection bias (``topk_method: noaux_tc``);
 - ``qwen3_next`` (``models/lm_hybrid.py``): Gated DeltaNet layers beside gated
   softmax attention, a softmax router with a sigmoid-gated shared expert.
   ``generate`` carries a recurrent state and conv window for some layers and a
@@ -26,21 +34,40 @@ The first family's layer equations (the plain float32 form is
 ``reference/lm_reference.py``, written from the same description and sharing
 no code with this file):
 
-- block: ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(FFN(N3(h)))``;
+- block, sandwich norm: ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(FFN(N3(h)))``;
+  pre-norm (``sandwich_norm: false``): the same without ``N2``, ``N4``;
+- block, hyper-connections (``hc_mult`` = n > 0; *mHC*, arXiv:2512.24880): the
+  residual is a stream ``X [n, C]`` a token, ``X₀`` = n copies of the
+  embedding, the model's output ``Σ_rows X_L``. Each sub-layer ``F`` (attention
+  or FFN behind its norm) has its own ``φ [nC, n + n + n²]``, ``b``, ``α``:
+  ``x̃ = RMSNorm(vec(X))`` over nC (no weight, ``hc_eps``);
+  ``H_pre = σ(α_pre · x̃φ_pre + b_pre)``, ``H_post = 2σ(α_post · x̃φ_post + b_post)``,
+  ``H_res = SK(clip(α_res · mat(x̃φ_res) + b_res, clamp_min, clamp_max))`` with
+  ``SK``: ``M = exp(·)``, then ``hc_sinkhorn_iters`` times every column divided
+  by its sum + ``hc_eps``, then every row by its sum + ``hc_eps``;
+  ``u = H_pre X``, ``y = F(u)``, ``X ← H_res X + H_postᵀ y``. The coefficient
+  path (norm, product at ``highest``, Sinkhorn) is float32 (:data:`HC_DTYPE`),
+  the stream and ``F`` are in the configuration's dtype;
 - MLA: ``cq = Nq(u Wdq)``, ``q = cq Wuq`` → heads × (nope | rope);
   ``[ckv | kr] = u Wdkv``, ``ckv ← Nkv(ckv)``, ``[k_nope | v] = ckv Wukv``;
-  RoPE on ``q_rope`` and on the one ``kr`` all heads share; the **cache holds
+  RoPE on ``q_rope`` and on the one ``kr`` all heads share (rotate-half; with
+  ``rope_scaling`` of type yarn the frequencies are blended between
+  ``θ^(-2i/d)`` and that ÷ ``factor`` by the linear ramp between the dimensions
+  that turn ``beta_fast`` and ``beta_slow`` times over the original context,
+  and the softmax scale is × ``(0.1 · mscale_all_dim · ln factor + 1)²``); the **cache holds
   ``[ckv | rope(kr)]``** — ``kv_lora_rank + qk_rope_head_dim`` numbers a token
   a layer, not per-head K/V. Prefill expands K/V; a decode step uses the
   absorbed form (``Wukv``'s K half folded into the query, its V half applied
   after the weighted sum over ``ckv``), LoRA delta included;
-- router: ``s = sigmoid(f32(u) Wrᵀ)``, top-k by ``s``,
+- router: ``s = sigmoid(f32(u) Wrᵀ)``, top-k by ``s`` (``noaux_tc``: by
+  ``s + e_score_correction_bias``, the weights still from ``s``; no groups),
   ``w = s_top / (Σ s_top + 1e-20) · routed_scaling_factor``;
   ``MoE(u) = Shared(u) + Σ_{e ∈ top-k, e held} w_e E_e(u)`` — the router keeps
   every output, the chip computes its own experts' part for the tokens routed
   to them, normalized over all k chosen; what absent experts would add is
   left out and no code stands in for them or their exchange;
-- MTP: ``h' = Block([Nh(h_i) ; Ne(Emb(t_{i+1}))] Wp)``, shared final norm and
+- MTP: ``h' = Block([Nh(h_i) ; Ne(Emb(t_{i+1}))] Wp)`` (with hyper-connections
+  the block runs over n copies of the projected input), shared final norm and
   head. In the model and the reference; not run by :func:`generate` (at plain
   sampling the family discards it).
 
@@ -54,6 +81,7 @@ members — and the VQ decoder of ``models/msvq.py`` over the sampled grid.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -89,7 +117,20 @@ PUBLISHED_KEYS = (
 )
 
 
-MLA_MODEL_TYPES = (None, "deepseek_v3", "pangu_ultra_moe")  # the family this file writes down
+# the ``model_type``s of the family this file writes down; the last takes its
+# residual path, router and RoPE from further keys of its file
+XING = "xing4_0"
+MLA_MODEL_TYPES = (None, "deepseek_v3", "pangu_ultra_moe", XING)
+XING_KEYS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+YARN_KEYS = ("factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim", "original_max_position_embeddings")
+
+# dtype of a hyper-connection sub-layer's coefficient path (the norm over the
+# stream, the product with φ, the Sinkhorn iterations): the configuration
+# states float32. A module constant so that the benchmark's control can run
+# the path in bfloat16 and be caught (``lm/hc_row_err``); nothing else
+# sets it.
+HC_DTYPE = jnp.float32
+HC_POST_GAIN = 2.0  # H_post = 2σ(·): at b = 0 a sub-layer's output is written at weight 1
 
 
 class Family(NamedTuple):
@@ -98,6 +139,7 @@ class Family(NamedTuple):
     prefill_state: Callable   # (params, cfg, ids, lens, lora, scale, factors) -> (carried state, MoE stats, bytes a sequence by kind)
     decode_layers: Callable   # (params, cfg, x, state, i, prompt_len, lora, scale, factors) -> (x, state, MoE stats)
     head: Callable            # (params, cfg, hidden) -> float32 logits over the rows held
+    state_rows: Optional[Callable] = None  # (cfg, carried state after the scan) -> further per-image rows {name: [B]}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,16 +233,56 @@ class LMConfig(GeneratorUse):
     first_k_dense_replace: int = 3
     num_nextn_predict_layers: int = 1
     vocab_size: int = 153600
+    # --- what a ``xing4_0`` file states besides; the defaults are the other model_types' block
+    model_type: Optional[str] = None
+    sandwich_norm: bool = True
+    hc_mult: int = 0                        # residual streams a token; 0: one residual vector
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    topk_method: str = "greedy"             # "noaux_tc": chosen by score + e_score_correction_bias
+    # ``rope_scaling`` (type yarn) key by key, ``rope_scaling_<key>``; factor 1: plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 0.0
+    rope_scaling_original_max_position_embeddings: int = 4096
 
     def __post_init__(self) -> None:
+        wrote = ("the MLA family of models/lm.py (model_type deepseek_v3 / pangu_ultra_moe / none: sandwich norm "
+                 "around one residual vector; xing4_0: pre-norm inside hyper-connection streams) writes down ")
         if self.n_shared_experts != 1:
-            raise ValueError(f"n_shared_experts {self.n_shared_experts}: the MLA family of models/lm.py writes "
-                             "down one ungated shared expert")
+            raise ValueError(f"n_shared_experts {self.n_shared_experts}: {wrote}one ungated shared expert")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"topk_method {self.topk_method!r}: {wrote}top-k by the sigmoid score (greedy) or by "
+                             "the score plus e_score_correction_bias (noaux_tc), without expert groups")
+        if self.hc_mult < 0 or (self.hc_mult and self.hc_sinkhorn_iters < 1):
+            raise ValueError(f"hc_mult {self.hc_mult}, hc_sinkhorn_iters {self.hc_sinkhorn_iters}: {wrote}"
+                             "hc_mult >= 0 streams (0: none) and at least one Sinkhorn iteration")
         self.check_use(self.n_routed_experts)
 
     @classmethod
     def from_raw(cls, raw: Dict[str, Any]) -> "LMConfig":
         kw = published_from_raw(raw, PUBLISHED_KEYS, "an MLA-family")
+        kw["model_type"] = raw.get("model_type")
+        if kw["model_type"] == XING:
+            kw.update(published_from_raw(raw, XING_KEYS, f"a {XING}"), sandwich_norm=False)
+        for k in ("sandwich_norm", "topk_method"):  # any file of the family may state them
+            if k in raw:
+                kw[k] = raw[k]
+        if raw.get("scoring_func", "sigmoid") != "sigmoid" or raw.get("n_group", 1) != 1 \
+                or raw.get("topk_group", 1) != 1:
+            raise ValueError("an MLA-family config.json: this model code writes down sigmoid scoring over one "
+                             f"expert group (scoring_func {raw.get('scoring_func')!r}, n_group {raw.get('n_group')}, "
+                             f"topk_group {raw.get('topk_group')})")
+        scaling = raw.get("rope_scaling")
+        if scaling:
+            if scaling.get("type") != "yarn":
+                raise ValueError(f"rope_scaling type {scaling.get('type')!r}: only yarn is written down")
+            kw.update({f"rope_scaling_{k}": v for k, v in published_from_raw(scaling, YARN_KEYS,
+                                                                             "a yarn rope_scaling group of").items()})
         return cls(**kw, **use_from_raw(raw, kw["n_routed_experts"], kw["vocab_size"]))
 
     @classmethod
@@ -212,6 +294,17 @@ class LMConfig(GeneratorUse):
     @property
     def cache_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def score_divisor(self) -> float:
+        """What attention's scores are divided by: ``sqrt(qk head dim)``, over
+        yarn's ``(0.1 · mscale_all_dim · ln factor + 1)²`` where RoPE is scaled."""
+        m = self.yarn_gain(self.rope_scaling_mscale_all_dim)
+        return math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim) / (m * m)
+
+    def yarn_gain(self, mscale: float) -> float:
+        """yarn's ``0.1 · mscale · ln factor + 1`` (1 where RoPE is not scaled)."""
+        return 0.1 * mscale * math.log(self.rope_scaling_factor) + 1.0 if self.rope_scaling_factor > 1 else 1.0
 
     @property
     def n_moe_layers(self) -> int:
@@ -270,8 +363,8 @@ def _swiglu_init(key, d: int, f: int, dt, experts: int = 0) -> Params:
 def _block_init(key, cfg: LMConfig, moe: bool) -> Params:
     d, H, dt = cfg.hidden_size, cfg.num_attention_heads, cfg.compute_dtype
     ks = jax.random.split(key, 8)
-    p: Params = {
-        "n1": _norm(d), "n2": _norm(d), "n3": _norm(d), "n4": _norm(d),
+    p: Params = {k: _norm(d) for k in (("n1", "n2", "n3", "n4") if cfg.sandwich_norm else ("n1", "n3"))}
+    p.update({
         "mla": {
             "wdq": _kernel(ks[0], (d, cfg.q_lora_rank), dt),
             "q_norm": _norm(cfg.q_lora_rank),
@@ -281,7 +374,16 @@ def _block_init(key, cfg: LMConfig, moe: bool) -> Params:
             "wukv": _kernel(ks[3], (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt),
             "wo": _kernel(ks[4], (H * cfg.v_head_dim, d), dt),
         },
-    }
+    })
+    if cfg.hc_mult:
+        # float32 and never quantized. Seeded at φ ~ N(0, 1/nC), α = 1, b = 0 (assumed, not the paper's
+        # near-identity start): every entry of x̃φ is O(1), so H_res is another doubly-stochastic matrix at
+        # every token and a program that dropped the dynamic term would fail every comparison
+        n, nC = cfg.hc_mult, cfg.hc_mult * d
+        for j, name in enumerate(("hc_attn", "hc_ffn")):
+            p[name] = {"phi": jax.random.normal(jax.random.fold_in(key, 100 + j), (nC, n * (n + 2)), jnp.float32)
+                       / math.sqrt(nC),
+                       "b": jnp.zeros((n * (n + 2),), jnp.float32), "alpha": jnp.ones((3,), jnp.float32)}
     if moe:
         p["moe"] = {
             # float32 and never quantized: the model's code routes in float32
@@ -289,6 +391,10 @@ def _block_init(key, cfg: LMConfig, moe: bool) -> Params:
             "experts": _swiglu_init(ks[6], d, cfg.moe_intermediate_size, dt, experts=cfg.experts_held),
             "shared": _swiglu_init(ks[7], d, cfg.moe_intermediate_size, dt),
         }
+        if cfg.topk_method == "noaux_tc":
+            # seeded at N(0, 0.1²) (assumed): a choice by the score alone then differs from the model's
+            p["moe"]["router"]["e_score_correction_bias"] = 0.1 * jax.random.normal(
+                jax.random.fold_in(key, 102), (cfg.n_routed_experts,), jnp.float32)
     else:
         p["ffn"] = _swiglu_init(ks[6], d, cfg.intermediate_size, dt)
     return p
@@ -335,13 +441,39 @@ def _rms(x: jax.Array, p: Params, cfg: LMConfig) -> jax.Array:
     return nn.rms_norm(x, p, eps=cfg.rms_norm_eps)
 
 
-def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """Plain RoPE, rotate-half convention (assumed: the config has no scaling
-    keys): ``x [..., dr]`` at positions ``pos`` broadcastable to ``x[..., 0]``."""
+def _yarn_blend(cfg: LMConfig, half: int):
+    """Share of the *unscaled* frequency in each of the ``half`` rotary pairs
+    (yarn): 1 below the pair that turns ``beta_fast`` times over the original
+    context, 0 above the one that turns ``beta_slow`` times, linear between
+    (the two bounds rounded outward, as DeepSeek-V3's modelling code does)."""
+    dim, span = 2 * half, cfg.rope_scaling_original_max_position_embeddings
+
+    def pair_of(turns: float) -> float:
+        return dim * math.log(span / (turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    lo = max(math.floor(pair_of(cfg.rope_scaling_beta_fast)), 0)
+    hi = min(math.ceil(pair_of(cfg.rope_scaling_beta_slow)), dim - 1)
+    ramp = (jnp.arange(half, dtype=jnp.float32) - lo) / max(hi - lo, 1e-3)
+    return 1.0 - jnp.clip(ramp, 0.0, 1.0)
+
+
+def _rope(x: jax.Array, pos: jax.Array, theta: float, yarn: Optional[LMConfig] = None) -> jax.Array:
+    """RoPE, rotate-half convention (assumed): ``x [..., dr]`` at positions
+    ``pos`` broadcastable to ``x[..., 0]``. Plain, or — ``yarn``: a
+    configuration whose ``rope_scaling_factor`` is over 1 — with blended
+    frequencies (:func:`_yarn_blend`) and cos / sin × ``mscale``'s ratio (1 as
+    the ``xing4_0`` file states it)."""
     half = x.shape[-1] // 2
     freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    amp = 1.0
+    if yarn is not None and yarn.rope_scaling_factor > 1:
+        keep = _yarn_blend(yarn, half)
+        freqs = freqs * keep + freqs / yarn.rope_scaling_factor * (1.0 - keep)
+        amp = yarn.yarn_gain(yarn.rope_scaling_mscale) / yarn.yarn_gain(yarn.rope_scaling_mscale_all_dim)
     ang = pos.astype(jnp.float32)[..., None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
@@ -360,12 +492,12 @@ def _mla_project(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array,
     H, dn, dr, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     kvr = nn.dense(p["wdkv"], u, lookup(lora, f"{path}/wdkv"), scale)
     entry = jnp.concatenate([_rms(kvr[..., :c], p["kv_norm"], cfg),
-                             _rope(kvr[..., c:], pos, cfg.rope_theta)], axis=-1)
+                             _rope(kvr[..., c:], pos, cfg.rope_theta, cfg)], axis=-1)
     if entry_only:
         return entry
     cq = _rms(nn.dense(p["wdq"], u, lookup(lora, f"{path}/wdq"), scale), p["q_norm"], cfg)
     q = nn.dense(p["wuq"], cq, lookup(lora, f"{path}/wuq"), scale).reshape(*u.shape[:-1], H, dn + dr)
-    q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], pos[..., None], cfg.rope_theta)
+    q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], pos[..., None], cfg.rope_theta, cfg)
     return q_nope, q_rope, entry
 
 
@@ -382,7 +514,7 @@ def mla_prefill(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array, valid: j
         f32 = jnp.float32
         sc = (jnp.einsum("sqhj,skhj->shqk", q_nope, kv[..., :dn], preferred_element_type=f32)
               + jnp.einsum("sqhr,skr->shqk", q_rope, entry[..., c:], preferred_element_type=f32))
-        sc = sc / math.sqrt(dn + cfg.qk_rope_head_dim)
+        sc = sc / cfg.score_divisor
         see = jnp.tril(jnp.ones((T, T), bool))[None, None] & valid[:, None, None, :]
         pr = jax.nn.softmax(jnp.where(see, sc, -1e30), axis=-1)
         o = jnp.einsum("shqk,skhv->sqhv", pr.astype(u.dtype), kv[..., dn:]).reshape(S, T, H * dv)
@@ -415,7 +547,7 @@ def mla_decode(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array, cache: ja
         ckv, kr = cache[..., :c], cache[..., c:]
         sc = (jnp.einsum("shc,stc->sht", q_lat, ckv, preferred_element_type=f32)
               + jnp.einsum("shr,str->sht", q_rope, kr, preferred_element_type=f32))
-        sc = sc / math.sqrt(dn + cfg.qk_rope_head_dim)
+        sc = sc / cfg.score_divisor
         pr = jax.nn.softmax(jnp.where(valid[:, None, :], sc, -1e30), axis=-1)
         o_lat = jnp.einsum("sht,stc->shc", pr.astype(dt), ckv)
         o = jnp.einsum("shc,chv->shv", o_lat, w[..., dn:])
@@ -426,9 +558,14 @@ def mla_decode(p: Params, cfg: LMConfig, u: jax.Array, pos: jax.Array, cache: ja
 
 def route(p: Params, cfg: LMConfig, u: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """``u [R, d]`` → (expert ids ``[R, k]`` of all ``n_routed_experts``,
-    weights ``[R, k]`` normalized over the k chosen). No groups, no bias."""
+    weights ``[R, k]`` normalized over the k chosen). No groups; ``noaux_tc``
+    chooses by score + bias and weighs by the score."""
     s = jax.nn.sigmoid(u.astype(jnp.float32) @ p["router"]["weight"].T)
-    top_s, top_i = jax.lax.top_k(s, cfg.num_experts_per_tok)
+    if cfg.topk_method == "noaux_tc":
+        _, top_i = jax.lax.top_k(s + p["router"]["e_score_correction_bias"], cfg.num_experts_per_tok)
+        top_s = jnp.take_along_axis(s, top_i, axis=-1)
+    else:
+        top_s, top_i = jax.lax.top_k(s, cfg.num_experts_per_tok)
     w = top_s / (top_s.sum(-1, keepdims=True) + 1e-20) if cfg.norm_topk_prob else top_s
     return top_i.astype(jnp.int32), w * cfg.routed_scaling_factor
 
@@ -553,26 +690,137 @@ def moe(p: Params, cfg: LMConfig, u: jax.Array, row_valid: jax.Array, lora: Opti
     return shared + routed, stats
 
 
+# ---------------------------------------------------------------------------
+# the residual path: one vector a token, or hyper-connection streams
+# ---------------------------------------------------------------------------
+
+def hc_coefficients(hc: Params, cfg: LMConfig, x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One sub-layer's hyper-connection coefficients for streams ``x [..., n,
+    C]``: (``H_pre [..., n]``, ``H_post [..., n]``, ``H_res [..., n, n]``,
+    doubly stochastic up to the Sinkhorn iterations' convergence), computed in
+    :data:`HC_DTYPE` whatever the stream's dtype."""
+    n, ft = cfg.hc_mult, HC_DTYPE
+    eps = jnp.asarray(cfg.hc_eps, ft)
+    with jax.named_scope("hc_coeff"):
+        v = x.reshape(*x.shape[:-2], -1).astype(ft)
+        xt = v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+        z = jnp.dot(xt, hc["phi"].astype(ft), precision=jax.lax.Precision.HIGHEST)
+        a, b = hc["alpha"].astype(ft), hc["b"].astype(ft)
+        pre = jax.nn.sigmoid(a[0] * z[..., :n] + b[:n])
+        post = HC_POST_GAIN * jax.nn.sigmoid(a[1] * z[..., n: 2 * n] + b[n: 2 * n])
+        logits = jnp.clip(a[2] * z[..., 2 * n:] + b[2 * n:], cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max)
+    with jax.named_scope("hc_sinkhorn"):
+        # 80 small launches a sub-layer on a TPU (a ``sum`` over 4 is a reduce, which ends a fusion; PERF.md §6,
+        # PR 33 says what was tried in ``jax.numpy`` instead and why one kernel is the next step)
+        m = jnp.exp(logits.reshape(*logits.shape[:-1], n, n))
+        for _ in range(cfg.hc_sinkhorn_iters):
+            m = m / (m.sum(-2, keepdims=True) + eps)   # every column by its sum
+            m = m / (m.sum(-1, keepdims=True) + eps)   # then every row by its sum
+    return pre, post, m
+
+
+def _hc_read(pre: jax.Array, x: jax.Array) -> jax.Array:
+    """``u = H_pre X``: the sub-layer's input ``[..., C]``. Four terms a
+    number, multiplied and summed in float32 on the vector unit (a dot at the
+    default precision would round the coefficients to bfloat16)."""
+    with jax.named_scope("hc_mix"):
+        return (pre.astype(jnp.float32)[..., None] * x.astype(jnp.float32)).sum(-2).astype(x.dtype)
+
+
+def _hc_write(post: jax.Array, res: jax.Array, x: jax.Array, y: jax.Array) -> jax.Array:
+    """``X ← H_res X + H_postᵀ y``, in float32, rounded once to the stream's dtype."""
+    f32 = jnp.float32
+    with jax.named_scope("hc_mix"):
+        mixed = (res.astype(f32)[..., None] * x.astype(f32)[..., None, :, :]).sum(-2)
+        return (mixed + post.astype(f32)[..., None] * y.astype(f32)[..., None, :]).astype(x.dtype)
+
+
+def _sublayer(hc: Optional[Params], cfg: LMConfig, x: jax.Array, f, scope: Optional[str],
+              hc_seen: Optional[List[jax.Array]]):
+    """One sub-layer ``f(u) -> (y, extra)`` (its norms inside) on the residual
+    path the configuration states: ``x + f(x)`` on one vector, or read /
+    write of the streams by this sub-layer's coefficients. ``f`` and the
+    plain add run under ``scope``; the streams' work under ``lm_hc``."""
+    ctx = jax.named_scope(scope) if scope else contextlib.nullcontext()
+    if not cfg.hc_mult:
+        with ctx:
+            y, extra = f(x)
+            return x + y, extra
+    with jax.named_scope("lm_hc"):
+        pre, post, res = hc_coefficients(hc, cfg, x)
+        u = _hc_read(pre, x)
+    with ctx:
+        y, extra = f(u)
+    with jax.named_scope("lm_hc"):
+        x = _hc_write(post, res, x, y)
+    if hc_seen is not None:
+        hc_seen.append(res)
+    return x, extra
+
+
 def block(p: Params, cfg: LMConfig, li: int, x: jax.Array, attn, row_valid: jax.Array,
-          lora: Optional[Params], factors, scale: float, prefix: str = "layers"):
-    """Sandwich-norm block on ``x [..., d]``; ``attn(u) -> (out, extra)`` is
-    the MLA form the caller is in (prefill or decode). Returns (y, extra, MoE
-    stats or None)."""
+          lora: Optional[Params], factors, scale: float, prefix: str = "layers",
+          hc_seen: Optional[List[jax.Array]] = None):
+    """One layer on ``x [..., d]`` — with hyper-connections on the streams
+    ``x [..., n, d]`` — sandwich norm or pre-norm as the configuration says;
+    ``attn(u) -> (out, extra)`` is the MLA form the caller is in (prefill or
+    decode). Returns (y, extra, MoE stats or None); the two sub-layers'
+    ``H_res`` are appended to ``hc_seen`` where a list is given."""
     path = f"{prefix}/{li}"
-    with jax.named_scope("lm_mla"):
-        a, extra = attn(_rms(x, p["n1"], cfg))
-        h = x + _rms(a, p["n2"], cfg)
-    u = _rms(h, p["n3"], cfg)
-    if "moe" in p:
-        with jax.named_scope("lm_moe"):
-            flat = u.reshape(-1, u.shape[-1])
-            f, stats = moe(p["moe"], cfg, flat, row_valid.reshape(-1), lora,
-                           factors, f"{path}/moe", scale)
-            f = f.reshape(u.shape)
-    else:
-        with jax.named_scope("lm_dense_ffn"):
-            f, stats = _swiglu(p["ffn"], u, lora, f"{path}/ffn", scale), None
-    return h + _rms(f, p["n4"], cfg), extra, stats
+    after = (lambda t, n: _rms(t, p[n], cfg)) if cfg.sandwich_norm else (lambda t, n: t)
+
+    def attention(u):
+        a, extra = attn(_rms(u, p["n1"], cfg))
+        return after(a, "n2"), extra
+
+    def ffn(h):
+        u = _rms(h, p["n3"], cfg)
+        if "moe" in p:
+            with jax.named_scope("lm_moe"):
+                flat = u.reshape(-1, u.shape[-1])
+                f, stats = moe(p["moe"], cfg, flat, row_valid.reshape(-1), lora,
+                               factors, f"{path}/moe", scale)
+                f = f.reshape(u.shape)
+        else:
+            with jax.named_scope("lm_dense_ffn"):
+                f, stats = _swiglu(p["ffn"], u, lora, f"{path}/ffn", scale), None
+        return after(f, "n4"), stats
+
+    h, extra = _sublayer(p.get("hc_attn"), cfg, x, attention, "lm_mla", hc_seen)
+    y, stats = _sublayer(p.get("hc_ffn"), cfg, h, ffn, None, hc_seen)
+    return y, extra, stats
+
+
+def _stream_in(cfg: LMConfig, x: jax.Array) -> jax.Array:
+    """``X₀``: ``hc_mult`` copies of a token's vector (the vector itself without hyper-connections)."""
+    return jnp.repeat(x[..., None, :], cfg.hc_mult, axis=-2) if cfg.hc_mult else x
+
+
+def _stream_out(cfg: LMConfig, x: jax.Array) -> jax.Array:
+    """The model's output: the streams summed (assumed: the hyper-connections convention)."""
+    return x.astype(jnp.float32).sum(-2).astype(x.dtype) if cfg.hc_mult else x
+
+
+def _hc_gauges(seen: List[jax.Array], valid: jax.Array) -> Dict[str, jax.Array]:
+    """The ``H_res`` of some sub-layers (each ``[S, ..., n, n]``, ``valid [S,
+    ...]`` marking tokens) → per sequence ``[S]``: ``err``, the largest |row or
+    column sum − 1| (the columns' part is what the Sinkhorn iterations have
+    not converged away); ``row``, the largest |row sum − 1| alone (rows are
+    normalized last: float32 leaves ``hc_eps`` and a rounding, a narrower
+    coefficient path its own spacing); ``off``, the off-diagonal mass summed
+    over its tokens and sub-layers (1 − trace ÷ n each); ``n``, how many."""
+    r = jnp.stack(seen).astype(jnp.float32)                                       # [k, S, ..., n, n]
+    row = jnp.abs(r.sum(-1) - 1.0).max(-1)
+    err = jnp.maximum(row, jnp.abs(r.sum(-2) - 1.0).max(-1))
+    off = 1.0 - jnp.trace(r, axis1=-2, axis2=-1) / r.shape[-1]
+    axes = (0,) + tuple(range(2, err.ndim))
+    return {"err": jnp.where(valid, err, 0.0).max(axes), "row": jnp.where(valid, row, 0.0).max(axes),
+            "off": jnp.where(valid, off, 0.0).sum(axes),
+            "n": (valid.astype(jnp.float32) * len(seen)).sum(tuple(range(1, valid.ndim)))}
+
+
+def _hc_gauges_joined(was: Dict[str, jax.Array], now: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    return {k: jnp.maximum(was[k], now[k]) if k in ("err", "row") else was[k] + now[k] for k in was}
 
 
 def _embed(params: Params, cfg, ids: jax.Array) -> jax.Array:
@@ -584,10 +832,12 @@ def _head(params: Params, cfg: LMConfig, h: jax.Array) -> jax.Array:
 
 
 def prefill(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
-            lora: Optional[Params] = None, lora_scale: float = 1.0, factors=None, cache_only: bool = False):
+            lora: Optional[Params] = None, lora_scale: float = 1.0, factors=None, cache_only: bool = False,
+            hc_seen: Optional[List[jax.Array]] = None):
     """``ids [S, T]`` (right-padded, ``lens [S]`` real) through every block.
-    Returns (hidden ``[S, T, d]`` before the final norm, per-layer cache
-    entries ``[S, T, c + dr]``, per-MoE-layer stats with rows ``[S, T]``).
+    Returns (hidden ``[S, T, d]`` before the final norm — the streams summed,
+    where the model has them —, per-layer cache entries ``[S, T, c + dr]``,
+    per-MoE-layer stats with rows ``[S, T]``); ``hc_seen``: see :func:`block`.
     ``cache_only`` (generation: the begin-of-image position, not the prompt's
     last, yields the first logits): the last layer stops at its cache entry —
     nothing reads what its attention and FFN would add, so they are neither
@@ -596,21 +846,25 @@ def prefill(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
     pos = jnp.broadcast_to(jnp.arange(T), (S, T))
     valid = pos < lens[:, None]
     factors = factors if factors is not None else expert_factors(lora, cfg, cfg.compute_dtype)
-    x = _embed(params, cfg, ids)
+    x = _stream_in(cfg, _embed(params, cfg, ids))
     entries, stats = [], []
     for li, p in enumerate(params["layers"]):
         if cache_only and li == len(params["layers"]) - 1:
+            if cfg.hc_mult:
+                with jax.named_scope("lm_hc"):
+                    x = _hc_read(hc_coefficients(p["hc_attn"], cfg, x)[0], x)
             with jax.named_scope("lm_mla"):
                 entries.append(_mla_project(p["mla"], cfg, _rms(x, p["n1"], cfg), pos, lora,
                                             f"layers/{li}/mla", lora_scale, entry_only=True))
             return None, entries, stats
         attn = lambda u, p=p, li=li: mla_prefill(p["mla"], cfg, u, pos, valid, lora, f"layers/{li}/mla", lora_scale)
-        x, entry, st = block(p, cfg, li, x, attn, valid, lora, factors[li] if factors else None, lora_scale)
+        x, entry, st = block(p, cfg, li, x, attn, valid, lora, factors[li] if factors else None, lora_scale,
+                             hc_seen=hc_seen)
         entries.append(entry)
         if st is not None:
             stats.append({"assign": st["assign"].reshape(S, T), "load": st["load"],
                           "topk": st["topk"].reshape(S, T, -1)})
-    return x, entries, stats
+    return _stream_out(cfg, x), entries, stats
 
 
 def forward_logits(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
@@ -630,20 +884,26 @@ def mtp_logits(params: Params, cfg: LMConfig, hidden: jax.Array, next_ids: jax.A
     pos = jnp.broadcast_to(jnp.arange(T), (S, T))
     valid = pos < lens[:, None]
     x = jnp.concatenate([_rms(hidden, p["nh"], cfg), _rms(_embed(params, cfg, next_ids), p["ne"], cfg)], axis=-1)
-    x = nn.dense(p["proj"], x)
+    x = _stream_in(cfg, nn.dense(p["proj"], x))
     attn = lambda u: mla_prefill(p["block"]["mla"], cfg, u, pos, valid, None, "mtp", 1.0)
     y, _, _ = block(p["block"], cfg, 0, x, attn, valid, None, None, 1.0, prefix="mtp")
-    return _head(params, cfg, y)
+    return _head(params, cfg, _stream_out(cfg, y))
 
 
 def _mla_prefill_state(params: Params, cfg: LMConfig, ids: jax.Array, lens: jax.Array,
                        lora, lora_scale: float, factors):
-    """:class:`Family` hook: the prompt into one latent cache a layer."""
+    """:class:`Family` hook: the prompt into one latent cache a layer (and,
+    with hyper-connections, the gauges of the ``H_res`` seen so far)."""
     B, P = ids.shape
     dt = cfg.compute_dtype
-    _, entries, stats = prefill(params, cfg, ids, lens, lora, lora_scale, factors, cache_only=True)
-    caches = tuple(jnp.zeros((B, cfg.cache_len, cfg.cache_width), dt).at[:, :P].set(e.astype(dt)) for e in entries)
-    return caches, stats, {}
+    seen: List[jax.Array] = []
+    _, entries, stats = prefill(params, cfg, ids, lens, lora, lora_scale, factors, cache_only=True, hc_seen=seen)
+    state = {"caches": tuple(jnp.zeros((B, cfg.cache_len, cfg.cache_width), dt).at[:, :P].set(e.astype(dt))
+                             for e in entries)}
+    if cfg.hc_mult:
+        state["hc"] = _hc_gauges(seen, jnp.arange(P) < lens[:, None]) if seen else \
+            {k: jnp.zeros((B,), jnp.float32) for k in ("err", "row", "off", "n")}
+    return state, stats, {}
 
 
 def decode_slot(cfg: GeneratorUse, i: jax.Array, prompt_len: jax.Array):
@@ -659,25 +919,37 @@ def decode_slot(cfg: GeneratorUse, i: jax.Array, prompt_len: jax.Array):
     return slot, pos, valid
 
 
-def _mla_decode_layers(params: Params, cfg: LMConfig, x: jax.Array, caches, i: jax.Array, prompt_len: jax.Array,
+def _mla_decode_layers(params: Params, cfg: LMConfig, x: jax.Array, state, i: jax.Array, prompt_len: jax.Array,
                        lora, lora_scale: float, factors):
     """:class:`Family` hook: sampled position ``i`` of every sequence, ``x [B,
     d]``, through the blocks over the latent caches."""
     B = x.shape[0]
     slot, pos, valid = decode_slot(cfg, i, prompt_len)
-    new_caches, stats = [], []
+    caches, new_caches, stats, seen = state["caches"], [], [], []
+    x = _stream_in(cfg, x)
     for li, p in enumerate(params["layers"]):
         attn = lambda u, p=p, li=li: mla_decode(
             p["mla"], cfg, u, pos, caches[li], slot, valid, lora, f"layers/{li}/mla", lora_scale)
         x, cache, st = block(p, cfg, li, x, attn, jnp.ones((B,), bool), lora,
-                             factors[li] if factors else None, lora_scale)
+                             factors[li] if factors else None, lora_scale, hc_seen=seen)
         new_caches.append(cache)
         if st is not None:
             stats.append(st)
-    return x, tuple(new_caches), stats
+    state = dict(state, caches=tuple(new_caches))
+    if cfg.hc_mult:
+        with jax.named_scope("lm_hc"):
+            state["hc"] = _hc_gauges_joined(state["hc"], _hc_gauges(seen, jnp.ones((B,), bool)))
+    return _stream_out(cfg, x), state, stats
 
 
-MLA_FAMILY = Family(init=_init_mla, prefill_state=_mla_prefill_state, decode_layers=_mla_decode_layers, head=_head)
+def _mla_state_rows(cfg: LMConfig, state) -> Dict[str, jax.Array]:
+    """:class:`Family` hook: the hyper-connection gauges a sequence gathered
+    (``backends/lm_backend.step_metrics`` reduces them to ``lm/hc_*``)."""
+    return {f"hc_{k}": v for k, v in state.get("hc", {}).items()}
+
+
+MLA_FAMILY = Family(init=_init_mla, prefill_state=_mla_prefill_state, decode_layers=_mla_decode_layers, head=_head,
+                    state_rows=_mla_state_rows)
 
 PROBE_EVERY = 16  # logits are kept at every 16th sampled position
 
@@ -703,7 +975,8 @@ def generate(
     where none), ``assign`` ``[B]`` (token–expert pairs computed here), ``load``
     ``[B]`` (largest expert load ratio of any call, the same for every image of
     a call), ``logits`` ``[B, n / PROBE_EVERY, image_vocab]`` and, where the
-    family says what a sequence carries, ``carried/<kind>`` ``[B]`` in bytes).
+    family says what a sequence carries, ``carried/<kind>`` ``[B]`` in bytes;
+    with hyper-connections ``hc_err``, ``hc_row``, ``hc_off``, ``hc_n`` ``[B]``, :func:`_hc_gauges`).
 
     Sampling keys fold in the step and each image's *global* batch position
     (``item_index``), so outputs do not depend on how the batch is chunked."""
@@ -753,7 +1026,7 @@ def generate(
 
         probe0 = jnp.zeros((B, n // PROBE_EVERY, cfg.image_vocab), jnp.float32)
         boi = jnp.full((B,), cfg.boi_id, jnp.int32)
-        (_, _, assign, load, probe), (ids, topk_d) = jax.lax.scan(
+        (_, state, assign, load, probe), (ids, topk_d) = jax.lax.scan(
             step, (boi, state, assign, load, probe0), jnp.arange(n))
         ids = ids.T                                                           # [B, n]
         topk = jnp.concatenate([topk_p, jnp.moveaxis(topk_d, 0, 1)], axis=1)  # [B, Tmax, layers, k]
@@ -761,6 +1034,8 @@ def generate(
     rows = {"ids": ids, "topk": topk, "assign": assign,
             "load": jnp.broadcast_to(load, (B,)), "logits": probe}
     rows.update({f"carried/{k}": jnp.full((B,), v, jnp.float32) for k, v in carried_bytes.items()})
+    if fam.state_rows is not None:
+        rows.update(fam.state_rows(cfg, state))
     if not decode:
         return ids, rows
     with jax.named_scope("decode"):

@@ -338,6 +338,7 @@ INNER_SCOPES = (
     "lm_prefill", "lm_decode_step",                                    # lm_ar: the two phases of generate
     "lm_mla", "attend", "lm_dense_ffn", "lm_moe", "router", "experts", "shared", "lm_head",  # inside them
     "lm_gdn", "conv", "delta_rule", "gdn_out", "lm_attn",              # the hybrid family's mixers (lm_attn -> attend)
+    "lm_hc", "hc_coeff", "hc_sinkhorn", "hc_mix",                      # hyper-connection streams around every sub-layer
     "preprocess", "clip_b", "clip_h", "score",                         # rewards
     "perturb",                                                         # es_noise: one member's adapter
     "fitness", "update", "health",                                     # es_update

@@ -184,21 +184,35 @@ def test_mtp_module_against_reference(toy):
 
 # (d) the share adds up --------------------------------------------------------
 
-def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer(tmp_path, toy, form):
-    """16 experts over 4 shares: the routed parts of the four shares plus the
-    shared expert counted once equal the uncut reference's MoE output."""
-    cfg, raw, params = toy
+@pytest.mark.parametrize("family", ["sandwich", "xing4_0"])
+def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(tmp_path, family, form):
+    """16 experts over 4 shares (the sandwich-norm toy, top-k by the score) or
+    8 experts over 2 shares under the selection-bias router (the ``xing4_0``
+    toy): the routed parts of the shares plus the shared expert counted once
+    equal the uncut reference's MoE output."""
+    if family == "xing4_0":
+        from hyperscalees_t2i_tpu.reference import mhc_moe_reference as reference
+        from tests.test_lm_mhc import toy_cfg as make_cfg
+    else:
+        reference, make_cfg = ref, toy_cfg
+    cfg, raw = make_cfg(tmp_path)
+    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
     p = params["layers"][1]["moe"]
+    held, shares = 4, cfg.n_routed_experts // 4
     u = jax.random.normal(jax.random.PRNGKey(7), (10, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
-        whole, _ = ref.moe(ref.block_weights(params["layers"][1], "x"), raw, u)
+        whole, picked = reference.moe(reference.block_weights(params["layers"][1], "x"), raw, u)
     top_i, top_w = lm.route(p, cfg, u)
+    assert np.array_equal(np.sort(np.asarray(top_i), -1), np.sort(np.asarray(picked), -1))
+    if family == "xing4_0":  # the bias changes the choice: by the score alone some token chooses otherwise
+        by_score = jax.lax.top_k(jax.nn.sigmoid(u @ p["router"]["weight"].T), cfg.num_experts_per_tok)[1]
+        assert not np.array_equal(np.sort(np.asarray(by_score), -1), np.sort(np.asarray(top_i), -1))
     total = lm._swiglu(p["shared"], u, None, "x", 1.0)
-    for share in range(4):
-        cfg_s, _ = toy_cfg(tmp_path, experts_held=4, expert_offset=4 * share)
-        mine = {k: {"kernel": v["kernel"][4 * share: 4 * share + 4]} for k, v in p["experts"].items()}
+    for share in range(shares):
+        cfg_s, _ = make_cfg(tmp_path, experts_held=held, expert_offset=held * share)
+        mine = {k: {"kernel": v["kernel"][held * share: held * (share + 1)]} for k, v in p["experts"].items()}
         routed, e = lm.routed_experts(mine, cfg_s, u, top_i, top_w, jnp.ones((10,), bool), None, 1.0)
-        assert int((e < 4).sum()) == int(((top_i >= 4 * share) & (top_i < 4 * share + 4)).sum())
+        assert int((e < held).sum()) == int(((top_i >= held * share) & (top_i < held * (share + 1))).sum())
         total = total + routed
     assert rel(total, whole) < TOL
 

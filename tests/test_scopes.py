@@ -238,8 +238,13 @@ LM_HYBRID_SCOPES = (
 )
 
 
+LM_MHC_SCOPES = LM_SCOPES | {f"generate/{phase}/lm_hc/{inner}" for phase in ("lm_prefill", "lm_decode_step")
+                             for inner in ("hc_coeff", "hc_sinkhorn", "hc_mix")}
+
+
 @pytest.mark.parametrize("family, want", [("sana_one_step", SANA_SCOPES), ("var", VAR_SCOPES),
-                                          ("lm_ar", LM_SCOPES), ("lm_ar:qwen3_next", LM_HYBRID_SCOPES)])
+                                          ("lm_ar", LM_SCOPES), ("lm_ar:qwen3_next", LM_HYBRID_SCOPES),
+                                          ("lm_ar:xing4_0", LM_MHC_SCOPES)])
 def test_compiled_step_carries_every_scope(family, want, tmp_path):
     """The guard against a refactor of the member loop silently dropping a
     scope: the tiny step of each family, compiled, names every top-level
@@ -255,7 +260,9 @@ def test_compiled_step_carries_every_scope(family, want, tmp_path):
     argv = ["--backend", family, "--model_scale", "tiny", "--prompts_txt", str(prompts),
             "--lora_r", "2", "--lora_alpha", "4"]
     if family == "lm_ar":
-        if model_type:
+        if model_type == "xing4_0":
+            from tests.test_lm_mhc import TOY
+        elif model_type:
             from tests.test_lm_hybrid import TOY
         else:
             from tests.test_lm import TOY
@@ -558,7 +565,8 @@ def test_member_reward_row_stays_out_of_the_pod_scalar_gather(tmp_path):
     from hyperscalees_t2i_tpu.train.trainer import _write_probe_once
 
     lm_row = _write_probe_once({**scalars, "moe/local_assignments": 12.0, "moe/max_expert_load": 2.0,
-                                "moe/pair_route_flip": 0.1, "probe/ids": np.zeros((4, 16), np.int32),
+                                "moe/pair_route_flip": 0.1, "lm/hc_marginal_err": 2e-3, "lm/hc_row_err": 1e-6,
+                                "lm/hc_offdiag_mass": 0.75, "probe/ids": np.zeros((4, 16), np.int32),
                                 "probe/logits": np.zeros((4, 1, 16), np.float32)}, None, 0)
     assert not any(k.startswith("probe/") for k in lm_row)
     assert set(host_reduce_keys(lm_row)) == set(keys)
