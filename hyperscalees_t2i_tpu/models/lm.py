@@ -92,8 +92,9 @@ import jax
 import jax.numpy as jnp
 
 from ..lora import LoRASpec, effective_factor, lookup
+from ..obs import note_program_geometry
 from ..ops import grouped
-from ..ops.quant import maybe_quantize_tree, resolve_kernel
+from ..ops.quant import kernel_shape, maybe_quantize_tree, resolve_kernel
 from ..ops.sampling import sample_top_k_top_p
 from . import msvq, nn
 
@@ -138,7 +139,8 @@ class Family(NamedTuple):
     init: Callable            # (key, cfg, base_quant) -> params
     prefill_state: Callable   # (params, cfg, ids, lens, lora, scale, factors) -> (carried state, MoE stats, bytes a sequence by kind)
     decode_layers: Callable   # (params, cfg, x, state, i, prompt_len, lora, scale, factors) -> (x, state, MoE stats)
-    head: Callable            # (params, cfg, hidden) -> float32 logits over the rows held
+    head: Callable            # (params, cfg, hidden) -> float32 logits over the columns of params["head"]: the rows
+                              # held, or the image-id range's where generate cut the node to them
     state_rows: Optional[Callable] = None  # (cfg, carried state after the scan) -> further per-image rows {name: [B]}
 
 
@@ -985,8 +987,17 @@ def generate(
     fam = cfg.family()
     item_idx = jnp.arange(B) if item_index is None else item_index
     lo, hi = cfg.image_id_offset, cfg.image_id_offset + cfg.image_vocab
+    note_program_geometry(lm_head_shape=kernel_shape(params["head"]))
 
     with jax.named_scope("generate"):
+        # sampling sees the image-id range only, so the decode scan is handed
+        # the head's columns of that range: every leaf of the node (an int8
+        # base and its scale, or a float kernel; a bias) cut on its output
+        # axis once, here. XLA sinks a slice of the logits through the dot but
+        # not through an int8 node's dequantization: it wrote the whole head to
+        # HBM at every position (``lm_head_whole_ops`` counts what is left)
+        with jax.named_scope("lm_head"):
+            image_head = {**params, "head": jax.tree_util.tree_map(lambda w: w[..., lo:hi], params["head"])}
         factors = expert_factors(lora, cfg, dt)
         with jax.named_scope("lm_prefill"):
             state, stats, carried_bytes = fam.prefill_state(params, cfg, prompt_ids, prompt_len, lora, lora_scale,
@@ -1011,7 +1022,7 @@ def generate(
                     load = jnp.maximum(load, st["load"])
                     tk.append(st["topk"])
                 with jax.named_scope("lm_head"):
-                    logits = fam.head(params, cfg, x)[:, lo:hi]  # sampling sees the image-id range only
+                    logits = fam.head(image_head, cfg, x)
                 with jax.named_scope("sample"):
                     k_i = jax.random.fold_in(key, i)
                     keys = jax.vmap(lambda j: jax.random.fold_in(k_i, j))(item_idx)

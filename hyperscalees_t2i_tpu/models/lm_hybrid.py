@@ -395,7 +395,17 @@ def block(p: Params, cfg: HybridLMConfig, li: int, x: jax.Array, mixer, row_vali
 
 
 def head(params: Params, cfg: HybridLMConfig, h: jax.Array) -> jax.Array:
-    return nn.dense(params["head"], _zrms(h, params["final_norm"], cfg)).astype(jnp.float32)
+    """Float32 logits over the columns of ``params["head"]``, as ONE array for
+    every reader. The TPU compiler widens the dot's bf16 result inside the
+    dot's own fusion, where the accumulator's float32 bits come out — what
+    ``generate``'s sampler and probe have both read in this family's step, and
+    what ``expected/`` records. Left free, it may hand one reader the *rounded*
+    bf16 result and widen that there: on the image-id columns it did, and the
+    sampler drew ids from other logits than the probe kept (PR 34; the barrier
+    keeps the widening where the dot is. The MLA family's step has always had
+    its sampler on the rounded result: ``lm._head`` is left as it is)."""
+    return jax.lax.optimization_barrier(
+        nn.dense(params["head"], _zrms(h, params["final_norm"], cfg)).astype(jnp.float32))
 
 
 def prefill(params: Params, cfg: HybridLMConfig, ids: jax.Array, lens: jax.Array,

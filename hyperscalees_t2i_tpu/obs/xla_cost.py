@@ -475,7 +475,8 @@ def kv_cache_whole_ops(compiled: Any, cache_shape: Any) -> Dict[str, int]:
     """Instructions of the optimized HLO module, by opcode, whose result is
     as large as a generator's whole KV cache: the K or V stack as the model
     keeps it (``cache_shape``, VAR: ``[depth, 2B, L, H, dh]``, noted at trace
-    time as ``kv_cache_shape``) or one layer of it (``cache_shape[1:]``),
+    time as ``kv_cache_shape``) or one layer of it (``cache_shape[1:]``; a
+    matrix, the head's kernel noted as ``lm_head_shape``, only whole),
     with or without one further axis (the member axis of a ``vmap``ped
     chunk). What a scale of generation needs is its own rows; each op counted
     here fills, copies or re-lays the cache whole — or, as a
@@ -494,7 +495,7 @@ def kv_cache_whole_ops(compiled: Any, cache_shape: Any) -> Dict[str, int]:
     import re
 
     stack = tuple(int(d) for d in cache_shape)
-    wanted = (stack, stack[1:])
+    wanted = (stack, stack[1:]) if len(stack) > 2 else (stack,)  # a matrix (``lm_head_shape``) has no layers
 
     def whole(dims: tuple) -> bool:
         return dims in wanted or any(
@@ -907,11 +908,12 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
     the compiler's peak as an ``obs/`` gauge (→ next ``metrics.jsonl`` row).
     With the tracer enabled, also write the program's op → scope table
     (:func:`scope_table`) to ``scopes/<label>.json`` beside the ledger and
-    name it in the record, and count the ops as large as the KV cache or the
-    recurrent states a generator noted (:func:`kv_cache_whole_ops` on
-    ``kv_cache_shape`` and on ``recurrent_state_shape``: the stack of the
-    layers' states, one layer's, with or without the member axis). The one
-    call every compile site makes. Never raises."""
+    name it in the record, and count the ops as large as the KV cache, the
+    recurrent states or the head a generator noted (:func:`kv_cache_whole_ops`
+    on ``kv_cache_shape``, ``recurrent_state_shape`` and ``lm_head_shape``:
+    the stack of the layers' states, one layer's, with or without the member
+    axis; the head's one kernel). The one call every compile site makes.
+    Never raises."""
     try:
         rec = program_record(**kwargs)
     except Exception:
@@ -929,7 +931,7 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
                 path.parent.mkdir(parents=True, exist_ok=True)
                 path.write_text(json.dumps(table, sort_keys=True))
                 rec["scope_table"] = str(rel)
-            for carried in ("kv_cache", "recurrent_state"):
+            for carried in ("kv_cache", "recurrent_state", "lm_head"):
                 shape = rec["geometry"].get(f"{carried}_shape")
                 if shape:
                     rec[f"{carried}_whole_ops"] = kv_cache_whole_ops(compiled, shape)

@@ -374,6 +374,68 @@ def test_mosaic_compiles_the_gated_delta_step_of_the_hybrid_cell(members, v5e_ch
     assert kv_cache_whole_ops(compiled, args[5].shape) == {"custom-call": 1}
 
 
+@pytest.mark.parametrize("family", ["mla", "mla-streams", "hybrid"])
+def test_the_decode_scan_reads_the_head_as_the_tpu_compiler_builds_it(family, v5e_chip, tmp_path, monkeypatch):
+    """``models/lm.generate`` under ``vmap`` over eight members at toy widths
+    in bfloat16 over an int8 head, compiled for a v5e (PR 34; here because one
+    file of a test run may load libtpu). (1) ``lm_head_whole_ops`` as
+    ``record_compile`` counts it: nothing is as large as the whole head, where
+    the parent's form (``fam.head(params, cfg, x)[:, lo:hi]``) carries it whole
+    through the scan (at the cells' widths it also dequantizes it whole there,
+    one ``fusion`` a position: PERF.md §5). (2) The hybrid family's logits
+    leave the dot's own fusion as ONE float32 array, which the sampler and the
+    probe both read: without ``lm_hybrid.head``'s ``optimization_barrier`` that
+    fusion hands the sampler a second, bf16 result (the accumulator rounded) —
+    on the chip the hybrid cell then drew ids from other logits than it probed,
+    and than ``expected/`` records. (The MLA family's step has always had its
+    sampler on the rounded result, in the parent's program too; it is left so.)"""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+    from hyperscalees_t2i_tpu.models import lm
+    from hyperscalees_t2i_tpu.obs import xla_cost
+    from tests import test_lm_head_columns as cols
+
+    cfg, _, params = cols.toy(tmp_path, monkeypatch, family, cols.OFFSETS["off-128s"], "int8", torch_dtype="bfloat16")
+    assert cfg.compute_dtype == jnp.bfloat16
+    M, B = 8, 8
+    s = SingleDeviceSharding(v5e_chip)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=s)
+    args = (jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params), sds((B, cfg.max_prompt_len), jnp.int32),
+            sds((B,), jnp.int32), sds((M, 2), jnp.uint32))
+
+    def compiled_generate():
+        def members(params, prompt, lens, keys):  # the head is shared, the sequences are a member's own
+            return jax.vmap(lambda k: lm.generate(params, cfg, prompt, lens, k, decode=False))(keys)
+
+        with jax.default_matmul_precision("default"):  # the program as deployed, not conftest's "highest"
+            compiled = jax.jit(members).lower(*args).compile()
+        shape = xla_cost.program_record(site="test", label="generate")["geometry"]["lm_head_shape"]  # as noted
+        assert tuple(shape) == (cfg.hidden_size, cols.ROWS_HELD)
+        return compiled, xla_cost.kv_cache_whole_ops(compiled, shape)
+
+    compiled, whole = compiled_generate()
+    assert whole == {}
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    logits, current = [], None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) ", line)
+            current = m.group(1) if m else current
+        elif current not in fused and "lm_decode_step/lm_head" in line:
+            result = line.split(" = ", 1)[1].split("(", 1)[0] if not line.split(" = ", 1)[1].startswith("(") \
+                else line.split(" = ", 1)[1].split(") ", 1)[0]
+            logits += re.findall(rf"([a-z0-9]+)\[{M},{B},{cols.IMAGE_VOCAB}\]", result)
+    if family == "hybrid":
+        assert logits == ["f32"], logits
+
+    fam = cfg.family()
+    monkeypatch.setattr(type(cfg), "family", lambda self: fam._replace(head=cols.parents_source(fam, cfg, params["head"])))
+    _, whole = compiled_generate()
+    assert whole.get("while", 0) >= 1, whole
+
+
 # ---------------------------------------------------------------------------
 # the member axis: a token block made of whole members (PR 28)
 # ---------------------------------------------------------------------------

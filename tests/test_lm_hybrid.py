@@ -411,7 +411,7 @@ def test_train_cli_lm_ar_two_epochs_on_a_qwen3_next_config(tmp_path, monkeypatch
     from hyperscalees_t2i_tpu.train import cli
 
     monkeypatch.setenv("HSES_BASE_QUANT_MIN_SIZE", "1")  # toy kernels still go int8
-    (tmp_path / "config.json").write_text(json.dumps(TOY))
+    (tmp_path / "config.json").write_text(json.dumps({**TOY, "vocab_size": 77, "vocab_rows_held": 77}))  # 77: no other axis of the toy
     prompts = tmp_path / "p.txt"
     prompts.write_text("a red square on a table\na blue circle\nthree green triangles in a row\n")
     cli.main([
@@ -445,6 +445,12 @@ def test_train_cli_lm_ar_two_epochs_on_a_qwen3_next_config(tmp_path, monkeypatch
     assert step["geometry"]["recurrent_state_shape"] == [3, 1, 4, 8, 8]
     whole = step["recurrent_state_whole_ops"]
     assert whole.get("while", 0) >= 1 and all(isinstance(v, int) and v > 0 for v in whole.values())
+    # and the ops as large as the whole head, noted as its [hidden, rows held] kernel: the decode scan is
+    # handed the image-id columns, so nothing dequantizes, converts, copies, cuts or carries the int8 head whole
+    assert step["geometry"]["lm_head_shape"] == [32, 77]
+    head_whole = step["lm_head_whole_ops"]
+    assert not any(op.startswith(("fusion", "convert", "copy", "slice", "while")) for op in head_whole), head_whole
+    assert not any("lm_head" in k for r in rows for k in r)   # geometry is the program's record, never an epoch's row
     probe = np.load(run / "probe_epoch0.npz")
     assert probe["ids"].shape == (2, 16) and probe["topk"].shape == (2, 22, 4, 4)
     q = lm.init_lm(jax.random.PRNGKey(0), lm.config_from_json(str(tmp_path / "config.json")), "int8")

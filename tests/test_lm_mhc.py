@@ -379,7 +379,9 @@ def test_train_cli_lm_ar_xing_two_epochs(tmp_path, monkeypatch):
         assert all(isinstance(r[k], float) for k in ("lm/hc_marginal_err", "lm/hc_row_err", "lm/hc_offdiag_mass"))
         assert not any(k.startswith(("probe/", "gen/")) for k in r)
     steps = [json.loads(l) for l in (run / "programs.jsonl").read_text().splitlines()]
-    assert len([p for p in steps if p["label"].startswith("es_step_")]) == 1
+    (step,) = [p for p in steps if p["label"].startswith("es_step_")]
+    # generate notes the head it cuts its image-id columns from; only a traced run counts the ops of that size
+    assert step["geometry"]["lm_head_shape"] == [32, 64] and "lm_head_whole_ops" not in step
     probe = np.load(run / "probe_epoch0.npz")
     assert probe["topk"].shape == (2, 22, 2, 2) and probe["logits"].shape == (2, 1, 16)
 

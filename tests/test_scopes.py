@@ -285,6 +285,8 @@ def test_compiled_step_carries_every_scope(family, want, tmp_path):
     # every scope is in the table by the program's own metadata, not by inference
     found = {s for s in table.values() if not s.startswith(INFERRED)}
     assert want <= found, sorted(want - found)
+    if family == "lm_ar":  # the head on the image-id columns is still the decode step's own scope, with ops under it
+        assert sum(s == "generate/lm_decode_step/lm_head" for s in table.values()) >= 2
     # the inference reads this jax's as_text(): operands printed as %names
     guessed = {s.lstrip(INFERRED) for s in table.values() if s.startswith(INFERRED)}
     assert guessed and {s.split("/")[0] for s in guessed} <= set(TOP_SCOPES), guessed
@@ -570,6 +572,13 @@ def test_member_reward_row_stays_out_of_the_pod_scalar_gather(tmp_path):
                                 "probe/logits": np.zeros((4, 1, 16), np.float32)}, None, 0)
     assert not any(k.startswith("probe/") for k in lm_row)
     assert set(host_reduce_keys(lm_row)) == set(keys)
+    # what a generator notes of its program (``lm_head_shape``, like ``kv_cache_shape``) is geometry of the next
+    # ``programs.jsonl`` record, consumed there: it reaches no row, so it cannot widen the gather
+    from hyperscalees_t2i_tpu.obs import note_program_geometry, xla_cost
+
+    note_program_geometry(lm_head_shape=(32, 64))
+    assert xla_cost.program_record(site="test", label="noted")["geometry"] == {"lm_head_shape": (32, 64)}
+    assert set(host_reduce_keys(_write_probe_once(dict(lm_row), None, 0))) == set(keys)
     # the pc > 1 payload path of run_training, as it builds and reads it
     payload = {k: scalars[k] for k in keys}
     payload["_preempt_req"] = 0.0
