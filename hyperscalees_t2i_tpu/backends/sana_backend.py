@@ -19,7 +19,7 @@ import numpy as np
 
 from ..lora import LoRASpec, init_lora
 from ..models import dcae, sana
-from ..obs import block_if_tracing, span as obs_span
+from ..obs import block_if_tracing, scope as obs_scope, span as obs_span
 from .base import StepInfo, default_step_info
 
 Pytree = Any
@@ -168,7 +168,7 @@ class SanaBackend:
         embeds = frozen["prompt_embeds"][flat_ids]
         mask = frozen["prompt_mask"][flat_ids]
         hw = (cfg.height_latent, cfg.width_latent)
-        with jax.named_scope("generate"):
+        with obs_scope("generate"):
             if cfg.backend_mode == "pipeline":
                 latents = sana.multistep_generate(
                     frozen["params"], cfg.model, embeds, mask, key,
@@ -185,7 +185,7 @@ class SanaBackend:
                 )
         if not cfg.decode_images:
             return latents
-        with jax.named_scope("decode"):
+        with obs_scope("decode"):
             return dcae.decode(frozen["vae"], cfg.vae, latents / cfg.vae.scaling_factor)
 
     def generate(self, theta: Pytree, flat_ids: jax.Array, key: jax.Array) -> jax.Array:

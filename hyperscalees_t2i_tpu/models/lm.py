@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lora import LoRASpec, effective_factor, lookup
-from ..obs import note_program_geometry
+from ..obs import note_program_geometry, scope as obs_scope
 from ..ops import grouped
 from ..ops.quant import kernel_shape, maybe_quantize_tree, resolve_kernel
 from ..ops.sampling import sample_top_k_top_p
@@ -989,7 +989,7 @@ def generate(
     lo, hi = cfg.image_id_offset, cfg.image_id_offset + cfg.image_vocab
     note_program_geometry(lm_head_shape=kernel_shape(params["head"]))
 
-    with jax.named_scope("generate"):
+    with obs_scope("generate"):
         # sampling sees the image-id range only, so the decode scan is handed
         # the head's columns of that range: every leaf of the node (an int8
         # base and its scale, or a float kernel; a bias) cut on its output
@@ -1049,7 +1049,7 @@ def generate(
         rows.update(fam.state_rows(cfg, state))
     if not decode:
         return ids, rows
-    with jax.named_scope("decode"):
+    with obs_scope("decode"):
         f_hat = msvq.embed_ids(params["vq"], ids).reshape(B, cfg.grid, cfg.grid, cfg.vq.c_vae).astype(jnp.float32)
         # the decoder's activations (256 px x 160 channels an image) are the
         # step's largest: a member chunk decodes ``decode_batch`` images a

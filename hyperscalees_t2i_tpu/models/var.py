@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lora import LoRASpec, lookup, slice_layer
-from ..obs import note_program_geometry
+from ..obs import note_program_geometry, scope as obs_scope
 from ..ops.attention import decode_attention
 from ..ops.quant import resolve_kernel
 from ..ops.sampling import sample_top_k_top_p
@@ -248,7 +248,7 @@ def generate(
     vq_cfg = cfg.vq
 
     # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only
-    with jax.named_scope("generate"):
+    with obs_scope("generate"):
         # CFG super-batch: cond rows then uncond rows (var.py:151).
         lbl2 = jnp.concatenate([labels, jnp.full_like(labels, cfg.uncond_label)])
         cond = params["class_emb"][lbl2]  # [2B, d]
@@ -312,7 +312,7 @@ def generate(
 
     if not decode:
         return f_hat
-    with jax.named_scope("decode"):
+    with obs_scope("decode"):
         return msvq.decode_img(params["vq"], vq_cfg, f_hat)
 
 

@@ -106,6 +106,7 @@ from .multihost import (
     trace_segment_path,
 )
 from .xla_cost import (
+    CompileProvenance,
     ProgramLedger,
     get_ledger,
     load_programs,
@@ -120,6 +121,8 @@ from .trace import (
     block_if_tracing,
     get_tracer,
     load_events,
+    record_startup,
+    scope,
     set_span_observer,
     set_tracer,
     span,
@@ -139,6 +142,7 @@ from .xplane import (
 
 __all__ = [
     "AnomalyWatchdog",
+    "CompileProvenance",
     "DEFAULT_BUCKETS",
     "Heartbeat",
     "Histogram",
@@ -187,10 +191,12 @@ __all__ = [
     "reconcile",
     "record_compile",
     "record_device_memory",
+    "record_startup",
     "render_prometheus",
     "reset_health",
     "roofline",
     "safe_process_index",
+    "scope",
     "set_ledger",
     "set_process_index_override",
     "set_registry",

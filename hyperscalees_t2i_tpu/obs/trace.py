@@ -24,6 +24,14 @@ for an operator. PERF.md §3 lists every span with the metric that reads it.
   session open): under a profiler session every span is an event of the
   ``.xplane.pb`` host plane too, on the device events' own clock.
 - ``to_chrome(events)`` converts the event list to Chrome trace-event JSON.
+- ``startup`` is the one span that starts before the tracer does:
+  ``record_startup`` back-dates it from the operating system's own stamp of
+  the process's start (``process_start_monotonic``), so its ``t0_s`` is
+  negative and a run's timeline begins where its ``setup_s`` does.
+- ``scope(name)`` is how the step enters one of its top-level device scopes
+  (``obs/xla_cost.TOP_SCOPES``): the ``jax.named_scope`` the device time is
+  named by and, with the tracer on, a host span ``trace/<name>`` around the
+  Python that traces the body — where ``lower``'s seconds go.
 
 A process-global tracer (``set_tracer`` / ``get_tracer``) lets call sites in
 other layers (``train/cli.py``, ``parallel/pop_eval.py``, backends) emit
@@ -133,7 +141,10 @@ class Tracer:
     def span(self, name: str, **attrs: Any):
         """Time a phase. Nesting is tracked per thread; the event line carries
         ``t0_s``/``dur_s`` (offsets from the tracer's monotonic origin),
-        ``depth``, ``parent``, pid/tid, and any keyword attrs.
+        ``depth``, ``parent``, pid/tid, and any keyword attrs. The ``with``
+        statement's target is the attrs dict itself: what is known only when
+        the phase ends (a compile's cache verdict) is put there before the
+        span closes.
 
         A registered span observer (``set_span_observer``) sees every
         completed span's ``(name, dur_s)`` even on a disabled tracer — the
@@ -141,7 +152,7 @@ class Tracer:
         is being written. With neither file nor observer the disabled path
         stays allocation- and clock-free."""
         if not self.enabled and _OBSERVER is None:
-            yield
+            yield attrs
             return
         stack = self._stack()
         annotation = _trace_annotation(name) if self.enabled else None
@@ -151,7 +162,7 @@ class Tracer:
         if annotation is not None:
             annotation.__enter__()
         try:
-            yield
+            yield attrs
         finally:
             if annotation is not None:
                 annotation.__exit__(None, None, None)
@@ -185,12 +196,15 @@ class Tracer:
         t0_monotonic: float,
         t1_monotonic: float,
         parent: Optional[str] = None,
+        depth: int = 0,
         **attrs: Any,
     ) -> None:
         """Record a completed span retroactively from two ``perf_counter``
         stamps — for phases whose start and end live in different call
         frames (a serve request's submit→complete lifetime spans queueing,
-        coalescing, and dispatch; no ``with`` block can wrap it). The event
+        coalescing, and dispatch; no ``with`` block can wrap it) or are
+        stamped by someone else (jax's own duration events under ``lower``:
+        ``depth`` places those under their parent). The event
         line is shaped exactly like a ``span`` line, so every trace reader
         (trace_report, run_report, Chrome export) consumes it unchanged."""
         if not self.enabled:
@@ -199,7 +213,7 @@ class Tracer:
             "name": name,
             "t0_s": round(t0_monotonic - self._mono0, 6),
             "dur_s": round(max(t1_monotonic - t0_monotonic, 0.0), 6),
-            "depth": 0,
+            "depth": int(depth),
             "parent": parent,
             "pid": os.getpid(),
             "tid": threading.get_ident(),
@@ -209,6 +223,10 @@ class Tracer:
             ev["attrs"] = attrs
         self._record(ev)
         self.flush()
+
+    def depth(self) -> int:
+        """How many spans are open on the calling thread."""
+        return len(self._stack())
 
 
 def _trace_annotation(name: str):
@@ -251,8 +269,46 @@ def get_tracer() -> Tracer:
 @contextmanager
 def span(name: str, **attrs: Any):
     """Span on the process-global tracer (no-op until ``set_tracer``)."""
-    with get_tracer().span(name, **attrs):
+    with get_tracer().span(name, **attrs) as live_attrs:
+        yield live_attrs
+
+
+@contextmanager
+def scope(name: str):
+    """``jax.named_scope(name)`` and, on an enabled tracer, a host span
+    ``trace/<name>`` around the same body. The device scope is the name the
+    compiled step's ops carry (``obs/xla_cost.scope_table``) and is the same
+    with the tracer on or off; the span times the Python that traces the
+    body. A body traced twice gives two spans."""
+    import jax
+
+    with jax.named_scope(name), get_tracer().span(f"trace/{name}"):
         yield
+
+
+def process_start_monotonic() -> Optional[float]:
+    """When the operating system started this process, on the
+    ``time.perf_counter`` clock (so: before every stamp the process took
+    itself). From ``/proc/self/stat``'s start time, which the kernel counts
+    in clock ticks since boot; None where there is no such file."""
+    try:
+        stat = Path("/proc/self/stat").read_text()
+        # the command name (field 2) may hold spaces: count from its ")"
+        start_ticks = int(stat.rsplit(")", 1)[1].split()[19])
+        age_s = time.clock_gettime(time.CLOCK_BOOTTIME) - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter() - age_s
+
+
+def record_startup(t_entered: float, **attrs: Any) -> None:
+    """The ``startup`` span of the process-global tracer: process start (the
+    operating system's stamp) → ``t_entered``, the ``perf_counter`` stamp
+    the entry point took first thing. Nothing where the system gives no
+    start time."""
+    t_start = process_start_monotonic()
+    if t_start is not None:
+        get_tracer().event("startup", t_start, t_entered, **attrs)
 
 
 def block_if_tracing(tree: Any) -> Any:

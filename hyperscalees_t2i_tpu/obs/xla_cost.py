@@ -15,6 +15,9 @@ bench rungs, preflight abstract lowerings) produces one JSON record in a
   when the backend lacks the API;
 - lowering/compile wall times and StableHLO line count/size/hash (the
   program-size evidence PERF.md used to hand-transcribe);
+- the compile's **provenance** (:class:`CompileProvenance`): whether the
+  executable was compiled or read from the persistent cache, under which
+  key, and jax's own seconds for tracing, StableHLO and the backend;
 - a **donation audit** of ``donate_argnums``: bytes the caller offered vs
   alias bytes XLA actually reused — a silently-dropped donation doubles
   peak HBM at flagship geometry.
@@ -35,6 +38,8 @@ from __future__ import annotations
 
 import hashlib
 import json
+import logging
+import re
 import threading
 import time
 from pathlib import Path
@@ -745,6 +750,156 @@ def roofline(
     }
 
 
+# --------------------------------------------------------------- provenance
+# jax publishes what a compile did through ``jax.monitoring`` and logs the
+# persistent cache's key at DEBUG; the names below are jax 0.9's.
+_CACHE_USED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_TO_STABLEHLO_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILER_LOGGER = "jax._src.compiler"
+_CACHE_KEY_LOGGER = "jax._src.cache_key"
+# the parts ``jax._src.cache_key.get`` hashes into the key, in its order
+CACHE_KEY_PARTS = (
+    "computation", "jax_lib version", "backend version", "XLA flags",
+    "compile_options", "accelerator_config", "compression", "custom_hook",
+)
+_KEY_LOGGED = re.compile(r"(?:cache hit|CACHE MISS) for '.*' with key '([^']+)'")
+_PART_LOGGED = re.compile(r"get_cache_key hash of serialized (.+): ([0-9a-f]+)$")
+
+
+class CompileProvenance:
+    """Listens, for the length of a ``with`` block around one program's
+    ``lower()`` and ``compile()``, to what jax says about them: the
+    ``jax.monitoring`` events above and the cache-key lines of jax's compiler
+    log. Everything it installed is removed when the block ends.
+
+    ``key_parts=True`` also turns on ``jax._src.cache_key``'s DEBUG lines,
+    the hash of each of the key's eight parts; jax computes them by
+    serializing the module a second time, so only a traced run asks.
+
+    Afterwards :meth:`fields` is what ``programs.jsonl`` records and
+    :meth:`lower_spans` jax's own timing of the lowering's two halves, for
+    the tracer. Where this jax gives no such event or line the field is
+    None; nothing here raises."""
+
+    def __init__(self, key_parts: bool = False):
+        self.want_key_parts = bool(key_parts)
+        self.cache_used = False
+        self.cache_hit = False
+        self.cache_key: Optional[str] = None
+        self.cache_read_s: Optional[float] = None
+        self.key_parts: Dict[str, str] = {}
+        self._spans: list = []  # (name, t0, t1) on the perf_counter clock
+        self._undo: list = []
+
+    # ---- listeners (jax calls them with the event's name first)
+    def _on_event(self, event: str, **_: Any) -> None:
+        if event == _CACHE_USED_EVENT:
+            self.cache_used = True
+        elif event == _CACHE_HIT_EVENT:
+            self.cache_hit = True
+
+    def _on_duration(self, event: str, duration: float, **_: Any) -> None:
+        if event == _CACHE_READ_EVENT:
+            self.cache_read_s = float(duration)
+
+    def _on_time_span(self, event: str, start: float, end: float, **_: Any) -> None:
+        name = {_JAXPR_TRACE_EVENT: "jaxpr_trace", _TO_STABLEHLO_EVENT: "to_stablehlo",
+                _BACKEND_COMPILE_EVENT: "backend_compile"}.get(event)
+        if name is not None:
+            wall, perf = self._anchor  # jax stamps these with time.time()
+            self._spans.append((name, perf + (start - wall), perf + (end - wall)))
+
+    def _capture(self, logger_name: str, pattern, take) -> None:
+        """Read ``logger_name``'s DEBUG lines through a filter that passes on
+        only what the logger would have let through anyway."""
+        logger = logging.getLogger(logger_name)
+        was_level, was_enabled_for = logger.level, logger.getEffectiveLevel()
+
+        def seen(record: logging.LogRecord) -> bool:
+            try:
+                found = pattern.search(record.getMessage())
+                if found:
+                    take(*found.groups())
+            except Exception:
+                pass
+            return record.levelno >= was_enabled_for
+
+        logger.addFilter(seen)
+        logger.setLevel(logging.DEBUG)
+        self._undo.append(lambda: (logger.setLevel(was_level), logger.removeFilter(seen)))
+
+    def __enter__(self) -> "CompileProvenance":
+        self._anchor = (time.time(), time.perf_counter())
+        try:
+            from jax import monitoring
+
+            for register, unregister, callback in (
+                (monitoring.register_event_listener,
+                 monitoring.unregister_event_listener, self._on_event),
+                (monitoring.register_event_duration_secs_listener,
+                 monitoring.unregister_event_duration_listener, self._on_duration),
+                (monitoring.register_event_time_span_listener,
+                 monitoring.unregister_event_time_span_listener, self._on_time_span),
+            ):
+                register(callback)
+                self._undo.append(lambda u=unregister, c=callback: u(c))
+            self._capture(_COMPILER_LOGGER, _KEY_LOGGED,
+                          lambda key: setattr(self, "cache_key", key))
+            if self.want_key_parts:
+                self._capture(_CACHE_KEY_LOGGER, _PART_LOGGED, self.key_parts.__setitem__)
+        except Exception:
+            pass  # a jax without these: the fields stay None
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        while self._undo:
+            try:
+                self._undo.pop()()
+            except Exception:
+                pass
+
+    @property
+    def cache(self) -> str:
+        """``hit``: read from the persistent cache; ``miss``: the cache was
+        asked and the backend compiled; ``off``: no cache was in use."""
+        if self.cache_hit:
+            return "hit"
+        return "miss" if self.cache_used or self.cache_key else "off"
+
+    def seconds(self, name: str) -> Optional[float]:
+        """jax's own seconds of ``jaxpr_trace`` (the outermost function's:
+        the jitted functions it calls are traced inside it and report too),
+        ``to_stablehlo`` or ``backend_compile`` (cache read included)."""
+        got = [t1 - t0 for n, t0, t1 in self._spans if n == name]
+        if not got:
+            return None
+        return round(max(got) if name == "jaxpr_trace" else sum(got), 4)
+
+    def lower_spans(self) -> list:
+        """``(name, t0, t1)`` of ``jaxpr_trace`` (the outermost one) and
+        ``to_stablehlo`` on the ``perf_counter`` clock."""
+        traces = [s for s in self._spans if s[0] == "jaxpr_trace"]
+        outer = max(traces, key=lambda s: s[2] - s[1], default=None)
+        return [s for s in self._spans if s[0] == "to_stablehlo" or s is outer]
+
+    def fields(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {
+            "cache": self.cache,
+            "cache_key": self.cache_key,
+            "cache_read_s": round(self.cache_read_s, 4) if self.cache_read_s is not None else None,
+            "backend_compile_s": self.seconds("backend_compile"),
+            "jaxpr_trace_s": self.seconds("jaxpr_trace"),
+            "to_stablehlo_s": self.seconds("to_stablehlo"),
+        }
+        if self.want_key_parts:
+            out["cache_key_parts"] = dict(self.key_parts) or None
+        return out
+
+
 class ProgramLedger:
     """Append-only ``programs.jsonl`` writer — one JSON line per AOT compile.
 
@@ -912,8 +1067,9 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
     recurrent states or the head a generator noted (:func:`kv_cache_whole_ops`
     on ``kv_cache_shape``, ``recurrent_state_shape`` and ``lm_head_shape``:
     the stack of the layers' states, one layer's, with or without the member
-    axis; the head's one kernel). The one call every compile site makes.
-    Never raises."""
+    axis; the head's one kernel). The one call every compile site makes;
+    the trainer's sites pass the compile's provenance
+    (:meth:`CompileProvenance.fields`) as ``extra``. Never raises."""
     try:
         rec = program_record(**kwargs)
     except Exception:
@@ -924,17 +1080,19 @@ def record_compile(**kwargs: Any) -> Dict[str, Any]:
 
         compiled = kwargs.get("compiled")
         if compiled is not None and ledger.enabled and get_tracer().enabled:
-            table = scope_table(compiled)
-            if table:
-                rel = Path("scopes") / f"{rec['label']}.json"
-                path = ledger.path.parent / rel
-                path.parent.mkdir(parents=True, exist_ok=True)
-                path.write_text(json.dumps(table, sort_keys=True))
-                rec["scope_table"] = str(rel)
-            for carried in ("kv_cache", "recurrent_state", "lm_head"):
-                shape = rec["geometry"].get(f"{carried}_shape")
-                if shape:
-                    rec[f"{carried}_whole_ops"] = kv_cache_whole_ops(compiled, shape)
+            # its own span: the part of the record only a traced run pays for
+            with get_tracer().span("scope_table"):
+                table = scope_table(compiled)
+                if table:
+                    rel = Path("scopes") / f"{rec['label']}.json"
+                    path = ledger.path.parent / rel
+                    path.parent.mkdir(parents=True, exist_ok=True)
+                    path.write_text(json.dumps(table, sort_keys=True))
+                    rec["scope_table"] = str(rel)
+                for carried in ("kv_cache", "recurrent_state", "lm_head"):
+                    shape = rec["geometry"].get(f"{carried}_shape")
+                    if shape:
+                        rec[f"{carried}_whole_ops"] = kv_cache_whole_ops(compiled, shape)
     except Exception:
         pass
     ledger.write(rec)

@@ -44,7 +44,7 @@ from ..es import (
     member_maps,
     stacked_adapter_theta,
 )
-from ..obs import get_registry, note_program_geometry, span as obs_span
+from ..obs import get_registry, note_program_geometry, scope as obs_scope, span as obs_span
 from .collectives import all_gather_tree
 from .mesh import DATA_AXIS, POP_AXIS, shard_map
 
@@ -328,7 +328,7 @@ def make_population_evaluator(
 
     def eval_one(frozen, theta, noise, flat_ids, item_index, gen_key, k, maps):
         # device-time scope (obs/xla_cost.INNER_SCOPES): a name only
-        with jax.named_scope("es_noise"), jax.named_scope("perturb"):
+        with obs_scope("es_noise"), jax.named_scope("perturb"):
             theta_k = factored_member_theta(theta, noise, k, pop_size, es_cfg, maps)
         return _eval_member(
             generate_p, reward_apply, reward_tile,
@@ -338,7 +338,7 @@ def make_population_evaluator(
     def make_maps():
         # device-side (signs, bases) built ONCE per trace and threaded into
         # every member lane
-        with jax.named_scope("es_noise"), jax.named_scope("perturb"):
+        with obs_scope("es_noise"), jax.named_scope("perturb"):
             return member_maps(pop_size, es_cfg.antithetic)
 
     # iteration domain: the whole population, or this host's member slice

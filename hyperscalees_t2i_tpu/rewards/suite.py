@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import clip as clip_mod
+from ..obs import scope as obs_scope
 
 Params = Dict[str, Any]
 
@@ -88,7 +89,7 @@ def compute_rewards_batch(
     """
     M = clip_text_table.shape[0] - 2
     # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only
-    with jax.named_scope("reward"):
+    with obs_scope("reward"):
         with jax.named_scope("preprocess"):
             pixels = clip_mod.preprocess_images(images, clip_cfg)
         with jax.named_scope("clip_b"):

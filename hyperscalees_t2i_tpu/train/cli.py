@@ -755,11 +755,18 @@ def train_config(args):
 
 
 def main(argv=None) -> None:
-    args = build_parser().parse_args(argv)
+    import time
 
-    from ..obs import Tracer, get_tracer, set_tracer
+    t_entered = time.perf_counter()
+    from ..obs import Tracer, get_tracer, record_startup, set_tracer
+    from ..obs.multihost import jax_backend_initialized
     from ..utils.compile_cache import place_compile_cache
 
+    # a caller that already brought the backend up (the benchmark's harness,
+    # a test) paid for it before this point, under ``startup``; a bare
+    # ``python -m`` launch pays under ``devices``
+    backend_up = jax_backend_initialized()
+    args = build_parser().parse_args(argv)
     place_compile_cache()
 
     # Multihost launch path: the CLI flags materialize as the coordinator
@@ -775,9 +782,13 @@ def main(argv=None) -> None:
         os.environ["JAX_PROCESS_ID"] = str(args.process_id)
     # --trace true: the tracer exists from here, so the build below is under
     # spans. It has no file yet — run_training names the run directory and
-    # adopts it.
+    # adopts it. What came before it is written back-dated: ``startup`` from
+    # the operating system's stamp of the process's start, ``parse_args``
+    # from this function's first line.
     if args.trace:
         set_tracer(Tracer(enabled=True))
+        record_startup(t_entered, backend_initialized=backend_up)
+        get_tracer().event("parse_args", t_entered, time.perf_counter())
     try:
         _run(args)
     finally:
@@ -786,34 +797,10 @@ def main(argv=None) -> None:
             set_tracer(None)
 
 
-def _run(args) -> None:
-    """``main`` after the tracer is installed: build, mesh, train."""
-    from ..obs import block_if_tracing, span as obs_span
-    from ..parallel import POP_AXIS, initialize_multihost, make_mesh
-    from .trainer import run_training
-
-    initialize_multihost()
-    with obs_span("build_backend"):
-        backend = build_backend(args)
-    with obs_span("backend_setup"):
-        backend.setup()
-        block_if_tracing(backend.frozen)
-    if args.base_quant == "int8":
-        # quantize the frozen generator trees in place AFTER setup (params
-        # exist) and BEFORE init_theta (the adapter tree then targets
-        # kernel_q8/q8 paths — same adapter structure and init values either
-        # way, lora.init_lora). The trained delta never touches the base.
-        from ..ops.quant import quantize_frozen
-
-        with obs_span("quantize"):
-            backend.params = quantize_frozen(backend.params, "int8")
-            if getattr(backend, "vae_params", None) is not None:
-                backend.vae_params = quantize_frozen(backend.vae_params, "int8")
-            block_if_tracing(backend.frozen)
-        print("[cli] base_quant=int8: frozen generator kernels stored int8 "
-              "(per-output-channel, ops/quant.py)", flush=True)
-    with obs_span("build_reward"):
-        reward_fn = build_reward_fn(args, backend)
+def build_mesh(args):
+    """The device mesh the flags ask for: ``{pop, data}`` over this process's
+    devices (host-sharded pods) or over all of them; None on one device."""
+    from ..parallel import POP_AXIS, make_mesh
 
     # Host-sharded pods (the multi-process default) build a LOCAL mesh: each
     # process compiles programs over its own devices only — the population
@@ -844,27 +831,65 @@ def _run(args) -> None:
         import math
 
         shards = math.gcd(mesh_pop, n_dev)
-    mesh = None
-    if n_dev > 1 and shards >= 1:
-        from ..parallel import DATA_AXIS
+    if n_dev <= 1 or shards < 1:
+        return None
+    from ..parallel import DATA_AXIS
 
-        if shards > n_dev:
-            sys.exit(f"ERROR: --pop_shards {shards} > {n_dev} available devices")
-        # remaining devices shard each member's image batch (data axis) so
-        # small populations still fill the slice (pop_eval pads both axes)
-        n_data = n_dev // shards
-        if shards * n_data < n_dev:
-            print(
-                f"[cli] WARNING: pop_shards={shards} does not divide {n_dev} "
-                f"devices; {n_dev - shards * n_data} devices idle",
-                flush=True,
-            )
-        mesh = make_mesh({POP_AXIS: shards, DATA_AXIS: n_data}, devices=devs)
-        scope = "local" if host_shard else "global"
-        print(f"[cli] mesh: {dict(mesh.shape)} over {n_dev} {scope} devices",
-              flush=True)
+    if shards > n_dev:
+        sys.exit(f"ERROR: --pop_shards {shards} > {n_dev} available devices")
+    # remaining devices shard each member's image batch (data axis) so
+    # small populations still fill the slice (pop_eval pads both axes)
+    n_data = n_dev // shards
+    if shards * n_data < n_dev:
+        print(
+            f"[cli] WARNING: pop_shards={shards} does not divide {n_dev} "
+            f"devices; {n_dev - shards * n_data} devices idle",
+            flush=True,
+        )
+    mesh = make_mesh({POP_AXIS: shards, DATA_AXIS: n_data}, devices=devs)
+    scope = "local" if host_shard else "global"
+    print(f"[cli] mesh: {dict(mesh.shape)} over {n_dev} {scope} devices",
+          flush=True)
+    return mesh
 
-    tc = train_config(args)
+
+def _run(args) -> None:
+    """``main`` after the tracer is installed: build, mesh, train. Every
+    statement lies under a span (PERF.md §3: the set-up waterfall)."""
+    from ..obs import block_if_tracing, span as obs_span
+
+    with obs_span("imports"):
+        from ..parallel import initialize_multihost
+        from .trainer import run_training
+
+    # multi-host init and the backend's bring-up (``jax.process_count()``
+    # there is the first call that needs one)
+    with obs_span("devices"):
+        initialize_multihost()
+    with obs_span("build_backend"):
+        backend = build_backend(args)
+    with obs_span("backend_setup"):
+        backend.setup()
+        block_if_tracing(backend.frozen)
+    if args.base_quant == "int8":
+        # quantize the frozen generator trees in place AFTER setup (params
+        # exist) and BEFORE init_theta (the adapter tree then targets
+        # kernel_q8/q8 paths — same adapter structure and init values either
+        # way, lora.init_lora). The trained delta never touches the base.
+        from ..ops.quant import quantize_frozen
+
+        with obs_span("quantize"):
+            backend.params = quantize_frozen(backend.params, "int8")
+            if getattr(backend, "vae_params", None) is not None:
+                backend.vae_params = quantize_frozen(backend.vae_params, "int8")
+            block_if_tracing(backend.frozen)
+        print("[cli] base_quant=int8: frozen generator kernels stored int8 "
+              "(per-output-channel, ops/quant.py)", flush=True)
+    with obs_span("build_reward"):
+        reward_fn = build_reward_fn(args, backend)
+    with obs_span("mesh"):
+        mesh = build_mesh(args)
+        tc = train_config(args)
 
     # best/median/worst member strips + histograms + profiler traces are
     # handled inside run_training (reference unifed_es.py:243-264,807-821)

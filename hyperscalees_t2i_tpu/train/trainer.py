@@ -23,7 +23,7 @@ import os
 import sys
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,7 @@ import numpy as np
 
 from ..backends.base import ESBackend, RewardFn, StepInfo
 from ..obs import (
+    CompileProvenance,
     MetricsRegistry,
     ProgramLedger,
     Tracer,
@@ -40,6 +41,7 @@ from ..obs import (
     record_compile,
     record_device_memory,
     roofline,
+    scope as obs_scope,
     set_ledger,
     set_registry,
     set_tracer,
@@ -107,7 +109,7 @@ def _combine_and_update(
 
     # device-time scopes (obs/xla_cost.TOP_SCOPES / INNER_SCOPES): names only,
     # the traced program is the same
-    with jax.named_scope("es_update"):
+    with obs_scope("es_update"):
         with jax.named_scope("fitness"):
             # S_comb[k, j]: mean over repeats (grouped layout [r][m],
             # unifed_es.py:208-215).
@@ -286,7 +288,7 @@ def make_host_sharded_programs(
 
     def eval_slice(frozen: Pytree, theta: Pytree, flat_ids: jax.Array, key: jax.Array):
         k_noise, k_gen = jax.random.split(key)
-        with jax.named_scope("es_noise"):
+        with obs_scope("es_noise"):
             noise = sample_noise(k_noise, theta, pop, es_cfg)
         rewards = eval_slice_pop(frozen, theta, noise, flat_ids, k_gen)
         # a generator's own rows stay on their host: only reward rows cross
@@ -301,7 +303,7 @@ def make_host_sharded_programs(
     def update(theta: Pytree, prev_delta: Pytree,
                rewards: Dict[str, jax.Array], key: jax.Array):
         k_noise, _ = jax.random.split(key)
-        with jax.named_scope("es_noise"):
+        with obs_scope("es_noise"):
             noise = sample_noise(k_noise, theta, pop, es_cfg)
         return _combine_and_update(
             theta, prev_delta, noise, rewards, tc=tc, es_cfg=es_cfg,
@@ -365,7 +367,7 @@ def make_es_step(
         key: jax.Array,
     ):
         k_noise, k_gen = jax.random.split(key)
-        with jax.named_scope("es_noise"):
+        with obs_scope("es_noise"):
             noise = sample_noise(k_noise, theta, pop, es_cfg)
 
         rewards = eval_pop(frozen, theta, noise, flat_ids, k_gen)  # dict of [pop, B]
@@ -397,6 +399,56 @@ def make_es_step(
         return theta_new, metrics, opt_scores
 
     return jax.jit(step, donate_argnums=(1,) if donate else ())
+
+
+class CompiledProgram(NamedTuple):
+    """What :func:`lower_and_compile` hands back: the two stages and the
+    program's ``programs.jsonl`` record."""
+
+    lowered: Any
+    compiled: Any
+    record: Dict[str, Any]
+
+
+def lower_and_compile(jitted: Callable, args: Tuple[Any, ...], *, label: str,
+                      geometry: Dict[str, Any], chain: int = 1) -> CompiledProgram:
+    """Lower ``jitted`` on ``args``, compile it, write its ledger record —
+    what every compile site of ``run_training`` does inside its ``compile``
+    span, under one span each:
+
+    - ``lower`` (``lowering_s``): ``jitted.lower``; jax's own timing of its two
+      halves is written beneath it as ``jaxpr_trace`` and ``to_stablehlo``,
+      beside the ``trace/<scope>`` spans the step's body opens as it is traced;
+    - ``backend_compile`` (``compile_s``): ``lowered.compile()``, a compile or
+      a read of the persistent cache — the span's attrs say which, and under
+      which key;
+    - ``record``: :func:`obs.record_compile` — cost and memory analysis,
+      StableHLO stats, the donation audit and, on a traced run, the scope table.
+
+    The provenance (``cache``, ``cache_key``, ``cache_read_s``,
+    ``backend_compile_s``, ``jaxpr_trace_s``, ``to_stablehlo_s``; traced also
+    ``cache_key_parts``) goes into the record whether or not a tracer is on."""
+    tracer = get_tracer()
+    with CompileProvenance(key_parts=tracer.enabled) as prov:
+        with tracer.span("lower"):
+            t0 = time.perf_counter()
+            lowered = jitted.lower(*args)
+            lowering_s = time.perf_counter() - t0
+            for name, t_a, t_b in prov.lower_spans():
+                tracer.event(name, t_a, t_b, parent="lower", depth=tracer.depth())
+        with tracer.span("backend_compile") as attrs:
+            t0 = time.perf_counter()
+            compiled = lowered.compile()
+            compile_s = time.perf_counter() - t0
+            attrs.update(cache=prov.cache, cache_read_s=prov.cache_read_s,
+                         cache_key=prov.cache_key)
+    with tracer.span("record"):
+        record = record_compile(
+            site="train", label=label, lowered=lowered, compiled=compiled,
+            chain=chain, lowering_s=lowering_s, compile_s=compile_s,
+            geometry=geometry, extra=prov.fields(),
+        )
+    return CompiledProgram(lowered, compiled, record)
 
 
 def fleet_scalar_args(tc_list) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -583,6 +635,7 @@ def run_training(
     """Full training driver (reference ``unifed_es.main``, unifed_es.py:497-839):
     setup → θ init (or RESUME — a capability the reference lacks, SURVEY.md
     §5.4) → epoch loop → metrics/checkpoints."""
+    t_entered = time.perf_counter()  # → the ``trainer_init`` span, back-dated
     from ..obs.es_health import DegeneracyWatchdog
     from ..obs.heartbeat import emit_heartbeat
     from ..obs.multihost import trace_segment_path
@@ -941,6 +994,10 @@ def run_training(
     # window existed to capture).
     profiling = False
     try:
+        # everything above (run directory, logger, registries, exporter,
+        # watchdogs, checkpoint stores), which ran before this run's tracer
+        # had its file
+        tracer.event("trainer_init", t_entered, time.perf_counter())
         with tracer.span("setup"):
             theta = backend.init_theta(jax.random.fold_in(jax.random.PRNGKey(tc.seed), 17))
             start_epoch = 0
@@ -1057,6 +1114,7 @@ def run_training(
                 prev_delta = replicate_to_mesh(prev_delta, mesh)
                 frozen = replicate_to_mesh(frozen, mesh)
 
+        t_setup_done = time.perf_counter()  # → the ``loop_init`` span
         # elastic runtime facts (resilience/elastic.py): the incarnation id
         # every process agrees on (start epoch + launch size — what makes a
         # stale liveness key from a previous incarnation detectable) and the
@@ -1119,6 +1177,21 @@ def run_training(
         jit_cache: Dict[Tuple[int, int], Callable] = {}
         chain_cache: Dict[Tuple[int, int, int], Callable] = {}
         out_struct: Dict[Tuple[int, int], Tuple[Any, Any]] = {}
+
+        def _base_geometry(m: int, r: int) -> Dict[str, Any]:
+            """The geometry key every compile site records."""
+            return {
+                "m": m, "r": r, "pop": tc.pop_size,
+                "member_batch": tc.member_batch,
+                "remat": tc_live.remat,
+                "noise_dtype": tc_live.noise_dtype,
+                "tower_dtype": tc_live.tower_dtype,
+                "base_quant": tc_live.base_quant,
+                # topology (every compile site records it, so ledger
+                # collective bytes are always attributable to a mesh)
+                "mesh_shape": dict(mesh.shape) if mesh is not None else None,
+                "n_devices": n_mesh_devices,
+            }
 
         def _epochs_until_due(e: int) -> int:
             """Distance to the next epoch with per-epoch host work (histograms,
@@ -1396,6 +1469,9 @@ def run_training(
             )
             return "continue"
 
+        # between ``setup`` and the first ``epoch``: the loop's caches and
+        # closures, and the profiler's start where --profile_epochs asks
+        tracer.event("loop_init", t_setup_done, time.perf_counter())
         while epoch < tc.num_epochs:
             try:
                 with tracer.span("epoch", epoch=epoch):
@@ -1423,62 +1499,40 @@ def run_training(
                             from ..parallel.collectives import set_gather_grace
 
                             set_gather_grace(True)
-                        base_geometry = {
-                            "m": m, "r": r, "pop": tc.pop_size,
-                            "member_batch": tc.member_batch,
-                            "remat": tc_live.remat,
-                            "noise_dtype": tc_live.noise_dtype,
-                            "tower_dtype": tc_live.tower_dtype,
-                            "base_quant": tc_live.base_quant,
-                            # topology (every compile site records it, so ledger
-                            # collective bytes are always attributable to a mesh)
-                            "mesh_shape": dict(mesh.shape) if mesh is not None else None,
-                            "n_devices": n_mesh_devices,
-                        }
+                        base_geometry = _base_geometry(m, r)
                         if host_shard:
                             # Pod step = two local programs + one host gather
                             # (make_host_sharded_programs). Both AOT-compiled and
                             # ledger-recorded; step_cost carries the eval program
                             # (it holds ~all the FLOPs the MFU line reports).
                             with tracer.span("compile", m=m, r=r), _hb("compile"):
-                                eval_j, upd_j = make_host_sharded_programs(
-                                    backend, reward_fn, tc_live, m, r, mesh,
-                                    (host_lo, host_lpop),
+                                with tracer.span("make_step"):
+                                    eval_j, upd_j = make_host_sharded_programs(
+                                        backend, reward_fn, tc_live, m, r, mesh,
+                                        (host_lo, host_lpop),
+                                    )
+                                prog_e = lower_and_compile(
+                                    eval_j, (frozen, state.theta, flat_ids, key),
+                                    label=f"es_eval_slice_m{m}r{r}",
+                                    geometry={**base_geometry,
+                                              "host_slice": [host_lo, host_lpop]},
                                 )
-                                with tracer.span("lower"):
-                                    t_l0 = time.perf_counter()
-                                    lowered = eval_j.lower(frozen, state.theta, flat_ids, key)
-                                    # reward-leaf structs come from the lowering
-                                    # already in hand — jax.eval_shape here would
-                                    # re-trace the whole generate→reward program
-                                    # (the largest in the system) a second time
-                                    rew_struct = jax.tree_util.tree_map(
-                                        lambda s: jax.ShapeDtypeStruct(
-                                            (n_live * s.shape[0], *s.shape[1:]), s.dtype
-                                        ),
-                                        lowered.out_info,
-                                    )
-                                    lowered_u = upd_j.lower(
-                                        state.theta, prev_delta, rew_struct, key
-                                    )
-                                    lowering_s = time.perf_counter() - t_l0
-                                t_c0 = time.perf_counter()
-                                compiled_e = lowered.compile()
-                                compiled_u = lowered_u.compile()
-                                compile_s = time.perf_counter() - t_c0
-                            step_cost[(m, r)] = record_compile(
-                                site="train", label=f"es_eval_slice_m{m}r{r}",
-                                lowered=lowered, compiled=compiled_e,
-                                lowering_s=lowering_s, compile_s=compile_s,
-                                geometry={**base_geometry,
-                                          "host_slice": [host_lo, host_lpop]},
-                            )
-                            record_compile(
-                                site="train", label=f"es_update_m{m}r{r}",
-                                lowered=lowered_u, compiled=compiled_u,
-                                lowering_s=0.0, compile_s=0.0,
-                                geometry=base_geometry,
-                            )
+                                # reward-leaf structs come from the lowering
+                                # already in hand — jax.eval_shape here would
+                                # re-trace the whole generate→reward program
+                                # (the largest in the system) a second time
+                                rew_struct = jax.tree_util.tree_map(
+                                    lambda s: jax.ShapeDtypeStruct(
+                                        (n_live * s.shape[0], *s.shape[1:]), s.dtype
+                                    ),
+                                    prog_e.lowered.out_info,
+                                )
+                                prog_u = lower_and_compile(
+                                    upd_j, (state.theta, prev_delta, rew_struct, key),
+                                    label=f"es_update_m{m}r{r}", geometry=base_geometry,
+                                )
+                            step_cost[(m, r)] = prog_e.record
+                            compiled_e, compiled_u = prog_e.compiled, prog_u.compiled
 
                             def _host_step(fz, th, dl, ids_, key_,
                                            _ev=compiled_e, _up=compiled_u):
@@ -1506,30 +1560,21 @@ def run_training(
                             # execution and FLOPs accounting — the jit dispatch path
                             # would compile the same program a second time.
                             with tracer.span("compile", m=m, r=r), _hb("compile"):
-                                jitted = make_es_step(
-                                    backend, reward_fn, tc_live, m, r, mesh,
-                                    stateful_delta=True,
-                                )
-                                with tracer.span("lower"):
-                                    t_l0 = time.perf_counter()
-                                    lowered = jitted.lower(
-                                        frozen, state.theta, prev_delta, flat_ids, key
+                                with tracer.span("make_step"):
+                                    jitted = make_es_step(
+                                        backend, reward_fn, tc_live, m, r, mesh,
+                                        stateful_delta=True,
                                     )
-                                    lowering_s = time.perf_counter() - t_l0
-                                t_c0 = time.perf_counter()
-                                compiled = lowered.compile()
-                                compile_s = time.perf_counter() - t_c0
+                                # one ledger record per AOT compile (obs/xla_cost.py)
+                                # → run_dir/programs.jsonl + obs/ gauges
+                                prog = lower_and_compile(
+                                    jitted,
+                                    (frozen, state.theta, prev_delta, flat_ids, key),
+                                    label=f"es_step_m{m}r{r}", geometry=base_geometry,
+                                )
                             jit_cache[(m, r)] = jitted
-                            step_cache[(m, r)] = compiled
-                            # one ledger record per AOT compile (obs/xla_cost.py):
-                            # normalized cost/memory analysis, StableHLO stats,
-                            # donation audit → run_dir/programs.jsonl + obs/ gauges
-                            step_cost[(m, r)] = record_compile(
-                                site="train", label=f"es_step_m{m}r{r}",
-                                lowered=lowered, compiled=compiled,
-                                lowering_s=lowering_s, compile_s=compile_s,
-                                geometry=base_geometry,
-                            )
+                            step_cache[(m, r)] = prog.compiled
+                            step_cost[(m, r)] = prog.record
                             registry.inc("compiles")
                         registry.gauge("compile_cache_entries", compile_cache_entries())
                     step = step_cache[(m, r)]
@@ -1576,43 +1621,29 @@ def run_training(
                                 set_gather_grace(True)
                             inner = jit_cache[(m, r)]
                             m0, s0 = out_struct[(m, r)]
-                            mz = jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype), m0)
-                            sz = jnp.zeros(s0.shape, s0.dtype)
-
-                            def multi(fz, th, dl, ik, kk):
-                                def body(i, carry):
-                                    th_, dl_, _, _ = carry
-                                    return inner(fz, th_, dl_, ik[i], kk[i])
-
-                                # Δθ chains through the carry, so es/update_cosine
-                                # stays per-generation-consecutive inside a chain.
-                                return jax.lax.fori_loop(0, K, body, (th, dl, mz, sz))
 
                             logger.info(f"compiling {K}-epoch chained step for (m={m}, r={r})")
                             with tracer.span("compile", m=m, r=r, chain=K), _hb("compile"):
-                                with tracer.span("lower"):
-                                    t_l0 = time.perf_counter()
-                                    lowered_k = jax.jit(multi, donate_argnums=(1, 2)).lower(
-                                        frozen, state.theta, prev_delta, ids_k, keys_k
-                                    )
-                                    lowering_s = time.perf_counter() - t_l0
-                                t_c0 = time.perf_counter()
-                                chain_cache[(m, r, K)] = compiled_k = lowered_k.compile()
-                                compile_s = time.perf_counter() - t_c0
-                            record_compile(
-                                site="train", label=f"es_chain_m{m}r{r}x{K}",
-                                lowered=lowered_k, compiled=compiled_k, chain=K,
-                                lowering_s=lowering_s, compile_s=compile_s,
-                                geometry={"m": m, "r": r, "pop": tc.pop_size,
-                                          "member_batch": tc.member_batch,
-                                          "remat": tc_live.remat,
-                                          "noise_dtype": tc_live.noise_dtype,
-                                          "tower_dtype": tc_live.tower_dtype,
-                                          "base_quant": tc_live.base_quant,
-                                          "mesh_shape": (dict(mesh.shape)
-                                                         if mesh is not None else None),
-                                          "n_devices": n_mesh_devices},
-                            )
+                                with tracer.span("make_step"):
+                                    mz = jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype), m0)
+                                    sz = jnp.zeros(s0.shape, s0.dtype)
+
+                                    def multi(fz, th, dl, ik, kk):
+                                        def body(i, carry):
+                                            th_, dl_, _, _ = carry
+                                            return inner(fz, th_, dl_, ik[i], kk[i])
+
+                                        # Δθ chains through the carry, so es/update_cosine
+                                        # stays per-generation-consecutive inside a chain.
+                                        return jax.lax.fori_loop(0, K, body, (th, dl, mz, sz))
+
+                                    jitted_k = jax.jit(multi, donate_argnums=(1, 2))
+                                chain_cache[(m, r, K)] = lower_and_compile(
+                                    jitted_k,
+                                    (frozen, state.theta, prev_delta, ids_k, keys_k),
+                                    label=f"es_chain_m{m}r{r}x{K}", chain=K,
+                                    geometry=_base_geometry(m, r),
+                                ).compiled
                             registry.inc("compiles")
                             registry.gauge("compile_cache_entries", compile_cache_entries())
                         # no device gauges inside the timed window — a gauge is a

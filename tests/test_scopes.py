@@ -346,6 +346,11 @@ def test_traced_cli_run_has_one_adopted_tracer(traced_run):
     ("text_tables", "build_reward"),
     ("compile", "epoch"), ("lower", "compile"),
     ("dispatch", "epoch"), ("enqueue", "dispatch"), ("fetch", "dispatch"),
+    # ISSUE 35: the set-up waterfall
+    ("startup", None), ("parse_args", None), ("imports", None), ("devices", None), ("mesh", None),
+    ("trainer_init", None), ("setup", None), ("loop_init", None),
+    ("make_step", "compile"), ("backend_compile", "compile"), ("record", "compile"),
+    ("jaxpr_trace", "lower"), ("to_stablehlo", "lower"), ("scope_table", "record"),
 ])
 def test_traced_cli_run_span_parents(traced_run, name, parent):
     _, lines = traced_run
@@ -354,14 +359,15 @@ def test_traced_cli_run_span_parents(traced_run, name, parent):
     assert {l["parent"] for l in found} == {parent}
     if name in ("enqueue", "fetch", "dispatch"):
         assert len(found) == 2  # one an epoch
-    if name in ("lower", "compile") or parent is None and name != "epoch":
+    if parent in ("compile", "lower", "record") or name == "compile" or parent is None and name != "epoch":
         assert len(found) == 1
 
 
 def test_traced_cli_run_children_lie_inside_their_parents(traced_run):
     _, lines = traced_run
     spans = [l for l in lines if "name" in l]
-    for parent, children in (("compile", ("lower",)), ("dispatch", ("enqueue", "fetch"))):
+    for parent, children in (("compile", ("make_step", "lower", "backend_compile", "record")),
+                             ("lower", ("jaxpr_trace", "to_stablehlo")), ("dispatch", ("enqueue", "fetch"))):
         for p in (s for s in spans if s["name"] == parent):
             inside = [s for s in spans if s["name"] in children
                       and p["t0_s"] - 1e-6 <= s["t0_s"] and s["t0_s"] + s["dur_s"] <= p["t0_s"] + p["dur_s"] + 1e-6]
@@ -370,7 +376,7 @@ def test_traced_cli_run_children_lie_inside_their_parents(traced_run):
 
 
 @pytest.mark.parametrize("name, why", [
-    ("xla_compile", "compile minus lower, and compile_s of programs.jsonl, already give it"),
+    ("xla_compile", "backend_compile is that span, and compile_s of programs.jsonl its seconds"),
     ("init_decoder", "params and decoder are seeded by one program, under init_params"),
 ])
 def test_traced_cli_run_has_no_span_that_nothing_reads(traced_run, name, why):
@@ -391,6 +397,63 @@ def test_traced_cli_run_writes_the_scope_table_programs_jsonl_names(traced_run):
         if json.loads(l).get("name") == "lower")) < 0.05
 
 
+def test_traced_cli_run_is_under_spans_from_process_start_to_the_end_of_the_second_epoch(traced_run):
+    """The waterfall has no gap: from where ``startup`` begins (the operating system's stamp of the
+    process's start) to the end of epoch 1, under 50 ms in all lie under no depth-0 or depth-1 span."""
+    _, lines = traced_run
+    spans = [l for l in lines if "name" in l]
+    lo = next(s["t0_s"] for s in spans if s["name"] == "startup")
+    assert lo < 0 < lo + next(s["dur_s"] for s in spans if s["name"] == "startup") + 0.1  # back-dated
+    hi = max(s["t0_s"] + s["dur_s"] for s in spans if s["name"] == "epoch")
+    assert sum(1 for s in spans if s["name"] == "epoch") == 2
+    covered, at = 0.0, lo
+    for s in sorted((s for s in spans if s["depth"] <= 1), key=lambda s: s["t0_s"]):
+        t0, t1 = max(s["t0_s"], at), min(s["t0_s"] + s["dur_s"], hi)
+        if t1 > t0:
+            covered, at = covered + t1 - t0, t1
+    assert (hi - lo) - covered < 0.05, f"{(hi - lo) - covered:.3f} s of {hi - lo:.1f} s under no span"
+    # trace_report's coverage, the operator's form of the same figure, starts at startup too
+    from hyperscalees_t2i_tpu.tools import trace_report
+
+    assert trace_report.coverage(load_events(traced_run[0])) > 0.99
+
+
+def test_traced_cli_run_times_the_tracing_of_every_top_scope(traced_run):
+    _, lines = traced_run
+    spans = [l for l in lines if "name" in l]
+    lower = next(s for s in spans if s["name"] == "lower")
+    for top in TOP_SCOPES:  # each a host span beside trace/pop_eval, inside lower
+        found = [s for s in spans if s["name"] == f"trace/{top}"]
+        assert found, top
+        assert all(lower["t0_s"] <= s["t0_s"] and s["t0_s"] + s["dur_s"] <= lower["t0_s"] + lower["dur_s"] + 1e-6
+                   for s in found)
+    halves = sum(s["dur_s"] for s in spans if s["name"] in ("jaxpr_trace", "to_stablehlo"))
+    assert 0.5 * lower["dur_s"] < halves <= lower["dur_s"] + 1e-3
+
+
+PROVENANCE = ("cache", "cache_key", "cache_read_s", "backend_compile_s", "jaxpr_trace_s", "to_stablehlo_s")
+
+
+def test_traced_cli_run_records_the_compile_s_provenance_and_the_key_s_parts(traced_run):
+    from hyperscalees_t2i_tpu.obs.xla_cost import CACHE_KEY_PARTS
+
+    run, lines = traced_run
+    step = next(p for p in map(json.loads, (run / "programs.jsonl").read_text().splitlines())
+                if p["label"].startswith("es_step_"))
+    assert set(PROVENANCE) <= set(step) and step["cache"] in ("hit", "miss")  # conftest sets a cache directory
+    assert step["cache_key"].startswith("jit_") and len(step["cache_key"].rsplit("-", 1)[1]) == 64
+    assert (step["cache_read_s"] is not None) == (step["cache"] == "hit")
+    assert tuple(step["cache_key_parts"]) == CACHE_KEY_PARTS
+    assert all(re.fullmatch(r"[0-9a-f]{64}", h) for h in step["cache_key_parts"].values())
+    span_attrs = next(l["attrs"] for l in lines if l.get("name") == "backend_compile")
+    assert (span_attrs["cache"], span_attrs["cache_key"]) == (step["cache"], step["cache_key"])
+    span_s = {l["name"]: l["dur_s"] for l in lines if l.get("name") in ("jaxpr_trace", "to_stablehlo", "backend_compile")}
+    assert span_s["jaxpr_trace"] == pytest.approx(step["jaxpr_trace_s"], abs=1e-3)
+    assert span_s["to_stablehlo"] == pytest.approx(step["to_stablehlo_s"], abs=1e-3)
+    assert span_s["backend_compile"] == pytest.approx(step["compile_s"], abs=0.05)
+    assert step["backend_compile_s"] <= step["compile_s"] + 1e-3
+
+
 def test_untraced_cli_run_leaves_no_trace_and_no_table(tmp_path, monkeypatch):
     entered = []
     real = jax.profiler.TraceAnnotation
@@ -408,6 +471,9 @@ def test_untraced_cli_run_leaves_no_trace_and_no_table(tmp_path, monkeypatch):
     assert (run / "metrics.jsonl").exists() and (run / "programs.jsonl").exists()
     assert not (run / "trace.jsonl").exists() and not (run / "scopes").exists()
     assert "scope_table" not in (run / "programs.jsonl").read_text()
+    step = next(p for p in map(json.loads, (run / "programs.jsonl").read_text().splitlines())
+                if p["label"].startswith("es_step_"))
+    assert set(PROVENANCE) <= set(step) and "cache_key_parts" not in step  # always written; the parts traced only
     assert entered == [] and syncs == []  # no annotation entered, no sync added
     assert not get_tracer().enabled
 
