@@ -137,7 +137,7 @@ def _blocks_step(
     cfg: VARConfig,
     x: jax.Array,  # [B2, n, d] current scale's token activations
     cond6_all: jax.Array,  # [depth, B2, 6, d] precomputed AdaLN modulation
-    caches: Tuple[Any, Any],  # K, V: the row blocks written so far, [depth, B2, n_s, H, dh] each
+    caches: Tuple[Any, Any],  # K, V: the row blocks written so far, [depth, B2, n_s, H·dh] each
     pos: int,  # static prefix length
     lora: Optional[Params],
     lora_scale: float,
@@ -151,7 +151,12 @@ def _blocks_step(
     block, of which the first ``pos`` rows count). The scan reads them,
     returns this scale's ``n`` rows a layer, and that block is appended — no
     row is copied again after the scale that wrote it, except into the
-    operand a layer's attention reads. A cache preallocated at the whole
+    operand a layer's attention reads. A row is kept as the ``qkv`` product
+    wrote it, its ``H`` heads side by side (``[.., H·dh]``, 1024 lanes): that is
+    the block ``decode_attention`` reads, and the TPU's tiled layout of a
+    ``[.., H, dh]`` array is another (64 lanes padded to 128), so a cache kept
+    by head is re-laid block by block, layer by layer, on its way into the
+    call (PERF.md §6, PR 36). A cache preallocated at the whole
     sequence's length and passed through the scan is filled and copied whole
     once a scale, and each layer's whole cache once a layer: a scan's outputs
     are a fresh zero-filled buffer and its inputs are not the loop's to
@@ -164,7 +169,7 @@ def _blocks_step(
 
     def layer(carry, inp):
         x, = carry
-        li, kP, vP, cond6 = inp  # kP/vP: this layer's row blocks so far, [B2, n_s, H, dh] each
+        li, kP, vP, cond6 = inp  # kP/vP: this layer's row blocks so far, [B2, n_s, H·dh] each
         g1, s1, b1, g2, s2, b2 = (cond6[:, i][:, None, :] for i in range(6))
 
         h = nn.layer_norm(x) * (1.0 + s1.astype(dt)) + b1.astype(dt)
@@ -181,15 +186,14 @@ def _blocks_step(
             # reference uses 0.25/sqrt(dh) in the non-l2 branch
             # (VAR_models/basic_var.py:72), not the usual 1/sqrt(dh)
             sm_scale = 0.25 / math.sqrt(dh)
-        k, v = k.astype(dt), v.astype(dt)
+        k, v = k.astype(dt).reshape(B2, n, d), v.astype(dt).reshape(B2, n, d)
         # visible context: all written positions [0, pos+n) (static kv_len).
         # Pallas flash path on TPU keeps the logit tile in VMEM instead of a
-        # [B2, H, n, L] f32 HBM tensor per scale (ops/attention.py).
+        # [B2, H, n, L] f32 HBM tensor per scale (ops/attention.py); the view
+        # by head is a view: the kernel reads the rows as they lie.
+        by_head = lambda blocks: jnp.concatenate(blocks, axis=1).reshape(B2, pos + n, H, dh)
         out = (
-            decode_attention(
-                q, jnp.concatenate([*kP, k], axis=1), jnp.concatenate([*vP, v], axis=1),
-                kv_len=pos + n, sm_scale=sm_scale,
-            )
+            decode_attention(q, by_head([*kP, k]), by_head([*vP, v]), kv_len=pos + n, sm_scale=sm_scale)
             .astype(dt)
             .reshape(B2, n, d)
         )
@@ -207,7 +211,8 @@ def _blocks_step(
 
         return (x,), (k, v)
 
-    kPrev, vPrev = (c if isinstance(c, tuple) else (c[:, :, :pos],) for c in caches)
+    kPrev, vPrev = (c if isinstance(c, tuple) else (c[:, :, :pos].reshape(cfg.depth, B2, pos, d),)
+                    for c in caches)
     (x,), (kNew, vNew) = jax.lax.scan(
         layer,
         (x.astype(dt),),
@@ -267,7 +272,7 @@ def generate(
         kC, vC = (), ()
         # for the program record of the enclosing compile: the cache's shape
         # when full, whose fills and copies obs/xla_cost.kv_cache_whole_ops counts
-        note_program_geometry(kv_cache_shape=(cfg.depth, 2 * B, L, H, dh))
+        note_program_geometry(kv_cache_shape=(cfg.depth, 2 * B, L, H * dh))
         f_hat = jnp.zeros((B, vq_cfg.grid, vq_cfg.grid, vq_cfg.c_vae), jnp.float32)
 
         # first scale input: sos from class embedding + start/level/pos tables

@@ -112,7 +112,9 @@ def stablehlo_stats(lowered: Any) -> Dict[str, Any]:
     once), and under ``pallas_members_per_block`` — for a kernel whose call
     declares it — how many of those sites put how many members of the
     ``vmap``ped chunk into one token block (``{"fused_qlora": {"1": 28, "8":
-    29}}``). What the kernel gates selected (``ops/pallas_gate``) can then be
+    29}}``), under ``pallas_heads_per_block`` how many put how many of a
+    sequence's heads into one grid step (``{"decode_attention": {"16":
+    10}}``). What the kernel gates selected (``ops/pallas_gate``) can then be
     held against what was actually lowered. ``{}`` when ``as_text`` is
     unavailable."""
     try:
@@ -122,23 +124,25 @@ def stablehlo_stats(lowered: Any) -> Dict[str, Any]:
     import re
 
     kernels: Dict[str, int] = {}
-    members: Dict[str, Dict[str, int]] = {}
+    # what a kernel says in its call's metadata (ops/fused_qlora.py,
+    # ops/attention.py): sites by the declared number
+    declared: Dict[str, Dict[str, Dict[str, int]]] = {"members_per_block": {}, "heads_per_block": {}}
     for name, attrs in re.findall(
         r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"(.*?) : \(tensor<', text
     ):
         kernels[name] = kernels.get(name, 0) + 1
-        # a kernel that says so in its call's metadata (ops/fused_qlora.py)
-        # (a JSON string inside MLIR text: its quotes print as \22)
-        g = re.search(r'members_per_block(?:\\22|\\?")\s*:\s*(?:\\22|\\?")(\d+)', attrs)
-        if g:
-            by_g = members.setdefault(name, {})
-            by_g[g.group(1)] = by_g.get(g.group(1), 0) + 1
+        for key, by_kernel in declared.items():
+            # (a JSON string inside MLIR text: its quotes print as \22)
+            g = re.search(key + r'(?:\\22|\\?")\s*:\s*(?:\\22|\\?")(\d+)', attrs)
+            if g:
+                by_g = by_kernel.setdefault(name, {})
+                by_g[g.group(1)] = by_g.get(g.group(1), 0) + 1
     return {
         "stablehlo_lines": text.count("\n") + 1,
         "stablehlo_bytes": len(text),
         "stablehlo_sha256": hashlib.sha256(text.encode()).hexdigest()[:16],
         "pallas_kernels": kernels,
-        "pallas_members_per_block": members,
+        **{f"pallas_{key}": by_kernel for key, by_kernel in declared.items()},
     }
 
 

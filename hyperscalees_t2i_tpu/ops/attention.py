@@ -2,7 +2,7 @@
 
 The reference reaches flash-attn/xformers CUDA kernels through a fallback
 chain (``/root/reference/VAR_models/basic_var.py:15-31``). The TPU-native
-answer: a Pallas kernel that computes each (batch, head, query-block) tile's
+answer: a Pallas kernel that computes each (sequence, query-block) tile's
 logits entirely in VMEM — the naive XLA path materializes the full
 ``[2B, H, n, L]`` f32 logit tensor in HBM against a preallocated max-length
 KV cache at every scale, which is what made the Infinity "1M" preset
@@ -13,10 +13,22 @@ Shapes follow the models' cache layout: queries ``[B, nq, H, dh]``, KV cache
 scale step). An optional boolean ``kv_mask [B, L]`` handles padded text for
 cross-attention (Infinity models/infinity.py:182-194).
 
+The grid is ``(sequence, head group, query block, kv block)`` and a step
+holds as many of a sequence's heads as fit a stated VMEM budget
+(:func:`_heads_per_block`: all 16 at VAR's and Infinity's 16 x 64, so one
+step a sequence, query block and kv block), read as lane slices of the
+models' own ``[B, n, H·dh]`` rows: a step costs a v5e ~0.6 us before it has
+computed anything, a full ``[128, 64] x [512, 64]`` head ~0.5 us more, and one
+(sequence, head) a step was sixteen times the steps VAR needs — most of them
+on tiles of a few rows (PERF.md §6, PR 36). The call says the number in its metadata
+(``heads_per_block``; ``obs/xla_cost.stablehlo_stats`` reads it into
+``programs.jsonl``).
+
 On non-TPU backends (CPU tests) the same math runs as a fused XLA path —
 kernel (interpret mode) and XLA path are asserted equal in
-tests/test_attention.py; on the chip ``tools/kernel_check.py`` compiles,
-runs and compares the kernel at the VAR cache geometry.
+tests/test_attention.py, all heads a step and one head a step bit for bit;
+on the chip ``tools/kernel_check.py`` compiles, runs and compares the kernel
+with both at the VAR cache geometry.
 """
 
 from __future__ import annotations
@@ -57,18 +69,69 @@ def _naive_masked_attention(
     return out.astype(q.dtype)
 
 
+# What a call may take of VMEM (a v5e core has 128 MiB; Mosaic's default scoped
+# limit is 16 MiB, which 16 heads' double-buffered blocks would fill), and the
+# part of it that :func:`_heads_per_block` lets blocks and scratch have. The
+# rest is the head loop's temporaries: a head's float32 q, k, v, logit and
+# probability tiles are ~1.2 MB at [128, 64] x [512, 64], and nothing promises
+# that the 16 unrolled heads share them.
+VMEM_LIMIT_BYTES = 32 * 2**20
+VMEM_BUDGET_BYTES = 12 * 2**20
+_LANES = 128
+
+
+def _step_vmem_bytes(heads: int, dh: int, block_q: int, block_kv: int, itemsize: int) -> int:
+    """Bytes of VMEM one grid step's blocks and scratch take with ``heads``
+    heads in it: q, out, K and V blocks double-buffered, the three float32
+    accumulators; a row is whole 128-lane tiles."""
+    lanes = lambda n: -(-n // _LANES) * _LANES
+    blocks = 2 * itemsize * lanes(heads * dh) * (2 * block_q + 2 * block_kv)
+    scratch = 4 * heads * block_q * (2 * _LANES + lanes(dh))
+    return blocks + scratch
+
+
+def _heads_per_block(H: int, dh: int, block_q: int, block_kv: int, itemsize: int) -> int:
+    """How many of the ``H`` heads of a sequence one grid step holds.
+
+    A step costs ~0.6 us on a v5e for being a step, however little its tile
+    holds (VAR's scale 0, sixteen ``[1, 64] x [1, 64]`` heads: 0.63 us), and a
+    head's work comes on top (0.53 us at ``[128, 64] x [512, 64]``; PERF.md
+    §6, PR 36): the more heads a step the better, so the largest divisor of
+    ``H`` whose blocks and scratch fit :data:`VMEM_BUDGET_BYTES` — all 16 at
+    VAR's and Infinity's 16 x 64, 8 of 16 at 128 lanes a head. A group that is
+    not the whole ``H·dh`` row has to be whole 128-lane tiles (Mosaic's rule
+    for a block's last dimension)."""
+    for heads in range(H, 0, -1):
+        if H % heads or (heads != H and heads * dh % _LANES):
+            continue
+        if heads == 1 or _step_vmem_bytes(heads, dh, block_q, block_kv, itemsize) <= VMEM_BUDGET_BYTES:
+            return heads
+    return H  # no narrower group is whole lane tiles: the row as it is
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale: float, kv_len: int, block_kv: int,
+    *, sm_scale: float, kv_len: int, block_kv: int, heads: int, dh: int, zero_v_tail: bool,
 ):
-    """One (batch, head, q-block, kv-block) tile with online softmax.
+    """One (sequence, head group, q-block, kv-block) step with online softmax:
+    ``heads`` heads of one sequence, each the lane slice ``h·dh … (h+1)·dh``
+    of the ``[block_q, heads·dh]`` query and ``[block_kv, heads·dh]`` K, V
+    blocks — the layout the models write, so nothing is transposed in HBM.
+    The heads are a static loop; each runs the same float32 products, ``exp``
+    and running (max, sum, weighted-V) accumulators on its own
+    ``[block_q, dh] x [block_kv, dh]`` tile that a one-head step would.
 
-    VMEM holds only the [block_q, block_kv] logit tile plus running
-    (max, sum, weighted-V) accumulators — the KV axis is a *grid* dimension,
-    so the kernel's footprint is independent of the cache length (the old
-    kernel streamed the full K/V and a [block_q, L] logit tile into VMEM,
-    which at the Infinity 1M preset (~10k kv, dh 128) was at/over the ~16MB
-    VMEM budget).
+    VMEM holds only a head's [block_q, block_kv] logit tile plus the
+    accumulators — the KV axis is a *grid* dimension, so the kernel's
+    footprint is independent of the cache length (the old kernel streamed the
+    full K/V and a [block_q, L] logit tile into VMEM, which at the Infinity
+    1M preset (~10k kv, dh 128) was at/over the ~16MB VMEM budget).
+
+    Key rows past ``kv_len`` are masked in the logits (``pos >= kv_len``), so
+    their probability is exactly 0 — but 0 times a NaN is NaN, and where the
+    cache is longer than its valid prefix (``zero_v_tail``) the rows between
+    hold whatever was written there: V's rows past ``kv_len`` are zeroed
+    before ``p @ v``. (The rows the wrapper pads a block with are zeros.)
     """
     from jax.experimental import pallas as pl
 
@@ -81,34 +144,47 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [bq, dh]
-    k = k_ref[0, 0].astype(jnp.float32)  # [bkv, dh]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale  # [bq, bkv]
-    pos = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    block_q = q_ref.shape[1]
+    # the heads of a sequence share positions and the text mask
+    pos = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
     valid = pos < kv_len
     if mask_ref is not None:
         valid = jnp.logical_and(valid, mask_ref[0] != 0)  # [1, bkv] over rows
-    s = jnp.where(valid, s, NEG_INF)
+    if zero_v_tail:
+        v_row = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, (block_kv, dh), 0)
+        v_valid = v_row < kv_len
 
-    m_prev = m_scr[...][:, :1]  # [bq, 1]
-    l_prev = l_scr[...][:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)  # rescale of previous blocks' sums
-    p = jnp.exp(s - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    for h in range(heads):
+        lanes = slice(h * dh, (h + 1) * dh)
+        q = q_ref[0, :, lanes].astype(jnp.float32)  # [bq, dh]
+        k = k_ref[0, :, lanes].astype(jnp.float32)  # [bkv, dh]
+        v = v_ref[0, :, lanes].astype(jnp.float32)
+        if zero_v_tail:
+            v = jnp.where(v_valid, v, 0.0)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale  # [bq, bkv]
+        s = jnp.where(valid, s, NEG_INF)
+
+        m_prev = m_scr[h][:, :1]  # [bq, 1]
+        l_prev = l_scr[h][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)  # rescale of previous blocks' sums
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(kv_i == n_kv - 1)
     def _finalize():
-        l = l_scr[...][:, :1]
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        for h in range(heads):
+            l = l_scr[h][:, :1]
+            o_ref[0, :, h * dh:(h + 1) * dh] = (
+                acc_scr[h] / jnp.maximum(l, 1e-30)
+            ).astype(o_ref.dtype)
 
 
 def _pallas_attention(
@@ -122,75 +198,79 @@ def _pallas_attention(
     block_kv: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
+    """The one ``pallas_call`` of a layer's attention. Grid ``(B, H / heads,
+    q blocks, kv blocks)``, kv innermost and sequential; q, K, V and the
+    output are the callers' arrays viewed ``[B, n, H·dh]``, in blocks
+    ``(1, block_q | block_kv, heads·dh)`` with ``heads`` from
+    :func:`_heads_per_block` — the whole row, one step a (sequence, q block,
+    kv block), wherever it fits. Nothing is transposed in HBM around the
+    call. Rows are padded with zeros to a whole number of blocks (VAR: scale
+    8's 169 queries to 256, scale 9's 680 keys to 1024, as before PR 36).
+    With last blocks that overhang their arrays every call ran alone on the
+    chip and the VAR step that holds them never came back — in that step XLA
+    keeps scale 9's V at the very end of VMEM, where such a block addresses
+    past it (PERF.md §6, PR 36)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, nq, H, dh = q.shape
     L = k.shape[1]
     block_q = min(block_q, nq)
     n_qblk = -(-nq // block_q)
-    nq_pad = n_qblk * block_q
     block_kv = min(block_kv, L)
     n_kvblk = -(-L // block_kv)
-    L_pad = n_kvblk * block_kv
-    # head-major layout so each grid instance reads one contiguous tile
-    qt = jnp.moveaxis(q, 2, 1)  # [B, H, nq, dh]
-    if nq_pad != nq:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, nq_pad - nq), (0, 0)))
-    kt = jnp.moveaxis(k, 2, 1)  # [B, H, L, dh]
-    vt = jnp.moveaxis(v, 2, 1)
-    if L_pad != L:
-        # padded tail positions fall outside kv_len and are masked in-kernel
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, L_pad - L), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, L_pad - L), (0, 0)))
+    heads = _heads_per_block(H, dh, block_q, block_kv, q.dtype.itemsize)
 
     kernel = functools.partial(
-        _flash_kernel, sm_scale=sm_scale, kv_len=kv_len, block_kv=block_kv
+        _flash_kernel, sm_scale=sm_scale, kv_len=kv_len, block_kv=block_kv,
+        heads=heads, dh=dh, zero_v_tail=L > kv_len,
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, qi, ki: (b, h, ki, 0)),
-        pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, qi, ki: (b, h, ki, 0)),
-    ]
-    operands = [qt, kt, vt]
+    q_spec = pl.BlockSpec((1, block_q, heads * dh), lambda b, g, qi, ki: (b, qi, g))
+    kv_spec = pl.BlockSpec((1, block_kv, heads * dh), lambda b, g, qi, ki: (b, ki, g))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    # rows up to a whole number of blocks (zeros; key rows there fall outside
+    # kv_len and are masked in-kernel): a block never overhangs its array
+    rows = lambda t, n: jnp.pad(t, ((0, 0), (0, n - t.shape[1])) + ((0, 0),) * (t.ndim - 2))
+    operands = [rows(t.reshape(*t.shape[:2], H * dh), n)
+                for t, n in ((q, n_qblk * block_q), (k, n_kvblk * block_kv), (v, n_kvblk * block_kv))]
     if kv_mask is not None:
-        if L_pad != kv_mask.shape[1]:
-            kv_mask = jnp.pad(kv_mask, ((0, 0), (0, L_pad - kv_mask.shape[1])))
         # [B, 1, L] int32: Mosaic takes neither a one-row block out of a
         # [B, L] array (a block's second-to-last dim must be a multiple of 8
         # or the whole axis) nor a boolean memref
-        in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b, h, qi, ki: (b, 0, ki)))
-        operands.append(kv_mask.astype(jnp.int32)[:, None, :])
+        in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b, g, qi, ki: (b, 0, ki)))
+        operands.append(rows(kv_mask.astype(jnp.int32), n_kvblk * block_kv)[:, None, :])
     else:
         kernel = _wrap_no_mask(kernel)
 
-    scratch_shapes = _vmem_scratch(block_q, dh)
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, H, nq_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_qblk * block_q, H * dh), q.dtype),
         # kv innermost: it is the sequential reduce dimension; the output
         # block index is constant in ki so Pallas keeps revisiting the same
         # tile until the accumulators are finalized.
-        grid=(B, H, n_qblk, n_kvblk),
+        grid=(B, H // heads, n_qblk, n_kvblk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, ki: (b, h, qi, 0)),
-        scratch_shapes=scratch_shapes,
+        out_specs=q_spec,
+        scratch_shapes=_vmem_scratch(heads, block_q, dh),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="decode_attention",
+        # read back from the lowered step by obs/xla_cost.stablehlo_stats:
+        # programs.jsonl says for every site how many heads share a grid step
+        metadata={"heads_per_block": str(heads)},
     )(*operands)
-    out = out[:, :, :nq, :]
-    return jnp.moveaxis(out, 1, 2)  # [B, nq, H, dh]
+    return out[:, :nq].reshape(B, nq, H, dh)
 
 
-def _vmem_scratch(block_q: int, dh: int):
-    """Running-max / running-sum / output accumulators ([bq,128] lanes for the
-    scalars, [bq,dh] for the weighted-V sum)."""
+def _vmem_scratch(heads: int, block_q: int, dh: int):
+    """Running-max / running-sum / output accumulators a head ([bq,128] lanes
+    for the scalars, [bq,dh] for the weighted-V sum)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    lanes = 128
     return [
-        pltpu.VMEM((block_q, lanes), jnp.float32),
-        pltpu.VMEM((block_q, lanes), jnp.float32),
-        pltpu.VMEM((block_q, dh), jnp.float32),
+        pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+        pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+        pltpu.VMEM((heads, block_q, dh), jnp.float32),
     ]
 
 

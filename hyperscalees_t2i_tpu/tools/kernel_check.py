@@ -12,7 +12,12 @@ VAR-d16 under ``--base_quant int8`` with the member axis the
 benchmark's cells put in front, the VAR ten-scale KV cache, Infinity's masked
 cross-attention, the hybrid cell's 64 recurrent states a DeltaNet layer), run
 with ``interpret=False`` and compared with the
-XLA form the gate would otherwise choose. ``chip_smoke.py`` runs the first
+XLA form the gate would otherwise choose. A case may name a twin — the same
+kernel over the same arithmetic on another grid (``fused_qlora`` once a
+member; ``decode_attention`` one head a grid step, :func:`one_head_a_step`):
+the run counts the outputs that differ from it bit for bit, prints
+microseconds a call of both, and an attention case fails on any difference.
+``chip_smoke.py`` runs the first
 case of every kernel the TPU gates select and fails on a disagreement.
 
 ``--compile_only`` lowers and compiles each case for a TPU v5e *without a
@@ -28,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import sys
 import traceback
@@ -52,10 +58,13 @@ class Case:
     xla_fn: Callable[..., Any]
     tol: float  # bound on max|kernel − xla| / max|xla|
     tol_reason: str
-    # the same kernel called once a member (what vmap's default batching of a
-    # Pallas call amounts to): where set, the chip run times both and counts
-    # the outputs that differ bit for bit
-    per_member_fn: Optional[Callable[..., jax.Array]] = None
+    # the same kernel over the same arithmetic on another grid, as (name, fn)
+    # — "per_member": called once a member (what vmap's default batching of a
+    # Pallas call amounts to); "one_head_a_step": a grid step a (sequence,
+    # head). Where set, the chip run times both and counts the outputs that
+    # differ bit for bit; ``twin_exact`` fails the case on any.
+    twin: Optional[Tuple[str, Callable[..., jax.Array]]] = None
+    twin_exact: bool = False
 
 
 def _factored(key, m: int, n: int, r_e: int, noise_dtype):
@@ -115,7 +124,7 @@ def _qlora_case(label: str, T: int, din: int, dout: int, members: int = 0) -> Ca
 
     return Case(
         "fused_qlora", label, make, run(kernel), run(xla_fused_qlora),
-        per_member_fn=per_member if members > 1 else None,
+        twin=("per_member", per_member) if members > 1 else None,
         tol=4 * _BF16_EPS,
         tol_reason="the XLA form rounds a_k, b_k and both partial products to "
                    "bf16 before the sum; the kernel keeps f32 until one final "
@@ -123,29 +132,48 @@ def _qlora_case(label: str, T: int, din: int, dout: int, members: int = 0) -> Ca
     )
 
 
+def one_head_a_step(attend: Callable[..., jax.Array], q, k, v, mask=None) -> jax.Array:
+    """``attend(q, k, v, mask)`` with a grid step a (sequence, head): the
+    heads folded into the sequence axis, ``[B·H, n, 1, dh]``, so that the
+    kernel's rule can put one head in a step and no more — the grid
+    ``decode_attention`` had up to PR 35, as a case of the kernel it has now.
+    What a step holds changes no head's arithmetic, so the two agree bit for
+    bit (here on the chip, in ``tests/test_attention.py`` interpreted)."""
+    B, nq, H, dh = q.shape
+    fold = lambda t: jnp.moveaxis(t, 2, 1).reshape(B * H, t.shape[1], 1, dh)
+    out = attend(fold(q), fold(k), fold(v), None if mask is None else jnp.repeat(mask, H, axis=0))
+    return jnp.moveaxis(out.reshape(B, H, nq, dh), 1, 2)
+
+
 def _attention_case(label: str, B: int, nq: int, L: int, kv_len: Optional[int],
-                    H: int = 16, dh: int = 64, masked: bool = False) -> Case:
+                    H: int = 16, dh: int = 64, masked: bool = False, members: int = 0) -> Case:
+    """One ``decode_attention`` call; ``members`` > 0 puts the member axis
+    ``lax.map(batch_size=member_batch)`` vmaps over in front of every operand."""
     from ..ops.attention import decode_attention
 
     def make(key):
         kq, kk, kv, km = jax.random.split(key, 4)
-        bf = lambda k, s: jax.random.normal(k, s, jnp.float32).astype(jnp.bfloat16)
+        lead = (members,) if members else ()
+        bf = lambda k, s: jax.random.normal(k, (*lead, *s), jnp.float32).astype(jnp.bfloat16)
         args = (bf(kq, (B, nq, H, dh)), bf(kk, (B, L, H, dh)), bf(kv, (B, L, H, dh)))
         if masked:
             # padded text: a valid prefix per row, never empty
-            n_valid = jax.random.randint(km, (B, 1), 1, L + 1)
-            args += (jnp.arange(L)[None, :] < n_valid,)
+            n_valid = jax.random.randint(km, (*lead, B, 1), 1, L + 1)
+            args += (jnp.arange(L) < n_valid,)
         return args
 
-    def run(use_pallas):
-        def fn(q, k, v, mask=None):
+    def run(use_pallas, fold=False):
+        def attend(q, k, v, mask=None):
+            # a head's softmax scale is the unfolded call's, 1/sqrt(dh)
             return decode_attention(q, k, v, kv_len=kv_len, kv_mask=mask,
                                     use_pallas=use_pallas)
 
-        return fn
+        fn = functools.partial(one_head_a_step, attend) if fold else attend
+        return jax.vmap(fn) if members else fn
 
     return Case(
         "decode_attention", label, make, run(True), run(False),
+        twin=("one_head_a_step", run(True, fold=True)), twin_exact=True,
         tol=2 * _BF16_EPS,
         tol_reason="both sides hold the softmax in f32 and round the output "
                    "to bf16 once; the online-softmax rescaling reorders the "
@@ -246,6 +274,15 @@ def cases() -> List[Case]:
                         8, nq, 680, kv)
         for i, (nq, kv) in reversed(list(enumerate(_VAR_SCALES)))
     ]
+    # what the benchmark's VAR cell calls (member_batch 4): the member axis in
+    # front, the cache as long as its valid prefix (models/var.py concatenates
+    # the row blocks written so far) — the two scales whose last block is part
+    # rows, part the wrapper's zeros
+    out += [
+        _attention_case(f"var scale {i}, member axis 4: q[4,8,{nq},16,64] vs cache[4,8,{kv},16,64]",
+                        8, nq, kv, kv, members=4)
+        for i, (nq, kv) in ((9, _VAR_SCALES[9]), (8, _VAR_SCALES[8]))
+    ]
     # the hybrid cell's decode step (qwen3next80b-ep4-train-pop8x8): 64
     # sequences' states a DeltaNet layer; in the step pop_eval's vmap puts the
     # member axis in front, 8 members x 8 sequences
@@ -299,7 +336,8 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
             jax.eval_shape(case.make, key),
         )
-        jax.jit(case.kernel_fn).lower(*args).compile()
+        for fn in (case.kernel_fn, *(case.twin or ())[1:]):  # the twin is Mosaic's to refuse too
+            jax.jit(fn).lower(*args).compile()
         return {**rec, "ok": True, "compiled_for": compile_only_device.device_kind}
     args = jax.jit(case.make)(key)
     f32_leaves = lambda fn: [t.astype(jnp.float32) for t in jax.tree_util.tree_leaves(jax.jit(fn)(*args))]
@@ -313,17 +351,20 @@ def run_case(case: Case, compile_only_device: Optional[Any] = None) -> Dict[str,
     # its own scale: the record is of the one furthest from its reference
     rel, diff, scale = max(map(apart, gots, refs))
     got = gots[0]
-    if case.per_member_fn is not None:
-        per = jax.jit(case.per_member_fn)(*args).astype(jnp.float32)
-        rec.update(
-            differ_from_per_member=int(jnp.sum(got != per)), outputs=int(got.size),
-            us_per_call=round(_us_per_call(case.kernel_fn, args), 2),
-            us_per_call_per_member=round(_us_per_call(case.per_member_fn, args), 2),
-        )
+    differ = 0
+    if case.twin is not None:
+        name, twin_fn = case.twin
+        differ = int(jnp.sum(got != jax.jit(twin_fn)(*args).astype(jnp.float32)))
+        rec.update({
+            f"differ_from_{name}": differ, "outputs": int(got.size),
+            "us_per_call": round(_us_per_call(case.kernel_fn, args), 2),
+            f"us_per_call_{name}": round(_us_per_call(twin_fn, args), 2),
+        })
     return {
         **rec, "max_abs_diff": diff, "max_abs_ref": scale, "rel": rel,
         "tol": case.tol, "tol_reason": case.tol_reason,
-        "ok": bool(rel <= case.tol and all(bool(jnp.all(jnp.isfinite(g))) for g in gots)),
+        "ok": bool(rel <= case.tol and all(bool(jnp.all(jnp.isfinite(g))) for g in gots)
+                   and not (case.twin_exact and differ)),
         "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,
     }

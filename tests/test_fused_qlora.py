@@ -346,6 +346,28 @@ def test_mosaic_compiles_the_largest_cell_calls(call, v5e_chip):
     assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
 
 
+@pytest.mark.parametrize("label", [
+    "var scale 0:", "var scale 8, member axis 4", "var scale 9, member axis 4",
+    "infinity cross-attn: q[8,256,16,64] vs text[8,16,16,64] + mask",
+])
+def test_mosaic_compiles_decode_attention_with_all_heads_a_step(label, v5e_chip):
+    """``ops/attention.py`` (here because one file of a test run may load
+    libtpu) at the VAR cell's smallest call and its two largest — scale 8's
+    last query block holds 41 rows of 128, scale 9's last kv block 168 of 512,
+    the rest zeros — under the member axis, and at Infinity's masked
+    cross-attention: sixteen 64-lane slices of a 1024-lane block (every other
+    one off a 128-lane tile), 8 MB of blocks and scratch under a raised VMEM
+    limit are Mosaic's to refuse, and the interpreter accepts anything.
+    The one-head-a-step twin ``kernel_check`` runs beside it compiles too."""
+    from hyperscalees_t2i_tpu.tools import kernel_check
+
+    case = next(c for c in kernel_check.cases()
+                if c.kernel == "decode_attention" and c.label.startswith(label))
+    assert case.twin[0] == "one_head_a_step" and case.twin_exact
+    rec = kernel_check.run_case(case, compile_only_device=v5e_chip)
+    assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
+
+
 @pytest.mark.parametrize("members", [False, True], ids=["64-sequences", "member-axis-8x8"])
 def test_mosaic_compiles_the_gated_delta_step_of_the_hybrid_cell(members, v5e_chip):
     """The third default-on kernel (``ops/gated_delta.py``, here because one

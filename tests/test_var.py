@@ -269,9 +269,13 @@ def test_blocks_step_equals_the_form_that_carried_the_stack_through_the_scan(si,
     np.testing.assert_array_equal(np.asarray(x), np.asarray(x0))
     pos, n = var_mod._scale_slices(cfg)[si]
     # the cache that comes back holds the positions written so far, all of
-    # them, a block of rows a scale
-    assert [b.shape[-3] for b in k_blocks] == [0] + [m for _, m in var_mod._scale_slices(cfg)[: si + 1]]
-    kAll, vAll = (jnp.concatenate(blocks, axis=-3) for blocks in (k_blocks, v_blocks))
+    # them, a block of rows a scale — a row as the qkv product wrote it, its
+    # heads side by side (PR 36: the block decode_attention reads)
+    assert [b.shape[-2:] for b in k_blocks] == [
+        (m, d) for m in [0] + [m for _, m in var_mod._scale_slices(cfg)[: si + 1]]]
+    kAll, vAll = (
+        jnp.concatenate(blocks, axis=-2).reshape(*blocks[0].shape[:-2], pos + n, cfg.n_heads, cfg.head_dim)
+        for blocks in (k_blocks, v_blocks))
     assert float(jnp.abs(kAll0[..., : pos + n, :, :]).min()) > 0.0
     assert not np.asarray(kAll0[..., pos + n :, :, :]).any()
     np.testing.assert_array_equal(np.asarray(kAll), np.asarray(kAll0[..., : pos + n, :, :]))
@@ -368,9 +372,10 @@ def test_whole_cache_ops_of_a_member_chunk_before_and_after(monkeypatch):
     params = var_mod.init_var(jax.random.PRNGKey(0), cfg)
     theta = _perturbed_theta(params, cfg, 2)
     labels = jnp.asarray([0, 2], jnp.int32)
-    shape = (cfg.depth, 4, cfg.seq_len, cfg.n_heads, cfg.head_dim)
+    by_head = (cfg.depth, 4, cfg.seq_len, cfg.n_heads, cfg.head_dim)  # the older form's stack
+    flat = (cfg.depth, 4, cfg.seq_len, cfg.d_model)  # a row's heads side by side, as generate notes it
 
-    def counts(step):
+    def counts(step, shape):
         monkeypatch.setattr(var_mod, "_blocks_step", step)
         compiled = jax.jit(jax.vmap(
             lambda lora, p, l, k: var_mod.generate(p, cfg, l, k, lora=lora, decode=False),
@@ -380,8 +385,9 @@ def test_whole_cache_ops_of_a_member_chunk_before_and_after(monkeypatch):
         assert sum(got.values()) == _count_whole(compiled.as_text(), shape)
         return got
 
-    after = counts(var_mod._blocks_step)
-    before = counts(_through_scan_preallocated)
+    after = counts(var_mod._blocks_step, flat)
+    assert not counts(var_mod._blocks_step, by_head)  # and nothing holds the cache by head
+    before = counts(_through_scan_preallocated, by_head)
     assert before.get("while", 0) == len(cfg.patch_nums)  # each scale's scan returned the stack
     assert "while" not in after and sum(after.values()) < sum(before.values()), (before, after)
 
@@ -412,7 +418,10 @@ def test_traced_run_counts_the_ops_as_large_as_the_cache(tmp_path, monkeypatch):
     programs = [json.loads(l) for l in (tmp_path / "run" / "programs.jsonl").read_text().splitlines()]
     (step,) = [p for p in programs if p["label"].startswith("es_step_")]
     (text, shape), = seen
-    assert list(shape) == step["geometry"]["kv_cache_shape"] and len(shape) == 5
+    assert list(shape) == step["geometry"]["kv_cache_shape"] and len(shape) == 4  # [depth, 2B, L, H·dh]
+    # sites by heads a grid step (PR 36): the key is always there; on the CPU the step runs
+    # the XLA path and has no site (tests/test_attention.py lowers the toy for a TPU: {"2": 3})
+    assert step["pallas_heads_per_block"] == {} == step["pallas_kernels"]
     counts = step["kv_cache_whole_ops"]  # {} where XLA:CPU never builds the stack whole
     assert all(isinstance(v, int) and v > 0 for v in counts.values())
     assert sum(counts.values()) == _count_whole(text, shape)
