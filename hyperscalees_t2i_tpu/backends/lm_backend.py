@@ -1,8 +1,9 @@
-"""``lm_ar``: a sparse-expert decoder language model as an autoregressive
-image-token generator under ES (models/lm.py; the family is the one the
-``model_type`` of ``--lm_config`` names: MLA over a latent cache, or
+"""``lm_ar``: a decoder language model as an autoregressive image-token
+generator under ES (models/lm.py; the family is the one the ``model_type`` of
+``--lm_config`` names: MLA over a latent cache with routed experts,
 ``qwen3_next``'s Gated DeltaNet layers beside gated attention,
-models/lm_hybrid.py).
+models/lm_hybrid.py, or ``granitemoehybrid``'s Mamba-2 mixers beside
+attention with a dense FFN, models/lm_ssm.py).
 
 A mechanism's backend, not a model's: sizes come from the ``config.json``-shaped
 file ``--lm_config`` names (the model's published keys plus the share of the
@@ -18,14 +19,16 @@ Besides the images, ``generate_p`` returns per-image rows (sampled ids, the
 router's choices, counters); ``pop_eval`` carries them beside the reward rows
 and :meth:`LMArBackend.step_metrics` reduces them to the step's metrics:
 
-- ``moe/local_assignments``: token–expert pairs computed here, all layers;
+- ``moe/local_assignments`` (a family with a router, as the next two):
+  token–expert pairs computed here, all layers;
 - ``moe/max_expert_load``: largest count one held expert saw in one call over
   the mean of that call;
 - ``moe/pair_route_flip``: share of (cache slot, layer) top-k sets that differ
   between the two halves of an antithetic pair;
 - ``lm/state_bytes``, ``lm/kv_cache_bytes`` (a family that says what a sequence
-  carries; ``qwen3_next`` does): bytes of recurrent state + conv window, and of
-  KV cache, that the step's sequences carry through their decode scans — what
+  carries; ``qwen3_next`` and ``granitemoehybrid`` do): bytes of recurrent
+  state + conv window, and of KV cache, that the step's sequences carry
+  through their decode scans — what
   grows with ``pop_size x prompts_per_gen`` whatever the prompt length, and of
   which ``member_batch / pop_size`` is resident at a time;
 - ``lm/hc_marginal_err``, ``lm/hc_row_err``, ``lm/hc_offdiag_mass`` (a model
@@ -166,19 +169,22 @@ class LMArBackend:
         return self.generate_p(self.frozen, theta, flat_ids, key)[0]
 
     def step_metrics(self, rows: Dict[str, jax.Array], pop: int, antithetic: bool) -> Dict[str, jax.Array]:
-        """The generator's rows of a step (each ``[pop, B, ...]``) → metrics."""
-        topk = rows["topk"]
-        out = {
-            "moe/local_assignments": rows["assign"].sum().astype(jnp.float32),
-            "moe/max_expert_load": rows["load"].max(),
-        }
-        half = pop // 2
-        if antithetic and half and topk.shape[3]:
-            seen = (topk[:half] >= 0).all(-1)                              # slots that hold a token
-            differ = (topk[:half] != topk[half: 2 * half]).any(-1) & seen
-            out["moe/pair_route_flip"] = differ.sum() / jnp.maximum(seen.sum(), 1)
-        else:
-            out["moe/pair_route_flip"] = jnp.float32(0.0)
+        """The generator's rows of a step (each ``[pop, B, ...]``) → metrics.
+        A family without a router (``granitemoehybrid`` with no experts)
+        returns no routing rows, and its step has no ``moe/*`` metrics and no
+        ``probe/topk``."""
+        out = {}
+        if "topk" in rows:
+            topk = rows["topk"]
+            out["moe/local_assignments"] = rows["assign"].sum().astype(jnp.float32)
+            out["moe/max_expert_load"] = rows["load"].max()
+            half = pop // 2
+            if antithetic and half and topk.shape[3]:
+                seen = (topk[:half] >= 0).all(-1)                              # slots that hold a token
+                differ = (topk[:half] != topk[half: 2 * half]).any(-1) & seen
+                out["moe/pair_route_flip"] = differ.sum() / jnp.maximum(seen.sum(), 1)
+            else:
+                out["moe/pair_route_flip"] = jnp.float32(0.0)
         for kind in ("state", "kv_cache"):
             if f"carried/{kind}" in rows:
                 out[f"lm/{kind}_bytes"] = rows[f"carried/{kind}"].sum()
@@ -186,7 +192,8 @@ class LMArBackend:
             out["lm/hc_marginal_err"] = rows["hc_err"].max()
             out["lm/hc_row_err"] = rows["hc_row"].max()
             out["lm/hc_offdiag_mass"] = rows["hc_off"].sum() / jnp.maximum(rows["hc_n"].sum(), 1.0)
-        n = min(PROBE_SEQUENCES, topk.shape[1])
+        n = min(PROBE_SEQUENCES, rows["ids"].shape[1])
         for k in ("ids", "topk", "logits", "prompt_ids", "prompt_len"):
-            out[f"probe/{k}"] = rows[k][0, :n]
+            if k in rows:
+                out[f"probe/{k}"] = rows[k][0, :n]
         return out

@@ -1,7 +1,7 @@
 """Decoder language models as autoregressive image-token generators.
 
-Two families stand behind one :func:`generate`, chosen by the ``model_type`` of
-a ``config.json``-shaped file (:func:`config_from_json`):
+Three families stand behind one :func:`generate`, chosen by the ``model_type``
+of a ``config.json``-shaped file (:func:`config_from_json`):
 
 - the one this file writes down: multi-head latent attention (MLA) over a
   latent cache, a sigmoid top-k router over routed experts of which this chip
@@ -19,15 +19,21 @@ a ``config.json``-shaped file (:func:`config_from_json`):
 - ``qwen3_next`` (``models/lm_hybrid.py``): Gated DeltaNet layers beside gated
   softmax attention, a softmax router with a sigmoid-gated shared expert.
   ``generate`` carries a recurrent state and conv window for some layers and a
-  KV cache for the others, side by side.
+  KV cache for the others, side by side;
+- ``granitemoehybrid`` with no routed experts (``models/lm_ssm.py``): Mamba-2
+  (SSD) mixers beside grouped-query attention without position embedding, a
+  dense SwiGLU in every layer, published multipliers on the embedding, the
+  residual branches and the logits, a head tied to the embedding. No router:
+  ``generate`` returns no routing rows for it. Its layers run as a
+  ``lax.scan`` over the periods of its layer pattern.
 
-What both share lives here: the use the system makes of either
+What they share lives here: the use the system makes of any of them
 (:class:`GeneratorUse`: the share of a stated deployment this chip holds —
 ``experts_held``, ``expert_offset``, ``vocab_rows_held`` — and
 ``image_tokens``), ``routed_experts`` over ``ops/grouped.py`` with
 ``expert_factors``, and :func:`generate` itself: prefill, the ``lax.scan`` of
 sampled positions, sampling, the image-id range, the VQ decode. A family is the
-four functions of :class:`Family`. No preset table: a user with a checkpoint
+functions of :class:`Family`. No preset table: a user with a checkpoint
 directory states sizes the same way.
 
 The first family's layer equations (the plain float32 form is
@@ -71,7 +77,7 @@ no code with this file):
   head. In the model and the reference; not run by :func:`generate` (at plain
   sampling the family discards it).
 
-Generation, either family: prefill the (padded, masked) prompt ids into what
+Generation, any family: prefill the (padded, masked) prompt ids into what
 the family carries (here the latent cache), then ``image_tokens.count`` steps
 of ``lax.scan`` — embed the last id (the begin-of-image id first), one pass of
 the blocks over the carried state, final norm, head over the rows held, the
@@ -134,14 +140,24 @@ HC_DTYPE = jnp.float32
 HC_POST_GAIN = 2.0  # H_post = 2σ(·): at b = 0 a sub-layer's output is written at weight 1
 
 
+def head_columns(params: Params, lo: int, hi: int):
+    """An untied head's cut to the ids ``[lo, hi)``: every leaf of the
+    ``params["head"]`` node (an int8 base and its scale, or a float kernel; a
+    bias) on its output axis. Returns (the params :func:`generate`'s scan is
+    handed, the whole head's shape)."""
+    return ({**params, "head": jax.tree_util.tree_map(lambda w: w[..., lo:hi], params["head"])},
+            kernel_shape(params["head"]))
+
+
 class Family(NamedTuple):
     """What :func:`generate` and the backend ask of a model family."""
     init: Callable            # (key, cfg, base_quant) -> params
     prefill_state: Callable   # (params, cfg, ids, lens, lora, scale, factors) -> (carried state, MoE stats, bytes a sequence by kind)
     decode_layers: Callable   # (params, cfg, x, state, i, prompt_len, lora, scale, factors) -> (x, state, MoE stats)
-    head: Callable            # (params, cfg, hidden) -> float32 logits over the columns of params["head"]: the rows
-                              # held, or the image-id range's where generate cut the node to them
+    head: Callable            # (params, cfg, hidden) -> float32 logits over the head cut_head handed it: the rows
+                              # held, or the image-id range's where generate cut the head to them
     state_rows: Optional[Callable] = None  # (cfg, carried state after the scan) -> further per-image rows {name: [B]}
+    cut_head: Callable = head_columns      # (params, lo, hi) -> (params with the head cut to ids [lo, hi), whole shape)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -330,7 +346,7 @@ def config_from_json(path: str):
     ``image_tokens`` group, ``vq`` and ``torch_dtype``. A file without
     ``model_type`` is read as the MLA family, as every file was before the key
     was looked at."""
-    from . import lm_hybrid  # the second family builds on this module
+    from . import lm_hybrid, lm_ssm  # the other families build on this module
 
     raw = json.loads(Path(path).read_text())
     model_type = raw.get("model_type")
@@ -338,7 +354,9 @@ def config_from_json(path: str):
         return LMConfig.from_raw(raw)
     if model_type == lm_hybrid.MODEL_TYPE:
         return lm_hybrid.HybridLMConfig.from_raw(raw)
-    known = [t for t in MLA_MODEL_TYPES if t] + [lm_hybrid.MODEL_TYPE]
+    if model_type == lm_ssm.MODEL_TYPE:
+        return lm_ssm.SSMLMConfig.from_raw(raw)
+    known = [t for t in MLA_MODEL_TYPES if t] + [lm_hybrid.MODEL_TYPE, lm_ssm.MODEL_TYPE]
     raise ValueError(f"{path}: model_type {model_type!r} is not a family this model code writes down ({known})")
 
 
@@ -967,16 +985,17 @@ def generate(
     decode: bool = True,
     item_index: Optional[jax.Array] = None,
 ):
-    """Prefill + ``image_tokens`` sampled steps + VQ decode, for either family:
+    """Prefill + ``image_tokens`` sampled steps + VQ decode, for any family:
     what the scan carries between positions is whatever ``cfg.family()``'s
     ``prefill_state`` returns (one latent cache a layer; or recurrent states,
     conv windows and KV caches side by side) and ``generate`` does not look
     inside it. Returns (images ``[B, H, W, 3]`` in [0, 1] — or the sampled ids
-    when ``decode=False`` — and per-image rows: ``ids`` ``[B, n]``, ``topk``
-    ``[B, Tmax, moe layers, k]`` (the router's choice at every cache slot, -1
-    where none), ``assign`` ``[B]`` (token–expert pairs computed here), ``load``
-    ``[B]`` (largest expert load ratio of any call, the same for every image of
-    a call), ``logits`` ``[B, n / PROBE_EVERY, image_vocab]`` and, where the
+    when ``decode=False`` — and per-image rows: ``ids`` ``[B, n]``; in a family
+    with a router ``topk`` ``[B, Tmax, moe layers, k]`` (the router's choice at
+    every cache slot, -1 where none), ``assign`` ``[B]`` (token–expert pairs
+    computed here), ``load`` ``[B]`` (largest expert load ratio of any call,
+    the same for every image of a call); ``logits`` ``[B, n / PROBE_EVERY,
+    image_vocab]`` and, where the
     family says what a sequence carries, ``carried/<kind>`` ``[B]`` in bytes;
     with hyper-connections ``hc_err``, ``hc_row``, ``hc_off``, ``hc_n`` ``[B]``, :func:`_hc_gauges`).
 
@@ -987,17 +1006,17 @@ def generate(
     fam = cfg.family()
     item_idx = jnp.arange(B) if item_index is None else item_index
     lo, hi = cfg.image_id_offset, cfg.image_id_offset + cfg.image_vocab
-    note_program_geometry(lm_head_shape=kernel_shape(params["head"]))
 
     with obs_scope("generate"):
         # sampling sees the image-id range only, so the decode scan is handed
-        # the head's columns of that range: every leaf of the node (an int8
-        # base and its scale, or a float kernel; a bias) cut on its output
-        # axis once, here. XLA sinks a slice of the logits through the dot but
-        # not through an int8 node's dequantization: it wrote the whole head to
-        # HBM at every position (``lm_head_whole_ops`` counts what is left)
+        # the head cut to that range once, here (``Family.cut_head``: an
+        # untied head's columns, a tied one's embedding rows). XLA sinks a
+        # slice of the logits through the dot but not through an int8 node's
+        # dequantization: it wrote the whole head to HBM at every position
+        # (``lm_head_whole_ops`` counts what is left)
         with jax.named_scope("lm_head"):
-            image_head = {**params, "head": jax.tree_util.tree_map(lambda w: w[..., lo:hi], params["head"])}
+            image_head, whole_head = fam.cut_head(params, lo, hi)
+        note_program_geometry(lm_head_shape=whole_head)
         factors = expert_factors(lora, cfg, dt)
         with jax.named_scope("lm_prefill"):
             state, stats, carried_bytes = fam.prefill_state(params, cfg, prompt_ids, prompt_len, lora, lora_scale,
@@ -1042,8 +1061,9 @@ def generate(
         ids = ids.T                                                           # [B, n]
         topk = jnp.concatenate([topk_p, jnp.moveaxis(topk_d, 0, 1)], axis=1)  # [B, Tmax, layers, k]
 
-    rows = {"ids": ids, "topk": topk, "assign": assign,
-            "load": jnp.broadcast_to(load, (B,)), "logits": probe}
+    # what the router chose and computed, in a family that has one
+    routed = {"topk": topk, "assign": assign, "load": jnp.broadcast_to(load, (B,))} if cfg.n_moe_layers else {}
+    rows = {"ids": ids, **routed, "logits": probe}
     rows.update({f"carried/{k}": jnp.full((B,), v, jnp.float32) for k, v in carried_bytes.items()})
     if fam.state_rows is not None:
         rows.update(fam.state_rows(cfg, state))

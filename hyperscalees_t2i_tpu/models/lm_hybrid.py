@@ -308,6 +308,29 @@ def _gdn_out(p: Params, cfg: HybridLMConfig, o: jax.Array, z: jax.Array, dtype,
     return nn.dense(p["wout"], o.reshape(*o.shape[:-2], -1).astype(dtype), lookup(lora, f"{path}/wout"), scale)
 
 
+def causal_conv(mixed: jax.Array, w: jax.Array, lens: jax.Array, bias: Optional[jax.Array] = None):
+    """The short causal depthwise conv of a recurrent mixer over whole
+    (right-padded) sequences: ``mixed [S, T, C]``, taps ``w [K, C]`` (float32:
+    the taps are summed in it), ``lens [S]`` real positions → (``silu(conv +
+    bias) [S, T, C]``, the window a decode step carries on ``[S, K - 1, C]``:
+    each sequence's last ``K - 1`` real inputs, zeros before its start)."""
+    K, T = w.shape[0], mixed.shape[1]
+    padded = jnp.pad(mixed, ((0, 0), (K - 1, 0), (0, 0)))                 # padded[t + K - 1] = mixed[t]
+    taps = sum(padded[:, j: j + T] * w[j] for j in range(K))
+    conv = jax.nn.silu(taps if bias is None else taps + bias)
+    at = lens[:, None] + jnp.arange(K - 1)[None, :]                       # mixed[len - (K - 1) + j]
+    return conv, jnp.take_along_axis(padded, at[:, :, None], axis=1)
+
+
+def causal_conv_step(window: jax.Array, mixed: jax.Array, w: jax.Array, bias: Optional[jax.Array] = None):
+    """One position of :func:`causal_conv`: ``window [S, K - 1, C]``, the new
+    input ``mixed [S, C]`` → (``silu(conv + bias) [S, C]``, the ``K`` inputs
+    ``[S, K, C]``, whose last ``K - 1`` are the next window)."""
+    full = jnp.concatenate([window, mixed[:, None].astype(window.dtype)], axis=1)
+    taps = (full * w).sum(1)
+    return jax.nn.silu(taps if bias is None else taps + bias), full
+
+
 def gdn_prefill(p: Params, cfg: HybridLMConfig, u: jax.Array, lens: jax.Array,
                 lora: Optional[Params], path: str, scale: float, chunk: int = GDN_CHUNK):
     """Whole (right-padded) sequences: ``u [S, T, d]``, ``lens [S]`` real
@@ -315,14 +338,9 @@ def gdn_prefill(p: Params, cfg: HybridLMConfig, u: jax.Array, lens: jax.Array,
     each sequence's last real position, conv window ``[S, K - 1, C]``: its
     last ``K - 1`` real conv inputs, zeros before the sequence's start))."""
     S, T, _ = u.shape
-    K = cfg.linear_conv_kernel_dim
     mixed, z, beta, g = _gdn_project(p, cfg, u, lora, path, scale)
     with jax.named_scope("conv"):
-        padded = jnp.pad(mixed, ((0, 0), (K - 1, 0), (0, 0)))             # padded[t + K - 1] = mixed[t]
-        w = p["conv"]["weight"]                                           # float32: the taps are summed in it
-        conv = jax.nn.silu(sum(padded[:, j: j + T] * w[j] for j in range(K)))
-        at = lens[:, None] + jnp.arange(K - 1)[None, :]                   # mixed[len - (K - 1) + j]
-        window = jnp.take_along_axis(padded, at[:, :, None], axis=1)
+        conv, window = causal_conv(mixed, p["conv"]["weight"], lens)
     with jax.named_scope("delta_rule"):
         q, k, v = _gdn_heads(cfg, conv)
         real = (jnp.arange(T)[None, :] < lens[:, None])[..., None]        # padding: beta = 0, alpha = 1
@@ -347,8 +365,7 @@ def gdn_decode(p: Params, cfg: HybridLMConfig, u: jax.Array, carried,
     state, window = carried
     mixed, z, beta, g = _gdn_project(p, cfg, u, lora, path, scale)
     with jax.named_scope("conv"):
-        full = jnp.concatenate([window, mixed[:, None].astype(window.dtype)], axis=1)   # [S, K, C]
-        conv = jax.nn.silu((full * p["conv"]["weight"]).sum(1))
+        conv, full = causal_conv_step(window, mixed, p["conv"]["weight"])
     with jax.named_scope("delta_rule"):
         q, k, v = _gdn_heads(cfg, conv)
         o, state = gated_delta.gated_delta_step(q, k, v, g, beta, state.astype(jnp.float32))
@@ -503,8 +520,9 @@ def decode_layers(params: Params, cfg: HybridLMConfig, x: jax.Array, state, i: j
     return x, tuple(new_state), stats
 
 
-# What the decode scan carries a DeltaNet layer's recurrent state in; the update itself is float32 either way.
-# The model states float32, and ``lm/state_bytes`` is counted from the carried arrays' own dtype, which is how
-# the benchmark holds a step to it. Only a control sets another (tests, ``BENCH_BF16_STATE``).
+# What the decode scan carries a recurrent layer's state in — a DeltaNet layer's here, a Mamba-2 layer's in
+# ``models/lm_ssm.py``, which reads this one constant; the update itself is float32 either way. Both models state
+# float32, and ``lm/state_bytes`` is counted from the carried arrays' own dtype, which is how the benchmark holds
+# a step to it. Only a control sets another (tests, ``BENCH_BF16_STATE``).
 STATE_DTYPE = jnp.float32
 FAMILY = lm.Family(init=init, prefill_state=prefill_state, decode_layers=decode_layers, head=head)

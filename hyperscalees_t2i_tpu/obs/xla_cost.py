@@ -348,6 +348,7 @@ INNER_SCOPES = (
     "lm_mla", "attend", "lm_dense_ffn", "lm_moe", "router", "experts", "shared", "lm_head",  # inside them
     "lm_gdn", "conv", "delta_rule", "gdn_out", "lm_attn",              # the hybrid family's mixers (lm_attn -> attend)
     "lm_hc", "hc_coeff", "hc_sinkhorn", "hc_mix",                      # hyper-connection streams around every sub-layer
+    "lm_ssm", "ssd", "ssm_out",                                        # a Mamba-2 mixer (lm_ssm -> conv, ssd, ssm_out)
     "preprocess", "clip_b", "clip_h", "score",                         # rewards
     "perturb",                                                         # es_noise: one member's adapter
     "fitness", "update", "health",                                     # es_update
