@@ -165,9 +165,9 @@ def main(argv=None) -> int:
              if p["label"].startswith("es_step_")]
     _check(len(steps) == 1, f"{len(steps)} step programs in the ledger, expected 1")
     step = steps[0]
-    # the Sana path has no decode-attention site and no DeltaNet layer; every
-    # other selected kernel must be in the step, and nothing else
-    expected = {k for k, on in selected.items() if on} - {"decode_attention", "gated_delta_step"}
+    # the Sana path has no decode-attention site, no DeltaNet and no Mamba-2
+    # layer; every other selected kernel must be in the step, and nothing else
+    expected = {k for k, on in selected.items() if on} - {"decode_attention", "gated_delta_step", "ssd_step"}
     found = set(step["pallas_kernels"])
     _check(found == expected,
            f"kernels in the compiled step {sorted(found)} != selected by the "

@@ -47,8 +47,12 @@ How it runs. ``layer_types`` repeats with a period (:attr:`SSMLMConfig.period`:
 carried state are stacked by position in the period — ``params["layers"][j]``
 holds position ``j`` of every period, leaves ``[n_periods, ...]`` — and the
 periods run under ``lax.scan`` in both the prefill and the decode step: one
-period is traced, not every layer. A decode step reads a layer's state and
-cache out of the stack the scan carries and writes them back into it in place
+period is traced, not every layer. A decode step hands a Mamba-2 layer's
+whole state stack and the period index to ``ops/ssd.ssd_step_at`` — on a TPU
+one Pallas call that reads and writes that period's state in place, elsewhere
+the ``jax.numpy`` step between a read at the period index and an in-place
+write back — and reads a layer's conv window and KV cache out of the stack
+the scan carries and writes them back into it in place
 (``dynamic_update_slice`` at the period index).
 """
 
@@ -250,12 +254,14 @@ def _lora_at(lora: Optional[Params], j: int, i) -> Optional[Params]:
 
 
 def _in_ssd(f, *args, **kw):
-    """``f`` under the scopes of the SSM update (``lm_ssm/ssd``): a decode
-    step's read of a layer's state out of its period stack and its write back
-    are passes of the update over the state (under the member ``vmap`` the
-    read is a gather that XLA materializes, the write a scatter that it fuses
-    with the update's arithmetic), and ``ssd_update_roofline`` divides the
-    update's floor by the seconds of that scope."""
+    """``f`` under the scopes of the SSM update (``lm_ssm/ssd``), which is
+    where ``ssd_update_roofline`` reads the update's seconds: a decode step's
+    read of a layer's conv window out of its period stack and its write back
+    (under the member ``vmap`` a gather and a scatter, 60 MB a step in the
+    published model). The state's own read and write lie inside
+    ``ops/ssd.ssd_step_at``, under the same scopes: on a TPU the kernel's one
+    pass in and one out, elsewhere the ``jax.numpy`` step between a read at the
+    period index and an in-place write back."""
     with jax.named_scope("lm_ssm"), jax.named_scope("ssd"):
         return f(*args, **kw)
 
@@ -312,22 +318,26 @@ def mamba_prefill(p: Params, cfg: SSMLMConfig, u: jax.Array, lens: jax.Array,
     return out, (state, window)
 
 
-def mamba_decode(p: Params, cfg: SSMLMConfig, u: jax.Array, carried, lora: Optional[Params], path: str,
-                 scale: float):
-    """One position a sequence: ``u [S, d]``, ``carried = (state, window)`` →
-    (out ``[S, d]``, the new pair). A state carried narrower than float32
-    (``lm_hybrid.STATE_DTYPE``, a control) is widened before the step and
+def mamba_decode(p: Params, cfg: SSMLMConfig, u: jax.Array, carried, k: jax.Array, lora: Optional[Params],
+                 path: str, scale: float):
+    """One position a sequence in period ``k``: ``u [S, d]``, ``carried =
+    (state, window)`` stacked over the periods (``[n_periods, S, H, P, N]``,
+    ``[n_periods, S, K - 1, C]``) → (out ``[S, d]``, the new pair, period ``k``
+    of each advanced in place). The state stack goes to the step whole
+    (``ops/ssd.ssd_step_at``); a state carried narrower than float32
+    (``lm_hybrid.STATE_DTYPE``, a control) is widened for the step and
     narrowed after."""
-    state, window = carried
+    states, windows = carried
+    window = _in_ssd(jax.lax.dynamic_index_in_dim, windows, k, keepdims=False)
     z, xbc, dt = _mamba_project(p, cfg, u, lora, path, scale)
     with jax.named_scope("conv"):
         xbc, full = lm_hybrid.causal_conv_step(window, xbc, p["conv"]["weight"], p["conv"]["bias"])
     with jax.named_scope("ssd"):
         x, delta, B, C, A = _ssd_inputs(p, cfg, xbc, dt)
-        y, state = ssd.ssd_step(x, delta, A, B, C, p["d"], state.astype(jnp.float32))
+        y, states = ssd.ssd_step_at(x, delta, A, B, C, p["d"], states, k)
     with jax.named_scope("ssm_out"):
         out = _mamba_out(p, cfg, y, z, u.dtype, lora, path, scale)
-    return out, (state.astype(lm_hybrid.STATE_DTYPE), full[:, 1:])
+    return out, (states, _in_ssd(jax.lax.dynamic_update_index_in_dim, windows, full[:, 1:], k, axis=0))
 
 
 def _attn_project(p: Params, cfg: SSMLMConfig, u: jax.Array, lora: Optional[Params], path: str, scale: float):
@@ -478,10 +488,8 @@ def decode_layers(params: Params, cfg: SSMLMConfig, x: jax.Array, state, i: jax.
     """:func:`models.lm.generate`'s second hook: sampled position ``i`` of
     every sequence, ``x [B, d]`` (the embedding row, scaled here), through the
     blocks — a ``lax.scan`` over the periods whose carry holds the stacked
-    states and caches, each layer's read at its period index and written back
-    in place."""
+    states and caches, each layer's advanced at its period index in place."""
     slot, _, valid = lm.decode_slot(cfg, i, prompt_len)
-    at = lambda a, k: _in_ssd(jax.lax.dynamic_index_in_dim, a, k, keepdims=False)
 
     def period(carry, k):
         x, state = carry
@@ -492,11 +500,9 @@ def decode_layers(params: Params, cfg: SSMLMConfig, x: jax.Array, state, i: jax.
                 mixer = lambda u, p=p, lj=lj, path=path: attn_decode(p["attn"], cfg, u, state[j], k, slot, valid, lj,
                                                                      f"{path}/attn", lora_scale)
             else:
-                mixer = lambda u, p=p, lj=lj, path=path: mamba_decode(p["mamba"], cfg, u, tuple(at(s, k) for s in state[j]),
-                                                                      lj, f"{path}/mamba", lora_scale)
+                mixer = lambda u, p=p, lj=lj, path=path: mamba_decode(p["mamba"], cfg, u, state[j], k, lj,
+                                                                      f"{path}/mamba", lora_scale)
             x, c = block(p, cfg, x, mixer, lj, path, lora_scale)
-            if kind == MAMBA:
-                c = tuple(_in_ssd(jax.lax.dynamic_update_index_in_dim, s, v, k, axis=0) for s, v in zip(state[j], c))
             new.append(c)
         return (x, tuple(new)), None
 

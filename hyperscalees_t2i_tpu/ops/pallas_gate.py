@@ -4,7 +4,7 @@ A kernel is selected from what the code can observe — the backend
 (:func:`backend_is_tpu`) and the call's shapes (each kernel's own fit check)
 — plus, for the two older kernels, one tri-state environment flag each
 (:func:`env_requested`): ``"0"``/``"off"`` opts a default-ON kernel out
-(``gated_delta_step`` has no flag). Nothing is probed and nothing
+(``gated_delta_step`` and ``ssd_step`` have no flag). Nothing is probed and nothing
 falls back: a kernel its gate selected and Mosaic refuses raises at the
 enclosing compile, on every path. Whether each
 kernel compiles, runs and agrees with its XLA composition on a chip is
@@ -19,9 +19,9 @@ default-ON gates), not guessed at trace time.
   into bench/dispatch_tax artifacts and ledger geometry so a measurement
   always says which kernels were requested when it was taken.
 
-The per-kernel gates stay in their own modules (all three kernels —
-``fused_qlora``, ``decode_attention``, ``gated_delta_step`` — are on by
-default on a TPU); only the env/backend mechanics live here. Stdlib-only
+The per-kernel gates stay in their own modules (all four kernels —
+``fused_qlora``, ``decode_attention``, ``gated_delta_step``, ``ssd_step`` —
+are on by default on a TPU); only the env/backend mechanics live here. Stdlib-only
 at import (jax-free processes render the flag marks).
 """
 
@@ -63,11 +63,13 @@ def selected_kernels() -> Dict[str, bool]:
     from .attention import should_use_pallas
     from .fused_qlora import use_fused_qlora_pallas
     from .gated_delta import use_gated_delta_pallas
+    from .ssd import use_ssd_pallas
 
     return {
         "fused_qlora": use_fused_qlora_pallas(),
         "decode_attention": should_use_pallas(),
         "gated_delta_step": use_gated_delta_pallas(),
+        "ssd_step": use_ssd_pallas(),
     }
 
 

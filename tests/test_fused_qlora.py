@@ -396,6 +396,88 @@ def test_mosaic_compiles_the_gated_delta_step_of_the_hybrid_cell(members, v5e_ch
     assert kv_cache_whole_ops(compiled, args[5].shape) == {"custom-call": 1}
 
 
+@pytest.mark.parametrize("members", [False, True], ids=["one-member", "member-axis-8"])
+def test_mosaic_compiles_the_ssd_step_of_the_granite_cell(members, v5e_chip):
+    """The fourth default-on kernel (``ops/ssd.py``) at the granite cell's
+    call, a Mamba-2 layer's period stack ``f32[4, 8, 64, 64, 128]``: Mosaic
+    takes the prefetched period index in the block index maps, the in-kernel
+    transposes of the ``[64, 64]`` head blocks and the 8 MB of state blocks;
+    the stack comes out in the buffer it went in by; and under ``vmap`` over
+    the members nothing as large as a period of the stack exists beside the
+    call's own operand and result — the batching is a grid axis, the period a
+    block index, neither a gather nor a scatter nor a copy."""
+    from jax.sharding import SingleDeviceSharding
+    from hyperscalees_t2i_tpu.tools import kernel_check
+
+    case = next(c for c in kernel_check.cases()
+                if c.kernel == "ssd_step" and ("member axis" in c.label) == members)
+    rec = kernel_check.run_case(case, compile_only_device=v5e_chip)
+    assert rec["ok"] and rec["compiled_for"] == "TPU v5 lite"
+    s = SingleDeviceSharding(v5e_chip)
+    args = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                                  jax.eval_shape(case.make, jax.random.PRNGKey(0)))
+    compiled = jax.jit(case.kernel_fn, donate_argnums=6).lower(*args).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    stack_bytes = (8 if members else 1) * 4 * 8 * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes == stack_bytes and mem.temp_size_in_bytes < stack_bytes // 4 // 16
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    from hyperscalees_t2i_tpu.obs.xla_cost import kv_cache_whole_ops
+    assert kv_cache_whole_ops(compiled, args[6].shape[-5:]) == {"custom-call": 1}
+
+
+@pytest.mark.parametrize("tpu_gate", [True, False], ids=["kernel", "jax-numpy-step"])
+def test_the_granite_decode_scan_advances_each_state_stack_in_place(tpu_gate, v5e_chip, tmp_path, monkeypatch):
+    """``models/lm.generate`` of the Mamba-2 family under ``vmap`` over eight
+    members, eight sequences each, at the published 64 x 128 head in a toy of
+    two periods ``[M, A, M]``, compiled for a v5e. With the TPU's gate every
+    Mamba-2 position of the traced period is one ``ssd_step`` call
+    (``pallas_kernels`` and ``pallas_heads_per_block`` as ``programs.jsonl``
+    records them), and of the ops as large as a layer's state stack
+    (``recurrent_state_whole_ops``) the in-place ``dynamic-update-slice``
+    fusions are the prefill's one write a layer alone: the decode scan's write
+    back is the kernel's aliased output. The ``jax.numpy`` step, which the CPU
+    and a bf16 state run, writes each layer back a second time, and nothing
+    copies a stack in either."""
+    import json
+
+    from jax.sharding import SingleDeviceSharding
+    from hyperscalees_t2i_tpu.models import lm
+    from hyperscalees_t2i_tpu.obs import xla_cost
+    from hyperscalees_t2i_tpu.ops import ssd
+    from tests.test_lm_ssm import TOY
+
+    raw = {**TOY, "hidden_size": 128, "mamba_n_heads": 4, "mamba_d_head": 64, "mamba_d_state": 128,
+           "torch_dtype": "bfloat16"}
+    (tmp_path / "config.json").write_text(json.dumps(raw))
+    cfg = lm.config_from_json(str(tmp_path / "config.json"))
+    monkeypatch.setattr(ssd, "backend_is_tpu", lambda: tpu_gate)
+    M, B = 8, 8
+    s = SingleDeviceSharding(v5e_chip)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
+    args = (jax.tree_util.tree_map(sds, jax.eval_shape(lambda k: lm.init_lm(k, cfg), jax.random.PRNGKey(0))),
+            sds(jnp.zeros((B, cfg.max_prompt_len), jnp.int32)), sds(jnp.zeros((B,), jnp.int32)),
+            sds(jnp.zeros((M, 2), jnp.uint32)))
+
+    def members(params, prompt, lens, keys):
+        return jax.vmap(lambda k: lm.generate(params, cfg, prompt, lens, k, decode=False))(keys)
+
+    with jax.default_matmul_precision("default"):  # the program as deployed, not conftest's "highest"
+        lowered = jax.jit(members).lower(*args)
+        compiled = lowered.compile()
+    rec = xla_cost.program_record(site="test", label="generate", lowered=lowered)
+    shape = rec["geometry"]["recurrent_state_shape"]
+    assert tuple(shape) == (cfg.n_periods, B, 4, 64, 128)
+    whole = xla_cost.kv_cache_whole_ops(compiled, shape)
+    mamba = cfg.period_types.count("mamba")
+    if tpu_gate:
+        assert rec["pallas_kernels"] == {"ssd_step": mamba}
+        assert rec["pallas_heads_per_block"] == {"ssd_step": {"4": mamba}}
+    else:
+        assert rec["pallas_kernels"] == {} and rec["pallas_heads_per_block"] == {}
+    assert whole["fusion(dynamic-update-slice)"] == (1 if tpu_gate else 2) * mamba, whole
+    assert not {"copy", "gather", "scatter", "fusion(gather)", "fusion(scatter)"} & set(whole), whole
+
+
 @pytest.mark.parametrize("family", ["mla", "mla-streams", "hybrid"])
 def test_the_decode_scan_reads_the_head_as_the_tpu_compiler_builds_it(family, v5e_chip, tmp_path, monkeypatch):
     """``models/lm.generate`` under ``vmap`` over eight members at toy widths
@@ -950,9 +1032,9 @@ def test_active_flags_and_marks(monkeypatch):
 
 def _as_tpu(monkeypatch, on: bool):
     """Every gate module binds backend_is_tpu at import; flip them all."""
-    from hyperscalees_t2i_tpu.ops import fused_qlora, gated_delta
+    from hyperscalees_t2i_tpu.ops import fused_qlora, gated_delta, ssd
 
-    for mod in (pallas_gate, fused_qlora, gated_delta):
+    for mod in (pallas_gate, fused_qlora, gated_delta, ssd):
         monkeypatch.setattr(mod, "backend_is_tpu", lambda: on)
 
 
@@ -960,11 +1042,11 @@ def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     """Selection is by platform (and, per layer, shape) plus the one
     tri-state flag: the kernels are on exactly on a TPU backend unless
     opted out, and a request never forces a kernel onto a backend that cannot
-    run Mosaic. ``gated_delta_step`` (PR 32) has no flag: the backend and the
-    call's shapes alone select it."""
+    run Mosaic. ``gated_delta_step`` and ``ssd_step`` have no flag: the
+    backend and the call's shapes alone select them."""
     for f in pallas_gate.PALLAS_ENV_FLAGS:
         monkeypatch.delenv(f, raising=False)
-    off = {"fused_qlora": False, "decode_attention": False, "gated_delta_step": False}
+    off = {"fused_qlora": False, "decode_attention": False, "gated_delta_step": False, "ssd_step": False}
     assert pallas_gate.selected_kernels() == off
     for f in pallas_gate.PALLAS_ENV_FLAGS:  # =1 off the TPU selects nothing
         monkeypatch.setenv(f, "1")
@@ -979,7 +1061,7 @@ def test_gates_select_by_backend_and_flag_alone(monkeypatch):
     # pallas_env stamp ("flash-") has to describe the path that ran
     monkeypatch.setenv("HSES_USE_PALLAS", "0")
     monkeypatch.setenv("HSES_FUSED_QLORA_PALLAS", "off")
-    assert pallas_gate.selected_kernels() == {**off, "gated_delta_step": True}
+    assert pallas_gate.selected_kernels() == {**off, "gated_delta_step": True, "ssd_step": True}
 
 
 def test_gate_inside_jit_selects_and_never_falls_back(monkeypatch):
