@@ -2,8 +2,9 @@
 generator under ES (models/lm.py; the family is the one the ``model_type`` of
 ``--lm_config`` names: MLA over a latent cache with routed experts,
 ``qwen3_next``'s Gated DeltaNet layers beside gated attention,
-models/lm_hybrid.py, or ``granitemoehybrid``'s Mamba-2 mixers beside
-attention with a dense FFN, models/lm_ssm.py).
+models/lm_hybrid.py, ``granitemoehybrid``'s Mamba-2 mixers beside
+attention with a dense FFN, models/lm_ssm.py, or ``mimo_v2_flash``'s
+sliding-window attention beside full attention, models/lm_swa.py).
 
 A mechanism's backend, not a model's: sizes come from the ``config.json``-shaped
 file ``--lm_config`` names (the model's published keys plus the share of the
@@ -25,10 +26,12 @@ and :meth:`LMArBackend.step_metrics` reduces them to the step's metrics:
   the mean of that call;
 - ``moe/pair_route_flip``: share of (cache slot, layer) top-k sets that differ
   between the two halves of an antithetic pair;
-- ``lm/state_bytes``, ``lm/kv_cache_bytes`` (a family that says what a sequence
-  carries; ``qwen3_next`` and ``granitemoehybrid`` do): bytes of recurrent
-  state + conv window, and of KV cache, that the step's sequences carry
-  through their decode scans — what
+- ``lm/state_bytes``, ``lm/kv_cache_bytes``, ``lm/window_cache_bytes`` (a
+  family that says what a sequence carries; ``qwen3_next``,
+  ``granitemoehybrid`` and ``mimo_v2_flash`` do): bytes of recurrent state +
+  conv window, of KV cache over every position, and of a window layer's ring
+  of ``sliding_window`` slots, that the step's sequences carry through their
+  decode scans — what
   grows with ``pop_size x prompts_per_gen`` whatever the prompt length, and of
   which ``member_batch / pop_size`` is resident at a time;
 - ``lm/hc_marginal_err``, ``lm/hc_row_err``, ``lm/hc_offdiag_mass`` (a model
@@ -185,7 +188,7 @@ class LMArBackend:
                 out["moe/pair_route_flip"] = differ.sum() / jnp.maximum(seen.sum(), 1)
             else:
                 out["moe/pair_route_flip"] = jnp.float32(0.0)
-        for kind in ("state", "kv_cache"):
+        for kind in ("state", "kv_cache", "window_cache"):
             if f"carried/{kind}" in rows:
                 out[f"lm/{kind}_bytes"] = rows[f"carried/{kind}"].sum()
         if "hc_err" in rows:

@@ -1,6 +1,6 @@
 """Decoder language models as autoregressive image-token generators.
 
-Three families stand behind one :func:`generate`, chosen by the ``model_type``
+Four families stand behind one :func:`generate`, chosen by the ``model_type``
 of a ``config.json``-shaped file (:func:`config_from_json`):
 
 - the one this file writes down: multi-head latent attention (MLA) over a
@@ -25,7 +25,12 @@ of a ``config.json``-shaped file (:func:`config_from_json`):
   dense SwiGLU in every layer, published multipliers on the embedding, the
   residual branches and the logits, a head tied to the embedding. No router:
   ``generate`` returns no routing rows for it. Its layers run as a
-  ``lax.scan`` over the periods of its layer pattern.
+  ``lax.scan`` over the periods of its layer pattern;
+- ``mimo_v2_flash`` (``models/lm_swa.py``): sliding-window attention with a
+  learned sink beside full grouped-query attention, query-key heads wider
+  than value heads, a RoPE base for each kind, a sigmoid router over routed
+  experts with no shared expert. ``generate`` carries a full layer's KV cache
+  of ``cache_len`` slots beside a window layer's ring of ``sliding_window``.
 
 What they share lives here: the use the system makes of any of them
 (:class:`GeneratorUse`: the share of a stated deployment this chip holds —
@@ -346,7 +351,7 @@ def config_from_json(path: str):
     ``image_tokens`` group, ``vq`` and ``torch_dtype``. A file without
     ``model_type`` is read as the MLA family, as every file was before the key
     was looked at."""
-    from . import lm_hybrid, lm_ssm  # the other families build on this module
+    from . import lm_hybrid, lm_ssm, lm_swa  # the other families build on this module
 
     raw = json.loads(Path(path).read_text())
     model_type = raw.get("model_type")
@@ -356,7 +361,9 @@ def config_from_json(path: str):
         return lm_hybrid.HybridLMConfig.from_raw(raw)
     if model_type == lm_ssm.MODEL_TYPE:
         return lm_ssm.SSMLMConfig.from_raw(raw)
-    known = [t for t in MLA_MODEL_TYPES if t] + [lm_hybrid.MODEL_TYPE, lm_ssm.MODEL_TYPE]
+    if model_type == lm_swa.MODEL_TYPE:
+        return lm_swa.SWALMConfig.from_raw(raw)
+    known = [t for t in MLA_MODEL_TYPES if t] + [lm_hybrid.MODEL_TYPE, lm_ssm.MODEL_TYPE, lm_swa.MODEL_TYPE]
     raise ValueError(f"{path}: model_type {model_type!r} is not a family this model code writes down ({known})")
 
 

@@ -349,6 +349,7 @@ INNER_SCOPES = (
     "lm_gdn", "conv", "delta_rule", "gdn_out", "lm_attn",              # the hybrid family's mixers (lm_attn -> attend)
     "lm_hc", "hc_coeff", "hc_sinkhorn", "hc_mix",                      # hyper-connection streams around every sub-layer
     "lm_ssm", "ssd", "ssm_out",                                        # a Mamba-2 mixer (lm_ssm -> conv, ssd, ssm_out)
+    "lm_swa",                                                          # sliding-window attention (lm_swa -> attend)
     "preprocess", "clip_b", "clip_h", "score",                         # rewards
     "perturb",                                                         # es_noise: one member's adapter
     "fitness", "update", "health",                                     # es_update

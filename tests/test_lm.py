@@ -184,18 +184,25 @@ def test_mtp_module_against_reference(toy):
 
 # (d) the share adds up --------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["sandwich", "xing4_0"])
+@pytest.mark.parametrize("family", ["sandwich", "xing4_0", "mimo_v2_flash"])
 def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(tmp_path, family, form):
-    """16 experts over 4 shares (the sandwich-norm toy, top-k by the score) or
-    8 experts over 2 shares under the selection-bias router (the ``xing4_0``
-    toy): the routed parts of the shares plus the shared expert counted once
-    equal the uncut reference's MoE output."""
+    """16 experts over 4 shares (the sandwich-norm toy, top-k by the score; the
+    ``mimo_v2_flash`` toy, by the score plus a selection bias and with no
+    shared expert) or 8 experts over 2 shares under the selection-bias router
+    (the ``xing4_0`` toy): the routed parts of the shares plus the shared
+    expert counted once, where there is one, equal the uncut reference's MoE
+    output."""
     if family == "xing4_0":
         from hyperscalees_t2i_tpu.reference import mhc_moe_reference as reference
         from tests.test_lm_mhc import toy_cfg as make_cfg
+    elif family == "mimo_v2_flash":
+        from hyperscalees_t2i_tpu.reference import gqa_swa_moe_reference as reference
+        from tests.test_lm_swa import ref_scalars, toy_cfg as make_cfg
     else:
         reference, make_cfg = ref, toy_cfg
     cfg, raw = make_cfg(tmp_path)
+    if family == "mimo_v2_flash":  # the scalars the benchmark hands its reference (``routed_scaling_factor`` 1)
+        raw = ref_scalars(cfg)
     params = lm.init_lm(jax.random.PRNGKey(0), cfg)
     p = params["layers"][1]["moe"]
     held, shares = 4, cfg.n_routed_experts // 4
@@ -204,10 +211,10 @@ def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(tmp_path, fa
         whole, picked = reference.moe(reference.block_weights(params["layers"][1], "x"), raw, u)
     top_i, top_w = lm.route(p, cfg, u)
     assert np.array_equal(np.sort(np.asarray(top_i), -1), np.sort(np.asarray(picked), -1))
-    if family == "xing4_0":  # the bias changes the choice: by the score alone some token chooses otherwise
+    if family != "sandwich":  # the bias changes the choice: by the score alone some token chooses otherwise
         by_score = jax.lax.top_k(jax.nn.sigmoid(u @ p["router"]["weight"].T), cfg.num_experts_per_tok)[1]
         assert not np.array_equal(np.sort(np.asarray(by_score), -1), np.sort(np.asarray(top_i), -1))
-    total = lm._swiglu(p["shared"], u, None, "x", 1.0)
+    total = lm._swiglu(p["shared"], u, None, "x", 1.0) if "shared" in p else jnp.zeros_like(u)
     for share in range(shares):
         cfg_s, _ = make_cfg(tmp_path, experts_held=held, expert_offset=held * share)
         mine = {k: {"kernel": v["kernel"][held * share: held * (share + 1)]} for k, v in p["experts"].items()}

@@ -1,13 +1,13 @@
 """The step programs of the ``lm_ar`` families that came before the
-``granitemoehybrid`` one are pinned: what a member runs — ``lm.generate`` with
+``mimo_v2_flash`` one are pinned: what a member runs — ``lm.generate`` with
 a member's factored adapter over an int8 base, two members of an antithetic
 pair under ``vmap``, the VQ decode, then ``LMArBackend.step_metrics`` over
 their rows — lowered at the toy widths of each family's tier-1 tests, its
 StableHLO text hashed (XLA:CPU; no debug information, so source lines do not
 enter). A change to the code these families share with another
-(``models/lm.py``, ``models/lm_hybrid.py``, ``backends/lm_backend.py``) that
-moves any of their programs shows here; a change meant to move one updates
-its hash with the reason."""
+(``models/lm.py``, ``models/lm_hybrid.py``, ``backends/lm_backend.py``,
+``obs/xla_cost.py``) that moves any of their programs shows here; a change
+meant to move one updates its hash with the reason."""
 
 import hashlib
 import json
@@ -20,15 +20,17 @@ from hyperscalees_t2i_tpu.backends.lm_backend import LMArBackend
 from hyperscalees_t2i_tpu.es import EggRollConfig, factored_member_theta, sample_noise
 from hyperscalees_t2i_tpu.lora import init_lora
 from hyperscalees_t2i_tpu.models import lm
-from tests import test_lm, test_lm_hybrid, test_lm_mhc
+from tests import test_lm, test_lm_hybrid, test_lm_mhc, test_lm_ssm
 
-TOYS = {"mla": test_lm.TOY, "xing4_0": test_lm_mhc.TOY, "qwen3_next": test_lm_hybrid.TOY}
+TOYS = {"mla": test_lm.TOY, "xing4_0": test_lm_mhc.TOY, "qwen3_next": test_lm_hybrid.TOY,
+        "granitemoehybrid": test_lm_ssm.TOY}
 
 # sha256 of each family's step text, first 16 hex digits
 PINNED = {
     "mla": "02ddf9fd13a7e796",
     "xing4_0": "380d1aa2587dbac1",
     "qwen3_next": "0743401b84ad04bc",
+    "granitemoehybrid": "4b2e605dd4915e2c",
 }
 
 
